@@ -31,6 +31,12 @@ Top-level namespaces mirror the reference package layout
 
 __version__ = "0.1.0"
 
+from analytics_zoo_tpu.common.runtime import configure_compile_cache
+
+# before any compile, on every entry point: importing the package is the
+# one step they all share
+configure_compile_cache()
+
 from analytics_zoo_tpu.common.nncontext import init_nncontext, get_nncontext
 
 __all__ = ["init_nncontext", "get_nncontext", "__version__"]

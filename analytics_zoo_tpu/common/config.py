@@ -29,9 +29,6 @@ class ZooConfig:
       default_dtype: compute dtype. bfloat16 keeps matmuls on the MXU's native
         path; params stay float32 unless ``param_dtype`` overrides.
       seed: root RNG seed; all layer init / dropout keys derive from it.
-      version_check: parity with ``spark.analytics.zoo.versionCheck``
-        (NNContext.scala:138) — verifies the jax/flax environment on init.
-      version_check_warning: warn instead of raise on mismatch.
     """
 
     mesh_shape: Optional[Sequence[int]] = None
@@ -39,8 +36,6 @@ class ZooConfig:
     default_dtype: str = "float32"
     param_dtype: str = "float32"
     seed: int = 0
-    version_check: bool = False
-    version_check_warning: bool = False
     log_level: str = "INFO"
     # Input pipeline: number of host-side prefetched batches kept in flight so
     # the mesh is never starved (SURVEY.md §7 hard-part #1).

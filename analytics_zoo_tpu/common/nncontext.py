@@ -42,8 +42,6 @@ class NNContext:
     def __init__(self, conf: Optional[ZooConfig] = None):
         self.conf = conf or ZooConfig()
         self._configure_logging()
-        if self.conf.version_check:
-            self._check_version()
         if self.conf.distributed:
             self._init_distributed()
 
@@ -74,7 +72,7 @@ class NNContext:
         executor/node counts from the cluster manager; here the coordinator
         address + process rank come from config/env and
         ``jax.distributed.initialize`` wires the processes together)."""
-        if getattr(jax.distributed, "is_initialized", lambda: False)():
+        if jax.distributed.is_initialized():
             logger.info("jax.distributed already initialized; reusing")
             return
         kw = {}
@@ -115,19 +113,6 @@ class NNContext:
                 logging.Formatter("%(asctime)s %(name)s %(levelname)s %(message)s")
             )
             logger.addHandler(h)
-
-    def _check_version(self):
-        """Parity with NNContext.scala:79-143 version verification."""
-        problems = []
-        jax_ver = tuple(int(x) for x in jax.__version__.split(".")[:2])
-        if jax_ver < (0, 4):
-            problems.append(f"jax>=0.4 required, found {jax.__version__}")
-        if problems:
-            msg = "; ".join(problems)
-            if self.conf.version_check_warning:
-                logger.warning(msg)
-            else:
-                raise RuntimeError(msg)
 
     # -- properties ------------------------------------------------------
 

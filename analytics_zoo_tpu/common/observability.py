@@ -871,54 +871,44 @@ def refresh_process_metrics(
     return out
 
 
-# Build-info label values are computed once — they cannot change within
-# a process, and the front door (which must stay jax-free) takes the
-# gated-import fallback path.
-_build_info_labels: Optional[Dict[str, str]] = None
-
-
 def _build_info_values() -> Dict[str, str]:
-    global _build_info_labels
-    if _build_info_labels is None:
-        try:
-            from analytics_zoo_tpu import __version__ as version
-        except Exception:  # pragma: no cover - defensive
-            version = "unknown"
-        jax_v = jaxlib_v = backend = "unavailable"
-        try:
-            import jax
+    """Label values of ``zoo_build_info``. The ``backend`` label names the
+    default backend only where one is ALREADY initialised: asking
+    ``jax.default_backend()`` would bring one up, and a process that has
+    brought up the TPU backend owns the chip — a front-door parent must
+    leave it to its workers. A process with no backend yet reports
+    ``uninitialized``."""
+    import jax
+    import jaxlib
+    from jax._src import xla_bridge
 
-            jax_v = jax.__version__
-            try:
-                import jaxlib
+    from analytics_zoo_tpu import __version__ as version
 
-                jaxlib_v = jaxlib.__version__
-            except Exception:  # pragma: no cover - jaxlib usually present
-                pass
-            backend = jax.default_backend()
-        except Exception:
-            # jax absent or not importable here (the front door runs
-            # jax-free by design) — report that honestly.
-            pass
-        _build_info_labels = {"version": version, "jax": jax_v,
-                              "jaxlib": jaxlib_v, "backend": backend}
-    return _build_info_labels
+    backend = (jax.default_backend()
+               if xla_bridge.backends_are_initialized() else "uninitialized")
+    return {"version": version, "jax": jax.__version__,
+            "jaxlib": jaxlib.__version__, "backend": backend}
 
 
 def build_info(registry: Optional[MetricsRegistry] = None) -> Gauge:
     """Register the ``zoo_build_info{version,jax,jaxlib,backend}``
     info-gauge (value pinned to 1) in ``registry`` (default: the global
     one) so every scrape identifies exactly what is running — package
-    version, jax/jaxlib versions, and the active backend. Processes
-    without jax (the front door) report ``unavailable``, which is the
-    truthful answer. Idempotent; returns the gauge child."""
+    version, jax/jaxlib versions, and the active backend (``uninitialized``
+    in a process that has not brought one up, e.g. the front door; this
+    call never initialises one). Idempotent: a later call replaces the
+    sample, so a registry carries exactly one even when the backend came
+    up in between. Returns the gauge child."""
     reg = registry if registry is not None else get_registry()
-    g = reg.gauge(
+    fam = reg.gauge(
         "zoo_build_info",
         "Build/runtime identity of this process (value is always 1; the "
         "information is in the labels).",
         labels=("version", "jax", "jaxlib", "backend"),
-    ).labels(**_build_info_values())
+    )
+    with fam._lock:
+        fam._children.clear()
+    g = fam.labels(**_build_info_values())
     g.set(1)
     return g
 

@@ -412,7 +412,7 @@ class ShardedUpdater:
         import jax
         import jax.numpy as jnp
         import optax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.flatten_util import ravel_pytree
         from jax.sharding import PartitionSpec as P
 
@@ -438,7 +438,7 @@ class ShardedUpdater:
             wrapped = shard_map(
                 body, mesh=self._mesh,
                 in_specs=(P("data"), P("data"), P("data"), opt_specs),
-                out_specs=(P(), opt_specs), check_rep=False)
+                out_specs=(P(), opt_specs), check_vma=False)
 
             def run(params, grad_vec, opt_state, mask_vec):
                 flat, _ = ravel_pytree(params)
@@ -458,7 +458,7 @@ class ShardedUpdater:
             wrapped = shard_map(
                 body, mesh=self._mesh,
                 in_specs=(P("data"), P("data"), opt_specs),
-                out_specs=(P(), opt_specs), check_rep=False)
+                out_specs=(P(), opt_specs), check_vma=False)
 
             def run(params, grad_vec, opt_state):
                 flat, _ = ravel_pytree(params)

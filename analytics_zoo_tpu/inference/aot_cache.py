@@ -52,7 +52,7 @@ from typing import Any, Dict, List, Optional
 
 logger = logging.getLogger("analytics_zoo_tpu")
 
-__all__ = ["AotExecutableCache", "serialization_available"]
+__all__ = ["AotExecutableCache"]
 
 #: Environment variable naming a process-wide cache directory picked up
 #: by every ``InferenceModel`` constructed without an explicit dir.
@@ -60,15 +60,6 @@ ENV_VAR = "AZOO_AOT_CACHE_DIR"
 
 _SUFFIX = ".zxc"  # zoo xla executable, pickled (payload, in_tree, out_tree)
 _META_SUFFIX = ".meta.json"  # optional human-readable sidecar per entry
-
-
-def serialization_available() -> bool:
-    """Whether this jax build exposes executable serialization."""
-    try:
-        from jax.experimental import serialize_executable  # noqa: F401
-        return True
-    except Exception:  # pragma: no cover - depends on jax build
-        return False
 
 
 class AotExecutableCache:
@@ -82,11 +73,6 @@ class AotExecutableCache:
     def __init__(self, directory: str):
         self.directory = str(directory)
         os.makedirs(self.directory, exist_ok=True)
-        self._available = serialization_available()
-        if not self._available:  # pragma: no cover - depends on jax build
-            logger.warning(
-                "AOT executable cache at %s disabled: this jax build has "
-                "no jax.experimental.serialize_executable", self.directory)
 
     # -- keying -----------------------------------------------------------
 
@@ -142,10 +128,7 @@ class AotExecutableCache:
         h = hashlib.sha256()
         h.update(jax.__version__.encode())
         h.update(jaxlib.__version__.encode())
-        try:
-            h.update(jax.default_backend().encode())
-        except Exception:  # pragma: no cover - defensive
-            pass
+        h.update(jax.default_backend().encode())
         h.update(args_structure.encode())
         h.update((mesh_fingerprint or "single-device").encode())
         if variant:
@@ -170,7 +153,7 @@ class AotExecutableCache:
 
         counters = aot_cache_counters()
         path = self._path(key)
-        if not self._available or not os.path.exists(path):
+        if not os.path.exists(path):
             counters["misses"].inc()
             return None
         try:
@@ -206,8 +189,6 @@ class AotExecutableCache:
         )
 
         counters = aot_cache_counters()
-        if not self._available:  # pragma: no cover - depends on jax build
-            return False
         try:
             from jax.experimental import serialize_executable
 

@@ -5,9 +5,13 @@ JNI façade (initialize/allocate/free/copy) backing PmemFeatureSet. Here the
 native library provides the arena/store/prefetcher trio; pybind11 is not in
 the image, so the ABI is plain C consumed via ctypes.
 
-The library is built on demand with g++ (``make -C native``) the first time
-it is needed; every entry point degrades gracefully (``available() -> False``)
-when a toolchain is missing so the pure-Python paths keep working.
+In a checkout the library always goes through ``make -C native`` the first
+time a process needs it: make is incremental (free when the ``.so`` is
+current), so a git-ignored binary can never be older than the tracked source
+it was built from. An installed wheel ships the ``.so`` without the sources
+and loads it as is. When the build or the load fails, ``available()`` is
+False, the failure is logged as an error with the compiler's output, and the
+pure-Python data paths keep working.
 """
 
 from __future__ import annotations
@@ -71,20 +75,25 @@ def _bind(lib) -> None:
     lib.zoo_native_version.restype = ctypes.c_int
 
 
+def _build() -> None:
+    """``make -C native`` where the sources are present (a checkout);
+    callers hold ``_lib_lock``."""
+    src = _repo_native_dir()
+    if os.path.isdir(src):
+        subprocess.run(["make", "-C", src], check=True, capture_output=True,
+                       text=True, timeout=120)
+
+
 def ensure_lib(lib_name: str) -> str:
-    """Build (make -C native/, bounded, serialized by the module lock) if
-    needed and return the path of ``lib_name`` inside the package — shared
-    by all native components. Raises if the build ran but did not produce
-    the library."""
+    """Bring ``lib_name`` up to date with its source (see module docstring)
+    and return its path inside the package — shared by all native
+    components. Raises if the build fails or does not produce the library."""
     so = os.path.join(os.path.dirname(os.path.abspath(__file__)), lib_name)
-    if not os.path.exists(so):
-        with _lib_lock:
-            if not os.path.exists(so):
-                subprocess.run(["make", "-C", _repo_native_dir()],
-                               check=True, capture_output=True, timeout=120)
+    with _lib_lock:
+        _build()
     if not os.path.exists(so):
         raise FileNotFoundError(
-            f"make completed but {lib_name} was not produced — is "
+            f"{lib_name} not found and not produced by make — is "
             f"native/Makefile's target list current?")
     return so
 
@@ -97,26 +106,19 @@ def _load():
         if _lib is not None or _load_failed:
             return _lib
         so = os.path.join(os.path.dirname(os.path.abspath(__file__)), _LIB_NAME)
-        if not os.path.exists(so):
-            # _lib_lock is already held here; build directly (ensure_lib
-            # would deadlock re-acquiring the non-reentrant lock)
-            try:
-                subprocess.run(["make", "-C", _repo_native_dir()],
-                               check=True, capture_output=True, timeout=120)
-            except (OSError, subprocess.SubprocessError) as e:
-                log.warning("native runtime build failed (%s); "
-                            "falling back to pure Python", e)
-                _load_failed = True
-                return None
         try:
+            _build()
             lib = ctypes.CDLL(so)
             _bind(lib)
             ver = lib.zoo_native_version()
             if ver != 1:  # not assert: must survive python -O
                 raise OSError(f"libzoo_native ABI {ver} != expected 1")
             _lib = lib
-        except OSError as e:
-            log.warning("native runtime load failed (%s)", e)
+        except (OSError, subprocess.SubprocessError) as e:
+            log.error("native runtime unavailable (%s)%s; the pure-Python "
+                      "data path will feed instead", e,
+                      f"\n{e.stderr[-2000:]}"
+                      if getattr(e, "stderr", None) else "")
             _load_failed = True
     return _lib
 

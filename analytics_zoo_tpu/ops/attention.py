@@ -2,9 +2,11 @@
 
 Dispatches between the Pallas flash-attention kernel (ops/flash_attention.py)
 and a fused-by-XLA jnp path. Both take (B, N, S, D) q/k/v plus an additive
-bias/mask. The default is measurement-driven (see _FLASH_BYTES_THRESHOLD):
-XLA at product shapes where it is faster end-to-end, the O(S)-memory Pallas
-kernel where the S^2 logits tensor would dominate HBM.
+bias/mask. The default routes by the size of the S^2 logits tensor (see
+_flash_bytes_threshold): XLA at product shapes, the O(S)-memory Pallas
+kernel where the logits tensor would dominate HBM. A kernel that fails to
+compile or run raises — only a shape outside the kernel's envelope
+(``NotImplementedError``) takes the XLA path, with a warning.
 """
 
 from __future__ import annotations
@@ -23,8 +25,8 @@ logger = logging.getLogger("analytics_zoo_tpu")
 _warned_fallback = False
 
 _DEFAULT_FLASH_BYTES_THRESHOLD = 256 << 20
-# Shapes OUTSIDE the regime the 256 MiB crossover was measured in (bf16,
-# seq axes divisible by the 512 sweep-winning tiles) keep the old 1 GiB
+# Shapes OUTSIDE the regime the 256 MiB crossover applies to (bf16, seq
+# axes divisible by the 512x512 default tiles) keep a 1 GiB
 # memory-pressure bound: there flash is the OOM-enabler, not a speedup.
 _CONSERVATIVE_FLASH_BYTES_THRESHOLD = 1 << 30
 
@@ -32,22 +34,18 @@ _CONSERVATIVE_FLASH_BYTES_THRESHOLD = 1 << 30
 def _flash_bytes_threshold() -> int:
     """Total bytes of the logits tensor (batch*heads*s_q*s_k*itemsize) above
     which the dispatcher prefers the O(S)-memory Pallas kernel over XLA's
-    materialized-logits path. 256 MiB ~= seq 2048 at 8 heads batch 4 (bf16)
-    — the crossover measured in the r5 on-chip sweep
-    (MEASURE_r05/flash_bench.jsonl): with the 512x512 default tiles the
-    bf16 kernels win BOTH passes from seq 2048 up (e.g. 4096-causal grad
-    step 12.4 ms vs 20.3 ms XLA). The sweep covers bf16 with 512-divisible
-    sequence axes ONLY, so ``_auto_use_flash`` applies this threshold just
-    there; other dtypes/tilings keep the conservative 1 GiB bound (128-tile
-    and f32 kernel passes measure SLOWER than XLA — flash past 1 GiB is
-    about not materializing S^2, not speed). The estimate counts the
-    logits tensor only — the XLA path's f32 softmax copy roughly triples
-    the true bf16 peak — so treat the threshold as "bytes the caller will
-    spend on S^2 tensors", not an exact OOM bound. Re-read at every
-    dispatch (malformed values fall back to the default), but under
-    ``jax.jit`` the decision is baked in at TRACE time: changing the env
-    var after a shape has compiled does not re-route already-cached
-    executables."""
+    materialized-logits path. 256 MiB ~= seq 2048 at 8 heads batch 4 (bf16).
+    ``_auto_use_flash`` applies it to bf16 inputs whose sequence axes take
+    the 512x512 default tiles; other dtypes/tilings keep the conservative
+    1 GiB bound (flash past 1 GiB is about not materializing S^2, not
+    speed). Where the speed crossover sits on the current machine is not
+    measured (S4 in ROADMAP.md). The estimate counts the logits tensor
+    only — the XLA path's f32 softmax copy roughly triples the true bf16
+    peak — so treat the threshold as "bytes the caller will spend on S^2
+    tensors", not an exact OOM bound. Re-read at every dispatch (malformed
+    values fall back to the default), but under ``jax.jit`` the decision
+    is baked in at TRACE time: changing the env var after a shape has
+    compiled does not re-route already-cached executables."""
     try:
         return int(os.environ.get("AZOO_FLASH_BYTES_THRESHOLD",
                                   _DEFAULT_FLASH_BYTES_THRESHOLD))
@@ -59,12 +57,9 @@ def _auto_use_flash(q, k) -> bool:
     """The dispatcher's default routing decision (no explicit
     ``use_flash``). An operator-pinned AZOO_FLASH_BYTES_THRESHOLD applies
     verbatim to every shape (whoever tunes it knows their workload); the
-    built-in default applies the measured 256 MiB crossover only in the
-    regime it was measured — bf16 inputs whose sequence axes take the
-    512x512 sweep-winning tiles — and the conservative 1 GiB
-    memory-pressure bound everywhere else (r5 sweep: 128-tile and f32
-    kernel passes lose to XLA, so routing them at 256 MiB would regress
-    every non-512-divisible shape in the 256 MiB-1 GiB band)."""
+    built-in default applies the 256 MiB crossover only to bf16 inputs
+    whose sequence axes take the 512x512 default tiles, and the
+    conservative 1 GiB memory-pressure bound everywhere else."""
     if jax.devices()[0].platform != "tpu":
         return False
     logits_bytes = (jnp.dtype(q.dtype).itemsize
@@ -73,15 +68,14 @@ def _auto_use_flash(q, k) -> bool:
     if "AZOO_FLASH_BYTES_THRESHOLD" not in os.environ:
         # The regime check asks what tiles this shape would ACTUALLY get
         # (per-call env pins included): an AZOO_FLASH_BLOCK_Q/K pin to 128
-        # puts even 512-divisible shapes on the 128-tile kernels the r5
-        # sweep measured slower than XLA in the 256 MiB-1 GiB band, so
-        # the fast crossover must not apply there (ADVICE r5 low).
+        # puts even 512-divisible shapes on the 128-tile kernels, so the
+        # 256 MiB crossover must not apply there.
         from analytics_zoo_tpu.ops.flash_attention import _resolve_blocks
 
-        measured_regime = (q.dtype == jnp.bfloat16
-                           and _resolve_blocks(None, None, q.shape[2],
-                                               k.shape[2]) == (512, 512))
-        if not measured_regime:
+        big_tiles = (q.dtype == jnp.bfloat16
+                     and _resolve_blocks(None, None, q.shape[2],
+                                         k.shape[2]) == (512, 512))
+        if not big_tiles:
             threshold = _CONSERVATIVE_FLASH_BYTES_THRESHOLD
     return logits_bytes >= threshold
 
@@ -120,23 +114,13 @@ def scaled_dot_product_attention(q, k, v, bias: Optional[jax.Array] = None,
         scale = q.shape[-1] ** -0.5
     explicit = use_flash is True
     if use_flash is None:
-        # Measured on v5e (docs/performance.md): at product shapes (BERT
-        # seq 128/512) both paths sit on the dispatch floor and XLA's
-        # fused attention wins the full train step, while from seq 2048 up
-        # the bf16 Pallas kernels with the seq-aware 512x512 tiles win
-        # both passes (r5 sweep: 1.2-1.6x) and past a few thousand tokens
-        # the XLA path's materialized O(S^2) logits dominate HBM or OOM
-        # outright. _auto_use_flash puts the crossover at the measured
-        # point per shape/dtype; the kernel also remains the per-shard
-        # engine of ring attention, and is available via use_flash=True.
+        # At product shapes (BERT seq 128/512) the logits tensor is small
+        # and XLA's fused attention serves; past a few thousand tokens the
+        # XLA path's materialized O(S^2) logits dominate HBM or OOM
+        # outright, and _auto_use_flash routes to the Pallas kernel. The
+        # kernel also remains the per-shard engine of ring attention, and
+        # is available via use_flash=True.
         use_flash = _auto_use_flash(q, k)
-        # Escape hatch for backends where Mosaic/Pallas compilation is
-        # unavailable or pathologically slow (e.g. tunneled PJRT proxies
-        # with remote compile): AZOO_DISABLE_PALLAS=1 routes attention to
-        # the XLA path without touching call sites. An explicit
-        # use_flash=True still wins.
-        if use_flash and os.environ.get("AZOO_DISABLE_PALLAS") == "1":
-            use_flash = False
     if use_flash and not (dropout_rate > 0.0 and dropout_rng is not None):
         try:
             from analytics_zoo_tpu.ops.flash_attention import flash_attention
@@ -156,9 +140,5 @@ def scaled_dot_product_attention(q, k, v, bias: Optional[jax.Array] = None,
                     "the XLA path, which will materialize the O(S^2) logits "
                     "this shape was routed to the kernel to avoid",
                     "requested" if explicit else "auto-selected", e)
-        except (ImportError, RuntimeError) as e:
-            if not _warned_fallback:
-                _warned_fallback = True
-                logger.warning("flash_attention unavailable (%s); using XLA path", e)
     return _reference_attention(q, k, v, bias, causal, scale,
                                 dropout_rate, dropout_rng)

@@ -29,11 +29,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only import
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 # Tunable without edits (on-chip sweeps): 128x128 tiles the MXU exactly;
 # larger Q blocks amortize the per-block softmax bookkeeping.
@@ -63,8 +59,8 @@ def _block_env(var: str, default: int) -> int:
 # The conservative MXU-tile floor the seq-aware default falls back to on
 # axes that don't divide by 512. (Not an env snapshot: AZOO_FLASH_BLOCK_Q/K
 # are read inside _resolve_blocks on every call, so setting or unsetting
-# them after import takes effect — ADVICE r5 low. Under jax.jit the block
-# choice is still baked in at TRACE time, like every other env knob here.)
+# them after import takes effect. Under jax.jit the block choice is still
+# baked in at TRACE time, like every other env knob here.)
 BLOCK_Q = 128
 BLOCK_K = 128
 
@@ -75,12 +71,11 @@ def _resolve_blocks(block_q, block_k, s_q: int, s_k: int):
     same validator, same clear error.
 
     The default tiles 512x512 whenever the sequence axes divide by 512:
-    the r5 on-chip sweep (MEASURE_r05/flash_bench.jsonl) shows 512x512
-    fastest on BOTH passes at seq 2048/4096 (e.g. 4096-causal bwd 12.4 ms
-    vs 20.3 ms for XLA and 21.5 ms for 128x128 tiles) and within noise of
-    the best flash tiling at 1024 (where XLA still wins overall — the
-    dispatcher's business, not this function's). Axes that don't divide
-    by 512 keep the 128 MXU floor.
+    larger tiles amortize the per-block softmax bookkeeping and the grid
+    step overhead over 16x the MXU work of a 128x128 tile. Whether that
+    is the fastest tiling on the current machine is not measured (S4 in
+    ROADMAP.md owns the sweep). Axes that don't divide by 512 keep the
+    128 MXU floor.
     """
     env_q = os.environ.get("AZOO_FLASH_BLOCK_Q")
     env_k = os.environ.get("AZOO_FLASH_BLOCK_K")
@@ -108,31 +103,40 @@ def _compute_dtype(ref) -> jnp.dtype:
     return jnp.bfloat16 if ref.dtype == jnp.bfloat16 else jnp.float32
 
 
+def _precision(cdt):
+    """Mosaic's default contract precision rounds f32 operands to bf16 (on
+    a v5e the f32 kernel then misses the f32 reference by 3.5e-3 at seq
+    384); HIGHEST is its ``contract_precision<fp32>``, which is what "exact
+    f32 matmuls" means on the chip. bf16 operands need no pin."""
+    return jax.lax.Precision.HIGHEST if cdt == jnp.float32 else None
+
+
 def _mm(a, b, cdt):  # a(m,k) @ b(k,n), f32 accumulate
     return jax.lax.dot_general(a.astype(cdt), b.astype(cdt),
                                (((1,), (0,)), ((), ())),
+                               precision=_precision(cdt),
                                preferred_element_type=jnp.float32)
 
 
 def _mm_nt(a, b, cdt):  # a(m,k) @ b(n,k)^T
     return jax.lax.dot_general(a.astype(cdt), b.astype(cdt),
                                (((1,), (1,)), ((), ())),
+                               precision=_precision(cdt),
                                preferred_element_type=jnp.float32)
 
 
 def _mm_tn(a, b, cdt):  # a(k,m)^T @ b(k,n)
     return jax.lax.dot_general(a.astype(cdt), b.astype(cdt),
                                (((0,), (0,)), ((), ())),
+                               precision=_precision(cdt),
                                preferred_element_type=jnp.float32)
 
 
 def _interpret() -> bool:
-    # Lazy: never touches the backend before the caller has (avoids the
-    # round-1 dryrun bootstrap hang class of bug).
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # pragma: no cover
-        return True
+    """Pallas interpret mode off TPU (the CPU test mesh); compiled Mosaic
+    on TPU. Asks the backend at call time and lets it raise: a backend
+    that cannot come up is an error, never a reason to interpret."""
+    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -302,13 +306,12 @@ def _flash_forward(q, k, v, bias_flat, scale: float, causal: bool,
 # kernel (3-D grid over (bn, k-block, q-block)). Both re-materialize the
 # probability tile from the saved logsumexp, accumulating in an f32 VMEM
 # scratch across the sequential innermost grid axis and flushing on its
-# last step. The r5 whole-row design (K/V as full (1, s, d) blocks with an
-# in-kernel fori over pl.ds slices) hit a Mosaic/libtpu code-size wall at
-# seq 16384 — a 17 KB StableHLO became a 33 MB Mosaic module and the
-# compiler died (MEASURE_r05/flash_bench_addendum.jsonl) — while this
-# blocked-grid form, the same shape jax's bundled kernel uses, compiles
-# fine at those lengths and lets the pallas pipeline stream K/V blocks
-# instead of holding whole rows in VMEM.
+# last step. A whole-row design (K/V as full (1, s, d) blocks with an
+# in-kernel fori over pl.ds slices) unrolls into a Mosaic module whose
+# size grows with the sequence; this blocked-grid form, the same shape
+# jax's bundled kernel uses, keeps the module size independent of the
+# sequence length and lets the pallas pipeline stream K/V blocks instead
+# of holding whole rows in VMEM.
 # ---------------------------------------------------------------------------
 
 
@@ -550,8 +553,6 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 def _validate(q, k, scale, block_q: int, block_k: int):
     """Shared support-envelope check for both public entry points; returns
     the resolved scale."""
-    if pltpu is None:
-        raise RuntimeError("pallas tpu backend unavailable")
     if scale is None:
         scale = q.shape[-1] ** -0.5
     s_q, s_k = q.shape[2], k.shape[2]

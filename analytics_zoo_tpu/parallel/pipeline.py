@@ -24,15 +24,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from analytics_zoo_tpu.parallel.ring_attention import _no_vma_check_kw
-
-try:  # jax>=0.8 top-level location
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 def stack_stage_params(param_list):
@@ -132,6 +125,6 @@ def pipeline_apply(stage_fn: Callable, stacked_params, x, mesh: Mesh,
         mesh=mesh,
         in_specs=(params_spec, x_spec),
         out_specs=x_spec,
-        **_no_vma_check_kw())
+        check_vma=False)
     out = fn(stacked_params, micro_x)
     return out.reshape((b,) + tuple(out.shape[2:]))

@@ -25,42 +25,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax>=0.8 top-level location
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
 _NEG_INF = -1e30
-
-
-def _pvary(x, axis_name):
-    """Mark a value as varying over a mesh axis; lax.pvary is deprecated in
-    favor of lax.pcast(..., to='varying') — support both spellings."""
-    if hasattr(lax, "pcast"):
-        try:
-            return lax.pcast(x, axis_name, to="varying")
-        except TypeError:  # pragma: no cover — signature drift
-            pass
-    return lax.pvary(x, axis_name)
-
-
-def _no_vma_check_kw() -> dict:
-    """shard_map kwarg disabling the varying-mesh-axes checker (needed when
-    a Pallas call runs inside the body); older jax spells it check_rep."""
-    import inspect
-
-    try:
-        params = inspect.signature(shard_map).parameters
-    except (TypeError, ValueError):  # pragma: no cover
-        return {}
-    if "check_vma" in params:
-        return {"check_vma": False}
-    if "check_rep" in params:  # pragma: no cover — older jax
-        return {"check_rep": False}
-    return {}  # pragma: no cover
 
 
 def _ring_attention_local(q, k, v, axis_name: str, causal: bool, scale: float,
@@ -126,11 +94,13 @@ def _ring_attention_local(q, k, v, axis_name: str, causal: bool, scale: float,
     b, h, _, d = q.shape
     dv = v.shape[-1]
     n_static = lax.psum(1, axis_name)
-    # pvary: mark the zero-init accumulators as device-varying over the seq
-    # axis, matching the varying type the loop body produces.
-    acc0 = _pvary(jnp.zeros((b, h, s_local, dv), jnp.float32), axis_name)
-    m0 = _pvary(jnp.full((b, h, s_local, 1), _NEG_INF, jnp.float32), axis_name)
-    l0 = _pvary(jnp.zeros((b, h, s_local, 1), jnp.float32), axis_name)
+    # mark the zero-init accumulators as device-varying over the seq axis,
+    # matching the varying type the loop body produces.
+    acc0, m0, l0 = (
+        lax.pcast(t, axis_name, to="varying") for t in (
+            jnp.zeros((b, h, s_local, dv), jnp.float32),
+            jnp.full((b, h, s_local, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((b, h, s_local, 1), jnp.float32)))
     perm = [(j, (j + 1) % n_static) for j in range(n_static)]
     # n-1 rotating steps, then the last shard is consumed WITHOUT the final
     # ppermute pair (its result would be discarded — wasted ICI traffic).
@@ -222,11 +192,8 @@ def _flash_ring_supported(q, k, v, mesh, seq_axis) -> bool:
     """Auto-select gate: shapes must tile the kernel AND the backend must be
     a real TPU — off-TPU the kernel would run in interpret mode (orders of
     magnitude slower than the einsum body). Tests force use_flash=True."""
-    try:
-        on_tpu = jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        on_tpu = False
-    return on_tpu and _flash_ring_shapes_ok(q, k, v, mesh, seq_axis)
+    return (jax.default_backend() == "tpu"
+            and _flash_ring_shapes_ok(q, k, v, mesh, seq_axis))
 
 
 def ring_attention(q, k, v, mesh: Mesh, seq_axis: str = "seq",
@@ -252,7 +219,7 @@ def ring_attention(q, k, v, mesh: Mesh, seq_axis: str = "seq",
     spec = P(None, None, seq_axis, None)
     # pallas_call's out avals carry no varying-mesh-axes annotation, so the
     # vma checker can't see through the flash body — disable it there
-    kw = _no_vma_check_kw() if use_flash else {}
+    kw = {"check_vma": False} if use_flash else {}
     if key_mask is not None:
         def masked_body(q_, k_, v_, m_):
             return _ring_attention_local(q_, k_, v_, axis_name=seq_axis,
@@ -318,7 +285,7 @@ def ulysses_attention(q, k, v, mesh: Mesh, seq_axis: str = "seq",
         raise ValueError(f"n_heads ({q.shape[1]}) must divide by "
                          f"mesh axis '{seq_axis}' size ({n})")
     spec = P(None, None, seq_axis, None)
-    kw = _no_vma_check_kw()   # flash may engage inside on TPU
+    kw = {"check_vma": False}   # flash may engage inside on TPU
     if key_mask is not None:
         def masked_body(q_, k_, v_, m_):
             return _ulysses_local(q_, k_, v_, axis_name=seq_axis,

@@ -145,7 +145,21 @@ _TRANSPORT_ERRORS = (OSError, http.client.HTTPException)
 
 class WorkerBootError(RuntimeError):
     """A worker subprocess failed to reach ready within the boot
-    timeout (or exited during boot) — see its log file."""
+    timeout (or exited during boot). The message carries the end of the
+    worker's log: on a host with fewer chips than workers the second
+    worker dies there with libtpu's "Unable to initialize backend 'tpu'"
+    (one process per chip — docs/known-issues.md), and that line must
+    reach whoever called ``start()``."""
+
+
+def _log_tail(path: str, limit: int = 1500) -> str:
+    try:
+        with open(path, "rb") as f:
+            f.seek(0, os.SEEK_END)
+            f.seek(max(0, f.tell() - limit))
+            return f.read().decode(errors="replace").strip()
+    except OSError as e:
+        return f"(log unreadable: {e})"
 
 
 class NoLiveWorkersError(RuntimeError):
@@ -438,9 +452,10 @@ class FrontDoor:
         # so the merger can stamp them worker="frontdoor"
         self._proc_registry = MetricsRegistry()
         # zoo_build_info rides in _proc_registry so the merged scrape
-        # carries the family exactly once (worker="frontdoor"); the
-        # jax labels honestly read "unavailable" — this process is
-        # jax-free by design
+        # carries the family exactly once (worker="frontdoor"). This
+        # process must initialise no JAX backend — a parent that holds
+        # the chip starves the workers it spawns — so its backend label
+        # reads "uninitialized" (build_info never brings one up)
         build_info(self._proc_registry)
         # ops plane (ISSUE 17): the front door keeps its OWN flight
         # recorder of proxy-level request records — when a worker is
@@ -660,7 +675,8 @@ class FrontDoor:
             if proc.poll() is not None:
                 raise WorkerBootError(
                     f"worker {slot} exited with code {proc.returncode} "
-                    f"during boot (log: {log_path})")
+                    f"during boot (log: {log_path}):\n"
+                    f"{_log_tail(log_path)}")
             time.sleep(0.02)
         if info is None:
             proc.kill()
@@ -670,7 +686,8 @@ class FrontDoor:
                     f"front door stopped during boot of worker {slot}")
             raise WorkerBootError(
                 f"worker {slot} did not become ready within "
-                f"{self.config.worker_boot_timeout_s}s (log: {log_path})")
+                f"{self.config.worker_boot_timeout_s}s (log: {log_path}):\n"
+                f"{_log_tail(log_path)}")
         port = int(info["port"])
         # health gate: the server is listening, but rejoin only a worker
         # that answers — a respawn must never route traffic into a boot

@@ -6,7 +6,10 @@ The thin stdlib layer (no framework dependency — same stance as
 - ``POST /v1/models/<name>:predict`` (also
   ``/v1/models/<name>/versions/<v>:predict``) — body is either JSON
   ``{"instances": [...], "timeout_ms": <optional float>}`` or a raw
-  ``.npy`` array (``Content-Type: application/x-npy``). JSON replies with
+  ``.npy`` array (``Content-Type: application/x-npy``). A model registered
+  with several input arrays (BERT's ids / token types / mask) takes
+  ``{"inputs": [[...], [...], ...]}`` instead — one rectangular array per
+  model input, in registration order, equal leading axes. JSON replies with
   ``{"predictions": ...}``; non-finite floats (NaN/Inf) are encoded as
   ``null`` and flagged with a top-level ``"non_finite": true`` marker
   (``json.dumps`` would otherwise emit non-standard ``NaN``/``Infinity``
@@ -226,6 +229,17 @@ def _jsonable(out, nonfinite: Optional[Dict[str, bool]] = None):
             sanitized[mask] = None
             return sanitized.tolist()
     return arr.tolist()
+
+
+def _json_array(value, field: str) -> np.ndarray:
+    """One request array out of a JSON body: rectangular, floats as f32
+    (integer dtypes are coerced to the model's signature at submit)."""
+    a = np.asarray(value)
+    if a.dtype == object:
+        raise ValueError(f"{field} must form a rectangular array")
+    if np.issubdtype(a.dtype, np.floating):
+        a = a.astype(np.float32)
+    return a
 
 
 def make_handler(engine, max_body_bytes: int = DEFAULT_MAX_BODY_BYTES):
@@ -564,19 +578,25 @@ def make_handler(engine, max_body_bytes: int = DEFAULT_MAX_BODY_BYTES):
                 return
             self._send_json(200, result)
 
-        def _parse_body(self) -> Tuple[np.ndarray, Optional[float]]:
+        def _parse_body(self):
+            """``(x, timeout_ms)``: ``x`` is one array, or a list of arrays
+            for an ``"inputs"`` body."""
             body = self._read_raw_body()
             ctype = self.headers.get("Content-Type", "application/json")
             if "application/x-npy" in ctype:
                 return np.load(io.BytesIO(body), allow_pickle=False), None
             req = json.loads(body)
-            if "instances" not in req:
-                raise ValueError('JSON body needs an "instances" field')
-            x = np.asarray(req["instances"])
-            if x.dtype == object:
-                raise ValueError("instances must form a rectangular array")
-            if np.issubdtype(x.dtype, np.floating):
-                x = x.astype(np.float32)
+            if "inputs" in req:
+                if not isinstance(req["inputs"], list) or not req["inputs"]:
+                    raise ValueError('"inputs" must be a non-empty list '
+                                     "with one array per model input")
+                x = [_json_array(v, f"inputs[{i}]")
+                     for i, v in enumerate(req["inputs"])]
+            elif "instances" in req:
+                x = _json_array(req["instances"], "instances")
+            else:
+                raise ValueError('JSON body needs an "instances" field '
+                                 '(or "inputs" for a multi-input model)')
             timeout_ms = req.get("timeout_ms")
             return x, (float(timeout_ms) if timeout_ms is not None else None)
 

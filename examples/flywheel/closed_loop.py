@@ -157,5 +157,4 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     main()

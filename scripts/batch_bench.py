@@ -45,6 +45,8 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 ".."))
 
+from analytics_zoo_tpu.common.runtime import device_info  # noqa: E402
+
 
 def build_model(feature_dim: int, weights_path=None):
     """The bench classifier (serving_bench's shape) behind a fresh
@@ -271,8 +273,7 @@ def main(argv=None):
 
     record = {"bench": "batch_scoring", "restart": restart,
               "overlap": overlap,
-              "platform": "cpu" if os.environ.get(
-                  "JAX_PLATFORMS", "").startswith("cpu") else "auto"}
+              "device": device_info()}
     with open(args.out, "w") as f:
         json.dump(record, f, indent=2)
         f.write("\n")

@@ -94,6 +94,7 @@ def run_bench(samples: int, batch: int, workers: int, epochs: int,
     import optax
 
     import analytics_zoo_tpu as zoo
+    from analytics_zoo_tpu.common.runtime import device_info
     from analytics_zoo_tpu.data.feature_set import ArrayFeatureSet
     from analytics_zoo_tpu.data.pipeline import Pipeline
     from analytics_zoo_tpu.data.sources import ArraySource
@@ -231,8 +232,7 @@ def run_bench(samples: int, batch: int, workers: int, epochs: int,
     return {
         "metric": "input_pipeline_overlap",
         "host_cpus": os.cpu_count(),
-        "devices": len(jax.devices()),
-        "platform": jax.devices()[0].platform,
+        "device": device_info(),
         "samples": samples,
         "image_shape": [_IMG, _IMG, 3],
         "crop": _CROP,

@@ -22,9 +22,13 @@ rendezvous — docs/distributed-training.md has the execution model):
    checkpoint committed), and a restarted gang must finish with final
    params bitwise-identical to an uninterrupted reference gang's.
 
+CPU-only by construction: every simulated host is a CPU process with two
+forced host devices, so the script pins ``JAX_PLATFORMS=cpu`` itself; its
+step times measure the filesystem rendezvous, never a chip.
+
 ::
 
-    JAX_PLATFORMS=cpu python scripts/dist_train_bench.py
+    python scripts/dist_train_bench.py
 """
 
 from __future__ import annotations
@@ -39,8 +43,14 @@ import tempfile
 import time
 import uuid
 
+# CPU-only by construction (see the module docstring): pinned before jax
+# is imported, and inherited by every worker this script spawns.
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 sys.path.insert(0, REPO)
+
+from analytics_zoo_tpu.common.runtime import device_info  # noqa: E402
 
 ROWS, FEATURES, CLASSES = 256, 32, 8
 GLOBAL_BATCH, EPOCHS = 64, 3
@@ -257,7 +267,7 @@ def main(argv=None):
               f"ok: {report['kill_resume']['bitwise_identical_to_reference']}")
         assert report["kill_resume"]["bitwise_identical_to_reference"]
 
-    report["platform"] = "cpu"
+    report["device"] = device_info()
     with open(args.out, "w") as f:
         json.dump(report, f, indent=1)
         f.write("\n")

@@ -28,6 +28,10 @@ scheduler-bound, and the curves measure the fabric, not the hardware):
 ``--smoke`` shortens every cell for CI; the acceptance record is
 printed last either way and the "Fleet fabric" tier-1 step gates on
 ``kill_non_quota_client_errors == 0``. See docs/fleet.md.
+
+CPU-only by construction: the sleeper does no device work, so the script
+pins ``JAX_PLATFORMS=cpu`` itself (every worker inherits the pin) and its
+numbers say nothing about a chip.
 """
 
 from __future__ import annotations
@@ -45,8 +49,13 @@ import urllib.request
 
 import numpy as np
 
+# CPU-only by construction (see the module docstring): pinned before jax
+# is imported, and inherited by every worker this script spawns.
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.abspath(__file__)), ".."))
+
 
 SPEC = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                     "_frontdoor_bench_spec.py") + ":build_engine"
@@ -297,7 +306,6 @@ def main(argv=None) -> int:
         args.duration = min(args.duration, 1.5)
         args.coop_keys = min(args.coop_keys, 12)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     scale_cells = run_scaling(args)
     kill_cell = run_kill(args)
     coop_cell = run_coop_cache(args)

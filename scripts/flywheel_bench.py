@@ -39,6 +39,8 @@ import numpy as np
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.abspath(__file__)), ".."))
 
+from analytics_zoo_tpu.common.runtime import device_info  # noqa: E402
+
 
 class MatmulModel:
     """Duck-typed servable: a real (non-sleeping) numpy forward so the
@@ -415,8 +417,7 @@ def main(argv=None):
     doc = {
         "metric": "flywheel_capture_overhead_and_cycle_latency",
         "capture_overhead": overhead,
-        "platform": "cpu" if os.environ.get("JAX_PLATFORMS") == "cpu"
-        else os.environ.get("JAX_PLATFORMS", "default"),
+        "device": device_info(),
         "methodology": (
             "closed-loop clients against a numpy matmul servable through "
             "the ServingEngine, best-of-trials req/s capture-off vs "
@@ -468,5 +469,4 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     main()

@@ -165,7 +165,8 @@ PAGES = {
         "Mesh/runtime bootstrap (ref APIGuide/PipelineAPI/nnframes.md "
         "init_nncontext).",
         ["analytics_zoo_tpu.common.nncontext",
-         "analytics_zoo_tpu.common.config"]),
+         "analytics_zoo_tpu.common.config",
+         "analytics_zoo_tpu.common.runtime"]),
     "profiling": (
         "Profiling and tracing",
         "set_profile + xplane summaries (ref ProgrammingGuide).",

@@ -43,6 +43,7 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(_HERE, ".."))
 sys.path.insert(0, _HERE)  # sibling import: serving_bench's build_model
 
+from analytics_zoo_tpu.common.runtime import device_info  # noqa: E402
 from analytics_zoo_tpu.common.flight_recorder import (  # noqa: E402
     RequestRecord,
 )
@@ -144,8 +145,7 @@ def run_bench(clients: int, requests: int, trials: int,
         "trials_off": [round(r, 1) for r in rps_off],
         "overhead_pct": round(overhead, 2),
         "budget_pct": 2.0,
-        "platform": "cpu" if os.environ.get(
-            "JAX_PLATFORMS", "").startswith("cpu") else "auto",
+        "device": device_info(),
     }
 
 

@@ -11,7 +11,9 @@ and the cells measure the resilience layer, not the hardware. The claim
 under test (docs/resilience.md): past saturation, shedding the unmeetable
 requests at submit keeps goodput at capacity and accepted-request latency
 inside the deadline, while the no-shedding baseline queues everything and
-collapses into 504s. Runs anywhere (``JAX_PLATFORMS=cpu`` works).
+collapses into 504s. CPU-only by construction: the sleeper does no device
+work, so the script pins ``JAX_PLATFORMS=cpu`` itself (its front-door
+workers inherit the pin) and its numbers say nothing about a chip.
 
 Front-door mode (ISSUE 14) — ``--workers N`` — benches the horizontal
 tier instead: closed-loop HTTP clients through a
@@ -39,8 +41,14 @@ import time
 
 import numpy as np
 
+# CPU-only by construction (see the module docstring): pinned before jax
+# is imported, and inherited by every worker this script spawns.
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.abspath(__file__)), ".."))
+
+from analytics_zoo_tpu.common.runtime import device_info  # noqa: E402
 
 
 class SleepModel:
@@ -288,8 +296,7 @@ def run_frontdoor_suite(args):
             "kill_worker_respawned":
                 kill_cell.get("worker_respawned_and_rejoined", False),
         },
-        "platform": "cpu" if os.environ.get(
-            "JAX_PLATFORMS", "").startswith("cpu") else "auto",
+        "device": device_info(),
     }
     print(json.dumps(record["acceptance"]))
     with open(args.out_frontdoor, "w") as f:
@@ -359,8 +366,7 @@ def main(argv=None):
                 (on2["accepted_p99_ms"] is not None
                  and on2["accepted_p99_ms"] <= args.deadline_ms),
         },
-        "platform": "cpu" if os.environ.get(
-            "JAX_PLATFORMS", "").startswith("cpu") else "auto",
+        "device": device_info(),
     }
     print(json.dumps(record["acceptance"]))
     with open(args.out, "w") as f:

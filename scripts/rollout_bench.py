@@ -12,7 +12,9 @@ under test (docs/rollouts.md): a healthy canary reaches 100% with no
 goodput dip beyond noise, and a canary that fails every request is
 rolled back automatically with the client-visible error fraction bounded
 by the ladder's early rungs — the blast radius the ladder exists to
-bound. Runs anywhere (``JAX_PLATFORMS=cpu`` works).
+bound. CPU-only by construction: the sleeper does no device work, so the
+script pins ``JAX_PLATFORMS=cpu`` itself and its numbers say nothing about
+a chip.
 """
 
 from __future__ import annotations
@@ -27,8 +29,14 @@ import time
 
 import numpy as np
 
+# CPU-only by construction (see the module docstring): pinned before jax
+# is imported, and inherited by every worker this script spawns.
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.abspath(__file__)), ".."))
+
+from analytics_zoo_tpu.common.runtime import device_info  # noqa: E402
 
 
 class SleepModel:
@@ -188,8 +196,7 @@ def main(argv=None):
                 healthy["post_resolution_errors"] == 0
                 and broken["post_resolution_errors"] == 0,
         },
-        "platform": "cpu" if os.environ.get(
-            "JAX_PLATFORMS", "").startswith("cpu") else "auto",
+        "device": device_info(),
     }
     print(json.dumps(record["acceptance"]))
     with open(args.out, "w") as f:

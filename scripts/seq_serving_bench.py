@@ -47,6 +47,8 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 ".."))
 
+from analytics_zoo_tpu.common.runtime import device_info  # noqa: E402
+
 # Two model sizes: the smoke gate only checks parity and compile
 # counts, so it uses a tiny net; the full goodput record needs the
 # decode step's device time to dominate per-iteration host overhead
@@ -337,7 +339,6 @@ def main(argv=None):
     parser.add_argument("--out", default=None)
     args = parser.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     from analytics_zoo_tpu.serving.sequence import SequenceConfig
 
     if args.smoke:
@@ -440,8 +441,7 @@ def main(argv=None):
         "zero_serve_compiles": (cont["serve_compiles"] == 0
                                 and convoy["serve_compiles"] == 0
                                 and int8["serve_compiles"] == 0),
-        "platform": "cpu" if os.environ.get(
-            "JAX_PLATFORMS", "").startswith("cpu") else "auto",
+        "device": device_info(),
     }
     out_path = args.out or os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),

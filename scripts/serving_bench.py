@@ -38,6 +38,8 @@ import numpy as np
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.abspath(__file__)), ".."))
 
+from analytics_zoo_tpu.common.runtime import device_info  # noqa: E402
+
 
 def build_model(feature_dim: int, hidden=(64,)):
     """The web-service demo classifier shape: Dense trunk + softmax
@@ -154,8 +156,7 @@ def run_bench(clients: int, requests: int, max_batch: int,
         "flushes": m.flushes.value,
         "padded_rows": m.padded_rows.value,
         "executable_cache": dict(inf.cache_stats),
-        "platform": "cpu" if os.environ.get(
-            "JAX_PLATFORMS", "").startswith("cpu") else "auto",
+        "device": device_info(),
     }
     return record
 
@@ -307,8 +308,7 @@ def run_zipf_bench(s: float, clients: int, requests: int, max_batch: int,
             / max(1e-9, no_cache["requests_per_sec"]), 4),
         "bitwise_identical": with_cache["bitwise_identical"],
         "curve": curve,
-        "platform": "cpu" if os.environ.get(
-            "JAX_PLATFORMS", "").startswith("cpu") else "auto",
+        "device": device_info(),
     }
 
 
@@ -433,8 +433,7 @@ def run_mesh_bench(mesh_spec: str, feature_dim: int = 16,
         },
         "restart": restart,
         "aot_cache_dir": cache_dir,
-        "platform": "cpu" if os.environ.get(
-            "JAX_PLATFORMS", "").startswith("cpu") else "auto",
+        "device": device_info(),
     }
 
 

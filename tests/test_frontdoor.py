@@ -24,6 +24,7 @@ import pytest
 from analytics_zoo_tpu.serving.frontdoor import (
     FrontDoor,
     FrontDoorConfig,
+    WorkerBootError,
     merge_expositions,
 )
 from analytics_zoo_tpu.serving.quota import TenantQuota
@@ -96,6 +97,24 @@ def test_load_spec_contract(tmp_path):
 
 
 # -- predict + routing ------------------------------------------------------
+
+
+@_boots_workers
+def test_worker_boot_failure_is_loud(tmp_path):
+    """A worker that dies during boot (on a chip host: the second worker
+    of a one-chip machine, refused by libtpu) fails ``start()`` with the
+    end of that worker's log in the message, not just a path."""
+    spec = tmp_path / "dies.py"
+    spec.write_text("def build_engine():\n"
+                    "    raise RuntimeError('Unable to initialize backend')\n")
+    fd = FrontDoor(FrontDoorConfig(spec=f"{spec}:build_engine", workers=1,
+                                   worker_boot_timeout_s=60.0))
+    try:
+        with pytest.raises(WorkerBootError,
+                           match="Unable to initialize backend"):
+            fd.start()
+    finally:
+        fd.shutdown()
 
 
 @_boots_workers

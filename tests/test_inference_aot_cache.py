@@ -8,7 +8,6 @@ detected and counted."""
 import os
 
 import numpy as np
-import pytest
 
 import analytics_zoo_tpu as zoo
 from analytics_zoo_tpu.common.observability import (
@@ -20,15 +19,9 @@ from analytics_zoo_tpu.common.observability import (
 from analytics_zoo_tpu.inference.aot_cache import (
     _SUFFIX,
     AotExecutableCache,
-    serialization_available,
 )
 from analytics_zoo_tpu.inference.inference_model import InferenceModel
 from analytics_zoo_tpu.serving import BatcherConfig, ServingEngine
-
-pytestmark = pytest.mark.skipif(
-    not serialization_available(),
-    reason="this jax build has no jax.experimental.serialize_executable")
-
 
 def _build(names=("aot_dense_1", "aot_dense_2"), **kw):
     """A small classifier with EXPLICIT layer names: auto-naming counts

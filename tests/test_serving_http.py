@@ -71,6 +71,38 @@ def test_predict_npy_roundtrip(server):
     np.testing.assert_array_equal(np.load(io.BytesIO(body)), x * 2.0)
 
 
+def test_predict_json_multi_input():
+    """A model registered with several input arrays (BERT's ids / token
+    types / mask) takes ``{"inputs": [...]}``: one array per model input,
+    dtypes coerced to the registered signature."""
+    class Mix:
+        def do_predict(self, x):
+            ids, mask = x
+            assert ids.dtype == np.int32 and mask.dtype == np.float32
+            return ids.astype(np.float32) * mask
+
+    engine = ServingEngine()
+    engine.register("mix", Mix(), example_input=[
+        np.zeros((1, 3), np.int32), np.zeros((1, 3), np.float32)],
+        config=BatcherConfig(max_batch_size=8, max_wait_ms=1.0))
+    srv, _t = serve(engine, port=0)
+    try:
+        url = f"http://127.0.0.1:{srv.server_port}/v1/models/mix:predict"
+        ids, mask = [[1, 2, 3], [4, 5, 6]], [[1.0, 0.0, 1.0], [0.5, 1.0, 0.0]]
+        code, _, body = _post(url, json.dumps({"inputs": [ids, mask]}).encode())
+        assert code == 200
+        np.testing.assert_allclose(json.loads(body)["predictions"],
+                                   np.asarray(ids) * np.asarray(mask))
+        for bad in ({"inputs": []}, {"inputs": [ids]},
+                    {"inputs": [ids, [[1.0], [1.0, 2.0]]]}):
+            with pytest.raises(urllib.error.HTTPError) as e:
+                _post(url, json.dumps(bad).encode())
+            assert e.value.code == 400
+    finally:
+        srv.shutdown()
+        engine.shutdown()
+
+
 def test_versioned_route_and_unknown_model(server):
     base, _ = server
     payload = json.dumps({"instances": [[1.0, 1.0, 1.0]]}).encode()

@@ -19,14 +19,12 @@ import threading
 import urllib.request
 
 import numpy as np
-import pytest
 
 import analytics_zoo_tpu as zoo
 from analytics_zoo_tpu.common.observability import (
     get_registry,
     install_compile_listener,
 )
-from analytics_zoo_tpu.inference.aot_cache import serialization_available
 from analytics_zoo_tpu.inference.inference_model import InferenceModel
 from analytics_zoo_tpu.mesh import MeshConfig, ShardingPlan
 from analytics_zoo_tpu.serving import BatcherConfig, ServingEngine
@@ -191,11 +189,6 @@ def test_batch_job_sharded_bitwise_parity():
     np.testing.assert_array_equal(out, ref)
 
 
-needs_serialization = pytest.mark.skipif(
-    not serialization_available(),
-    reason="this jax build has no jax.experimental.serialize_executable")
-
-
 def _lifetime(cache_dir, sharded, names, warm_buckets=(16, 32)):
     """One simulated serving-process lifetime: fresh model + engine
     against ``cache_dir``, register (bucket warmup), one predict."""
@@ -214,7 +207,6 @@ def _lifetime(cache_dir, sharded, names, warm_buckets=(16, 32)):
     return np.asarray(out)
 
 
-@needs_serialization
 def test_warm_restart_under_data8_mesh_compiles_zero_times(tmp_path):
     compiles = _compile_counter()
     cache_dir = str(tmp_path / "aot")
@@ -232,7 +224,6 @@ def test_warm_restart_under_data8_mesh_compiles_zero_times(tmp_path):
     assert warm.shape == cold.shape
 
 
-@needs_serialization
 def test_single_device_and_sharded_entries_never_cross_hit(tmp_path):
     import os
 
