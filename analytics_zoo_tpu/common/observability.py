@@ -60,6 +60,7 @@ __all__ = [
     "MetricsRegistry",
     "Span",
     "Tracer",
+    "Phase",
     "get_registry",
     "get_tracer",
     "span",
@@ -724,11 +725,103 @@ class Tracer:
                 f.write(text)
         return text
 
+    def wall_spans_ns(self) -> List[Tuple[str, int, int]]:
+        """Collected spans as ``(name, start_ns, end_ns)`` on the
+        ``time.time_ns()`` clock — the form a device-trace reduction
+        takes as its host side (``benchmark/trace.py:reduce``), so the
+        program's spans can be laid over a ``jax.profiler`` trace tied to
+        the same clock. One anchor per export (:func:`wall_anchor_ns`),
+        not per span: the spans keep their monotonic spacing."""
+        anchor = wall_anchor_ns()
+        return [(s.name, anchor + int(s.start * 1e9),
+                 anchor + int(s.end * 1e9)) for s in self.spans()]
+
+
+class Phase:
+    """The one place a loop times a named phase: every pass through it
+    adds the elapsed ``perf_counter`` seconds to a metric child the loop
+    holds (``add`` — a counter's ``inc`` or a summary's ``observe``) and,
+    when the tracer is on, retires a span of the same name with the same
+    start and end. ``with phase:`` for a block, ``start()`` / ``stop()``
+    for an interval that ends elsewhere; ``stop`` without ``start`` does
+    nothing. ``seconds`` is this object's running total, ``last`` its
+    newest interval.
+
+    The span nests under the innermost live span of the thread, and
+    spans started inside the phase nest under it. On a thread that has no
+    such context (an infeed thread) pass the ``parent`` span: the phase
+    then joins that span's trace as its child. With the tracer off a pass
+    costs two clock reads and one ``add``; no registry look-up is on the
+    path."""
+
+    __slots__ = ("name", "seconds", "last", "_add", "_tracer", "_span_kw",
+                 "_ctx", "_t0")
+
+    def __init__(self, name: str, add, tracer: Optional[Tracer] = None,
+                 parent: Optional[Span] = None, **attrs):
+        self.name = name
+        self.seconds = 0.0
+        self.last = 0.0
+        self._add = add
+        self._tracer = tracer if tracer is not None else _global_tracer
+        self._span_kw = attrs
+        if parent is not None:
+            attrs.update(trace_id=parent.trace_id, parent_id=parent.span_id)
+        self._ctx = None
+        self._t0: Optional[float] = None
+
+    def start(self) -> "Phase":
+        """Open the interval (and, with the tracer on, its span)."""
+        if self._tracer.enabled:
+            ctx = self._tracer.span(self.name, **self._span_kw)
+            if ctx.__enter__() is not None:
+                self._ctx = ctx
+                self._t0 = ctx._t0      # the span's own start: one interval
+                return self
+        self._t0 = time.perf_counter()
+        return self
+
+    def stop(self, exc_type=None, exc=None, tb=None) -> None:
+        """Close the interval: add its seconds, retire its span (marked
+        with the exception's name when one is passed). No-op unless
+        started."""
+        if self._t0 is None:
+            return
+        ctx = self._ctx
+        if ctx is not None:
+            ctx.__exit__(exc_type, exc, tb)
+            self.last = ctx._span.duration
+            self._ctx = None
+        else:
+            self.last = time.perf_counter() - self._t0
+        self._t0 = None
+        self.seconds += self.last
+        self._add(self.last)
+
+    __enter__ = start
+
+    def __exit__(self, exc_type, exc, tb):
+        self.stop(exc_type, exc, tb)
+        return False
+
 
 def monotonic_s() -> float:
     """'Now' on the tracer time base (seconds since the process origin) —
     pair with :meth:`Tracer.record_span` explicit timestamps."""
     return time.perf_counter() - _T0
+
+
+def wall_anchor_ns() -> int:
+    """``time.time_ns()`` at this process's tracer origin. The two clocks
+    cannot be read at one instant, so each ``perf_counter_ns()`` is
+    followed by its ``time_ns()`` and the least difference of a few such
+    pairs is kept: a pair can only read late, by what ran between its two
+    reads. Sampled now, not cached (the wall clock may be stepped)."""
+    pairs = []
+    for _ in range(5):
+        mono = time.perf_counter_ns()
+        pairs.append(time.time_ns() - mono)
+    return min(pairs) + int(_T0 * 1e9)
 
 
 def wall_anchor() -> float:
@@ -739,7 +832,7 @@ def wall_anchor() -> float:
     anchor is *sampled now*, not cached — the residual skew between two
     processes' anchors is real measurement noise, which the front door's
     trace merge reports alongside the spans rather than hiding."""
-    return time.time() - monotonic_s()
+    return wall_anchor_ns() / 1e9
 
 
 _global_tracer = Tracer()
@@ -953,8 +1046,10 @@ def data_metrics() -> Dict[str, Any]:
     ``starvation_ratio`` (gauge ``zoo_data_starvation_ratio`` — the
     fraction of recent step wall-time spent waiting on the input
     iterator; near 1.0 means training is input-bound, near 0.0 means the
-    prefetcher keeps the device fed). One call per pipeline/epoch — the
-    caller holds the children."""
+    prefetcher keeps the device fed), and the ``Estimator.train`` infeed
+    thread's ``assemble_seconds`` / ``transfer_seconds`` (counters
+    ``zoo_data_assemble_seconds_total`` / ``zoo_data_transfer_seconds_total``).
+    One call per pipeline/epoch — the caller holds the children."""
     reg = get_registry()
     return {
         "samples": reg.counter(
@@ -980,6 +1075,14 @@ def data_metrics() -> Dict[str, Any]:
             "zoo_data_starvation_ratio",
             "Fraction of step wall-time spent waiting on the input "
             "iterator (1.0 = fully input-bound).").labels(),
+        "assemble_seconds": reg.counter(
+            "zoo_data_assemble_seconds_total",
+            "Seconds the train infeed thread spent taking host batches "
+            "from the dataset's iterator.").labels(),
+        "transfer_seconds": reg.counter(
+            "zoo_data_transfer_seconds_total",
+            "Seconds the train infeed thread spent handing host batches "
+            "to the device (device_put).").labels(),
     }
 
 
@@ -1112,9 +1215,14 @@ def distributed_metrics() -> Dict[str, Any]:
 def training_metrics() -> Dict[str, Any]:
     """The training metric children in the global registry:
     ``steps`` (counter ``zoo_train_steps_total``), ``step_seconds``
-    (summary ``zoo_train_step_seconds``) and ``items_per_sec`` (gauge
-    ``zoo_train_items_per_sec``). One call per ``train()`` — the loop
-    holds the children."""
+    (summary ``zoo_train_step_seconds``), ``items_per_sec`` (gauge
+    ``zoo_train_items_per_sec``), ``epochs`` (counter
+    ``zoo_train_epochs_total``) and the loop's clock, counters of
+    ``perf_counter`` seconds: ``call_seconds``, ``drain_seconds``,
+    ``host_seconds`` and ``fill_seconds``. With the consumer's waits
+    (``zoo_data_wait_seconds``) they partition a call: wait + drain +
+    host = call. One call per ``train()`` — the loop holds the
+    children."""
     reg = get_registry()
     return {
         "steps": reg.counter(
@@ -1128,6 +1236,24 @@ def training_metrics() -> Dict[str, Any]:
             "zoo_train_items_per_sec",
             "Training throughput over the most recent drain "
             "window.").labels(),
+        "epochs": reg.counter(
+            "zoo_train_epochs_total",
+            "Epochs finished by Estimator.train.").labels(),
+        "call_seconds": reg.counter(
+            "zoo_train_call_seconds_total",
+            "Seconds inside Estimator.train calls.").labels(),
+        "drain_seconds": reg.counter(
+            "zoo_train_drain_seconds_total",
+            "Seconds Estimator.train spent blocked on the device, "
+            "fetching losses.").labels(),
+        "host_seconds": reg.counter(
+            "zoo_train_host_seconds_total",
+            "Seconds of Estimator.train calls outside the input waits "
+            "and the drains: the host loop's own work.").labels(),
+        "fill_seconds": reg.counter(
+            "zoo_train_fill_seconds_total",
+            "Seconds from the top of a host-fed epoch to its first batch "
+            "out of the infeed queue (part of wait + host).").labels(),
     }
 
 

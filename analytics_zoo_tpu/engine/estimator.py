@@ -22,6 +22,7 @@ Model protocol (duck-typed; KerasNet and nnframes both implement it):
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import itertools
 import logging
@@ -41,6 +42,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from analytics_zoo_tpu.common.nncontext import get_nncontext
 from analytics_zoo_tpu.common.observability import (
+    Phase,
+    data_metrics,
     get_tracer,
     monotonic_s,
     training_metrics,
@@ -228,10 +231,12 @@ class _StepWatchdog:
 
 
 _SENTINEL = object()
+_UNTIMED = contextlib.nullcontext()
 
 
 def _device_prefetch(host_iter, transfer: Callable, depth: int = 2,
-                     on_dequeue: Optional[Callable] = None):
+                     on_dequeue: Optional[Callable] = None,
+                     wait=_UNTIMED, assemble=_UNTIMED, put=_UNTIMED):
     """Run host batch assembly + device_put in a background thread, ``depth``
     batches ahead of the consumer (the double-buffer that keeps the jitted
     step from ever waiting on input — SURVEY.md §7 hard-part #1; the
@@ -242,11 +247,14 @@ def _device_prefetch(host_iter, transfer: Callable, depth: int = 2,
     the *host-side* gather/augment cost; the bounded queue caps device-memory
     pressure at ``depth`` in-flight batches.
 
-    ``on_dequeue(wait_seconds, queue_depth)`` fires once per consumed batch
-    with the time the consumer spent blocked and the ready-queue depth right
-    after the take — the hook behind the ``zoo_data_*`` wait/starvation
-    instrumentation when the dataset is a streaming
-    :class:`~analytics_zoo_tpu.data.pipeline.Pipeline`.
+    ``wait``, ``assemble`` and ``put`` are the caller's clocks
+    (:class:`~analytics_zoo_tpu.common.observability.Phase`): ``wait`` around
+    each take of the consumer from the queue, and on the infeed thread
+    ``assemble`` around each ``next(host_iter)`` and ``put`` around each
+    ``transfer(item)`` (the thread's time blocked on a full queue is in
+    neither). ``on_dequeue(queue_depth)`` fires once per take with the
+    ready-queue depth right after it — the hook behind the ``zoo_data_*``
+    queue-depth and starvation gauges of ``Estimator.train``.
     """
     q: queue_lib.Queue = queue_lib.Queue(maxsize=depth)
     stop = threading.Event()  # set when the consumer abandons the epoch early
@@ -262,8 +270,17 @@ def _device_prefetch(host_iter, transfer: Callable, depth: int = 2,
 
     def worker():
         try:
-            for item in host_iter:
-                if not _put(("ok", transfer(item))):
+            it = iter(host_iter)
+            while True:
+                with assemble:
+                    item = next(it, _SENTINEL)
+                if item is _SENTINEL:
+                    break
+                with put:
+                    placed = ("ok", transfer(item))
+                # `item` lives on until the next one is taken, as it did in a
+                # plain for-loop: the transfer it feeds is asynchronous
+                if not _put(placed):
                     return
         except BaseException as e:  # noqa: BLE001 — re-raised in consumer
             _put(("err", e))
@@ -274,10 +291,10 @@ def _device_prefetch(host_iter, transfer: Callable, depth: int = 2,
     t.start()
     try:
         while True:
-            w0 = time.perf_counter()
-            item = q.get()
+            with wait:
+                item = q.get()
             if on_dequeue is not None:
-                on_dequeue(time.perf_counter() - w0, q.qsize())
+                on_dequeue(q.qsize())
             if item is _SENTINEL:
                 return
             tag, payload = item
@@ -1172,7 +1189,39 @@ class Estimator:
         data-iterator offset within an interrupted epoch — so the resumed
         trajectory is bitwise the uninterrupted one
         (docs/fault-tolerance.md).
+
+        The call keeps its own clock (docs/observability.md): its seconds
+        go to ``zoo_train_call_seconds_total`` and are split, always, into
+        the loop's waits on the infeed queue (``zoo_data_wait_seconds``),
+        its waits on the device (``zoo_train_drain_seconds_total``) and
+        the rest, the host's own work (``zoo_train_host_seconds_total``);
+        with the tracer on the same intervals are ``train.*`` spans under
+        one ``train.call``.
         """
+        obs = training_metrics()
+        call = Phase("train.call", obs["call_seconds"].inc)
+        wait = Phase("train.infeed_wait",
+                     data_metrics()["wait_seconds"].observe)
+        drain = Phase("train.drain", obs["drain_seconds"].inc)
+        try:
+            with call:
+                self._train(obs, wait, drain, train_set, criterion,
+                            end_trigger, checkpoint_trigger, validation_set,
+                            validation_method, batch_size,
+                            validation_batch_size, auto_resume)
+        finally:
+            # the waits and drains lie inside the call, so what is left is
+            # the host's; max() only guards the float's last bit
+            obs["host_seconds"].inc(
+                max(0.0, call.last - wait.seconds - drain.seconds))
+        return self
+
+    def _train(self, obs, wait: Phase, drain: Phase, train_set, criterion,
+               end_trigger, checkpoint_trigger, validation_set,
+               validation_method, batch_size, validation_batch_size,
+               auto_resume) -> None:
+        """The body of :meth:`train`, inside its ``train.call`` phase;
+        ``wait`` and ``drain`` are the clocks of its two kinds of wait."""
         if (auto_resume and self._checkpoint_path is not None
                 and self.run_state.iteration == 0):
             # process-restart entry: a warm estimator (iteration > 0) is
@@ -1211,18 +1260,17 @@ class Estimator:
         steps_this_call = 0
         watchdog = None
         tracer = get_tracer()
-        obs = training_metrics()
+        data_obs = data_metrics()
+        fill = Phase("train.fill", obs["fill_seconds"].inc, tracer)
 
         # Streaming-pipeline integration (data/pipeline.py). A Pipeline is
         # consumed through the same duck-typed train_batches protocol as any
-        # FeatureSet, but three contracts upgrade when one is passed:
-        # the infeed thread adopts the pipeline's .prefetch(k) depth, the
-        # consumer side feeds the zoo_data_* wait/starvation gauges, and
+        # FeatureSet, but two contracts upgrade when one is passed:
+        # the infeed thread adopts the pipeline's .prefetch(k) depth, and
         # every checkpoint carries the resumable stream position
         # (state_dict -> ft metadata; see _write_checkpoint).
         is_stream = hasattr(train_set, "note_queue_depth")
-        infeed_depth = 2
-        on_dequeue = None
+        infeed_depth = int(getattr(train_set, "prefetch_depth", 0) or 2)
         if self._restored_data_state is not None:
             if int(self._restored_data_state.get("position_batches", 0)) == 0:
                 # epoch-boundary checkpoint: there is no mid-epoch offset
@@ -1242,23 +1290,19 @@ class Estimator:
                     "is ignored (epoch_step still resumes the batch "
                     "offset)", type(train_set).__name__)
             self._restored_data_state = None
-        if is_stream:
-            infeed_depth = int(getattr(train_set, "prefetch_depth", 0) or 2)
-            from analytics_zoo_tpu.common.observability import data_metrics
+        note_depth = getattr(train_set, "note_queue_depth", None)
+        call_t0 = time.perf_counter()
 
-            data_obs = data_metrics()
-            infeed_t0 = time.perf_counter()
-            infeed_waited = [0.0]
-
-            def on_dequeue(wait_s, qdepth, _dm=data_obs, _w=infeed_waited,
-                           _t0=infeed_t0):
-                _w[0] += wait_s
-                train_set.note_queue_depth(qdepth + 1)
-                _dm["queue_depth"].set(qdepth)
-                _dm["wait_seconds"].observe(wait_s)
-                elapsed = time.perf_counter() - _t0
-                if elapsed > 0:
-                    _dm["starvation_ratio"].set(min(1.0, _w[0] / elapsed))
+        def on_dequeue(qdepth):
+            # every host-fed set: the zoo_data_* gauges say whether the
+            # input path keeps up (the waits themselves are `wait`'s)
+            if note_depth is not None:
+                note_depth(qdepth + 1)
+            data_obs["queue_depth"].set(qdepth)
+            elapsed = time.perf_counter() - call_t0
+            if elapsed > 0:
+                data_obs["starvation_ratio"].set(
+                    min(1.0, wait.seconds / elapsed))
         self._active_train_set = train_set if is_stream else None
 
         # Chunked dispatch (see _make_train_scan): K steps per call when the
@@ -1419,19 +1463,27 @@ class Estimator:
             xs, y = host_batch
             return (_shard(mesh, xs), _shard(mesh, y))
 
+        # the paths that take their batches from the infeed thread, one
+        # dispatch a step (the fused ones upload an index plan or a key)
+        host_fed = fit_fn is None and epoch_fn is None and scan_fn is None
+        epoch_ctx = None
         try:
             # started inside the try so any raise is guaranteed to reach
             # the finally-stop (a leaked daemon would alarm on a dead run)
             if self._watchdog:
                 watchdog = _StepWatchdog(rs, *self._watchdog).start()
             while not end_trigger(rs):
+                epoch_ctx = tracer.span("train.epoch", epoch=rs.epoch)
+                epoch_span = epoch_ctx.__enter__()
+                if host_fed:
+                    fill.start()      # until the first batch is out
                 rs.epoch_finished = False
                 # >0 only right after a mid-epoch resume: the number of
                 # this epoch's batches the interrupted run already consumed
                 # (epoch order is a pure function of seed=rs.epoch, so
                 # skipping exactly that many continues the trajectory)
                 resume_skip = rs.epoch_step
-                epoch_start = time.time()
+                epoch_start = time.perf_counter()
                 epoch_loss, epoch_batches = 0.0, 0
                 # (first_iteration, device losses) — a scalar loss for the
                 # per-step path, a (K,) vector for one scan/epoch dispatch
@@ -1442,11 +1494,13 @@ class Estimator:
                     nonlocal epoch_loss, epoch_batches, last_drain_t
                     first_it, dev_losses = pending.popleft()
                     # ONE fetch; ravel: the fused-fit path yields (E, steps)
-                    vals = np.atleast_1d(np.asarray(dev_losses)).ravel()
+                    with drain:     # the host waits for the device here
+                        vals = np.asarray(dev_losses)
+                    vals = np.atleast_1d(vals).ravel()
                     rs.loss = float(vals[-1])
                     epoch_loss += float(vals.sum())
                     epoch_batches += len(vals)
-                    now = time.time()
+                    now = time.perf_counter()
                     dt = now - last_drain_t
                     last_drain_t = now
                     # training metric families (drain granularity: a fused
@@ -1485,6 +1539,7 @@ class Estimator:
                         _drain_one()
                     # the loop tail accounts for ONE epoch; own the rest
                     rs.epoch += fit_epochs - 1
+                    obs["epochs"].inc(fit_epochs - 1)
                     logger.info(
                         "Epochs %d-%d fused into one dispatch (%d steps)",
                         rs.epoch - fit_epochs + 2, rs.epoch + 1,
@@ -1585,9 +1640,16 @@ class Estimator:
                                 **skip_kw, **kw),
                             window),
                         resume_skip)
-                for batch in _device_prefetch(host_iter, _transfer,
-                                              depth=infeed_depth,
-                                              on_dequeue=on_dequeue):
+                for batch in _device_prefetch(
+                        host_iter, _transfer, depth=infeed_depth,
+                        on_dequeue=on_dequeue, wait=wait,
+                        assemble=Phase("infeed.assemble",
+                                       data_obs["assemble_seconds"].inc,
+                                       tracer, parent=epoch_span),
+                        put=Phase("infeed.transfer",
+                                  data_obs["transfer_seconds"].inc,
+                                  tracer, parent=epoch_span)):
+                    fill.stop()
                     rng = self.ctx.next_rng_key()
                     _profiler_tick()
                     with tracer.span("train.dispatch", kind="step"):
@@ -1604,14 +1666,16 @@ class Estimator:
                         break
                     if checkpoint_trigger(rs) and not isinstance(checkpoint_trigger, EveryEpoch):
                         self._maybe_checkpoint()
+                fill.stop()     # an epoch with no batch left to take
                 while pending:
                     _drain_one()
                 rs.epoch += 1
+                obs["epochs"].inc()
                 rs.epoch_step = 0
                 rs.epoch_finished = True
                 logger.info(
                     "Epoch %d done in %.2fs — mean loss %.5f",
-                    rs.epoch, time.time() - epoch_start,
+                    rs.epoch, time.perf_counter() - epoch_start,
                     epoch_loss / max(epoch_batches, 1))
                 # non-stepping phases: the iteration counter legitimately
                 # stalls here (checkpoint write/allgather, a whole
@@ -1635,10 +1699,15 @@ class Estimator:
                 # epoch boundary: the fused/epoch dispatch paths check here
                 # (per-step paths already checked every iteration)
                 self._check_preemption(watchdog)
+                epoch_ctx.__exit__(None, None, None)
+                epoch_ctx = None
             # surface async checkpoint-writer failures to the caller, and
             # guarantee every triggered save is durable before returning
             self._drain_checkpoints()
         finally:
+            if epoch_ctx is not None:   # an epoch that raised: close its spans
+                fill.stop(*sys.exc_info())
+                epoch_ctx.__exit__(*sys.exc_info())
             self._active_train_set = None
             if watchdog is not None:
                 watchdog.stop()
