@@ -1048,7 +1048,9 @@ def data_metrics() -> Dict[str, Any]:
     iterator; near 1.0 means training is input-bound, near 0.0 means the
     prefetcher keeps the device fed), and the ``Estimator.train`` infeed
     thread's ``assemble_seconds`` / ``transfer_seconds`` (counters
-    ``zoo_data_assemble_seconds_total`` / ``zoo_data_transfer_seconds_total``).
+    ``zoo_data_assemble_seconds_total`` / ``zoo_data_transfer_seconds_total``)
+    and ``borrowed_batches`` (counter ``zoo_data_borrowed_batches_total`` —
+    batches it placed straight from a buffer the dataset lent, no host copy).
     One call per pipeline/epoch — the caller holds the children."""
     reg = get_registry()
     return {
@@ -1082,7 +1084,12 @@ def data_metrics() -> Dict[str, Any]:
         "transfer_seconds": reg.counter(
             "zoo_data_transfer_seconds_total",
             "Seconds the train infeed thread spent handing host batches "
-            "to the device (device_put).").labels(),
+            "to the device (device_put, and for a borrowed batch the wait "
+            "until its copy has left the host).").labels(),
+        "borrowed_batches": reg.counter(
+            "zoo_data_borrowed_batches_total",
+            "Train batches placed on the device straight from a buffer the "
+            "dataset lent (no host copy in between).").labels(),
     }
 
 

@@ -101,9 +101,10 @@ class NativeCachedFeatureSet(FeatureSet):
                 off += nb
         return self._split(outs)
 
-    def batches(self, batch_size: int, shuffle: bool = True, seed: int = 0,
-                drop_remainder: bool = False):
-        """Hot path: batches come out of the native prefetch ring."""
+    def _ring_batches(self, batch_size: int, shuffle: bool, seed: int,
+                      drop_remainder: bool = False):
+        """One epoch out of the native prefetch ring: each batch's components
+        as views into the ring's slot, valid until the next step."""
         from analytics_zoo_tpu import native
 
         pf = self._prefetchers.get(batch_size)
@@ -115,23 +116,39 @@ class NativeCachedFeatureSet(FeatureSet):
         order = np.arange(self._n, dtype=np.uint64)
         if shuffle:
             np.random.default_rng(seed).shuffle(order)
-        for comps in pf.epoch(order, drop_remainder=drop_remainder):
-            # The views die when the slot is recycled after the generator
-            # resumes, and JAX host->device transfers are asynchronous (a
-            # device array may still reference the host buffer then) — so
-            # hand the consumer its own copy. The copy is one straight
-            # memcpy; the scatter-gather assembly stays on the C++ threads.
-            # Zero-copy consumers that block on the transfer themselves can
-            # use NativePrefetcher.epoch() directly.
+        yield from pf.epoch(order, drop_remainder=drop_remainder)
+
+    def batches(self, batch_size: int, shuffle: bool = True, seed: int = 0,
+                drop_remainder: bool = False):
+        """Hot path: batches come out of the native prefetch ring, each the
+        caller's own copy to keep (one straight memcpy; the scatter-gather
+        assembly stays on the C++ threads)."""
+        for comps in self._ring_batches(batch_size, shuffle, seed,
+                                        drop_remainder):
             yield self._split([np.array(c) for c in comps])
 
-    def train_batches(self, batch_size: int, shuffle: bool = True, seed: int = 0):
+    def train_batches(self, batch_size: int, shuffle: bool = True, seed: int = 0,
+                      borrowed: bool = False):
         """Masked variant on top of the native ring: the C++ assembler
         wrap-pads the tail batch (zoo_native.cpp, same contract as
-        FeatureSet.batches), so only the last batch's mask differs."""
+        FeatureSet.batches), so only the last batch's mask differs.
+
+        ``borrowed=True`` is the lending form: the same batches in the same
+        order without the copy, ``x`` and ``y`` as views into the ring's
+        slot. The contract is ``NativePrefetcher.epoch``'s: a batch is valid
+        until the iterator's next step, which hands its slot back to the C++
+        workers to refill. The borrower must be done reading it by then: a
+        host-to-device transfer started from it must have finished, and
+        where the placed array can alias host memory (``device_put`` on the
+        CPU backend does: the slots are 64-byte aligned) the batch has to be
+        copied first. ``Estimator.train`` borrows; a caller that may keep
+        what it is given leaves the default."""
         tail = self._n % batch_size
         n_batches = -(-self._n // batch_size)
-        for b, (x, y) in enumerate(self.batches(batch_size, shuffle, seed)):
+        batches = (map(self._split,
+                       self._ring_batches(batch_size, shuffle, seed))
+                   if borrowed else self.batches(batch_size, shuffle, seed))
+        for b, (x, y) in enumerate(batches):
             mask = np.ones(batch_size, np.float32)
             if tail and b == n_batches - 1:
                 mask[tail:] = 0.0

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import contextlib
 import hashlib
+import inspect
 import itertools
 import logging
 import os
@@ -236,7 +237,8 @@ _UNTIMED = contextlib.nullcontext()
 
 def _device_prefetch(host_iter, transfer: Callable, depth: int = 2,
                      on_dequeue: Optional[Callable] = None,
-                     wait=_UNTIMED, assemble=_UNTIMED, put=_UNTIMED):
+                     wait=_UNTIMED, assemble=_UNTIMED, put=_UNTIMED,
+                     borrowed: bool = False):
     """Run host batch assembly + device_put in a background thread, ``depth``
     batches ahead of the consumer (the double-buffer that keeps the jitted
     step from ever waiting on input — SURVEY.md §7 hard-part #1; the
@@ -247,14 +249,28 @@ def _device_prefetch(host_iter, transfer: Callable, depth: int = 2,
     the *host-side* gather/augment cost; the bounded queue caps device-memory
     pressure at ``depth`` in-flight batches.
 
+    The lending contract. By default an item is the iterator's to give: the
+    thread keeps it alive until it takes the next one and never waits for
+    the transfer. ``borrowed`` says the items are lent (views into a buffer
+    the iterator refills on its next step: ``train_batches(borrowed=True)``
+    of ``data/pmem.py``). The thread then blocks until the placed batch is
+    ready (``jax.block_until_ready``) before it steps the iterator, or
+    leaves it. Where the queue has room, as at an epoch's start, the batch
+    goes on it before that wait, so the consumer's dispatch overlaps the
+    copy; where it is full the consumer has batches in hand and the wait
+    comes first. That suffices where the transfer is a copy; where the
+    placed array can alias the host's (``_aliases_host``) it is ``transfer``
+    that has to copy a lent item first.
+
     ``wait``, ``assemble`` and ``put`` are the caller's clocks
     (:class:`~analytics_zoo_tpu.common.observability.Phase`): ``wait`` around
     each take of the consumer from the queue, and on the infeed thread
     ``assemble`` around each ``next(host_iter)`` and ``put`` around each
-    ``transfer(item)`` (the thread's time blocked on a full queue is in
-    neither). ``on_dequeue(queue_depth)`` fires once per take with the
-    ready-queue depth right after it — the hook behind the ``zoo_data_*``
-    queue-depth and starvation gauges of ``Estimator.train``.
+    ``transfer(item)`` and, for a borrowed item, the wait for its copy to
+    finish (the thread's time blocked on a full queue is in neither).
+    ``on_dequeue(queue_depth)`` fires once per take with the ready-queue
+    depth right after it — the hook behind the ``zoo_data_*`` queue-depth
+    and starvation gauges of ``Estimator.train``.
     """
     q: queue_lib.Queue = queue_lib.Queue(maxsize=depth)
     stop = threading.Event()  # set when the consumer abandons the epoch early
@@ -268,6 +284,13 @@ def _device_prefetch(host_iter, transfer: Callable, depth: int = 2,
                 continue
         return False
 
+    def _offer(item) -> bool:
+        try:
+            q.put_nowait(item)
+            return True
+        except queue_lib.Full:
+            return False
+
     def worker():
         try:
             it = iter(host_iter)
@@ -277,10 +300,14 @@ def _device_prefetch(host_iter, transfer: Callable, depth: int = 2,
                 if item is _SENTINEL:
                     break
                 with put:
-                    placed = ("ok", transfer(item))
-                # `item` lives on until the next one is taken, as it did in a
-                # plain for-loop: the transfer it feeds is asynchronous
-                if not _put(placed):
+                    placed = transfer(item)
+                    sent = borrowed and _offer(("ok", placed))
+                    if borrowed:    # the lender gets its buffer back after this
+                        jax.block_until_ready(placed)
+                # an `item` that is not lent lives on until the next one is
+                # taken, as it did in a plain for-loop: the transfer it feeds
+                # is asynchronous
+                if not sent and not _put(("ok", placed)):
                     return
         except BaseException as e:  # noqa: BLE001 — re-raised in consumer
             _put(("err", e))
@@ -324,6 +351,22 @@ def _shard(mesh, v):
     if isinstance(v, (list, tuple)):
         return tuple(shard_batch(mesh, t) for t in v)
     return shard_batch(mesh, v)
+
+
+def _aliases_host(mesh) -> bool:
+    """Whether an array placed on ``mesh`` can share memory with the host
+    array it was put from: the CPU backend's ``device_put`` aliases an
+    aligned buffer instead of copying it, so "ready" never means "copied"
+    there. Every other backend's transfer is a copy to another memory."""
+    return any(d.platform == "cpu" for d in mesh.devices.flat)
+
+
+def _names_parameter(fn, name: str) -> bool:
+    """Whether ``fn`` (or ``None``) declares a parameter called ``name``."""
+    try:
+        return name in inspect.signature(fn).parameters
+    except (TypeError, ValueError):     # not callable, or no signature
+        return False
 
 
 def _skip_steps(make_iter, k: int):
@@ -1271,6 +1314,15 @@ class Estimator:
         # (state_dict -> ft metadata; see _write_checkpoint).
         is_stream = hasattr(train_set, "note_queue_depth")
         infeed_depth = int(getattr(train_set, "prefetch_depth", 0) or 2)
+        # A set whose train_batches can lend (`borrowed=True`: views into its
+        # own ring, valid until the iterator's next step) is fed straight
+        # from them: no host copy between the set and the device
+        # (_device_prefetch has the contract). An override that does not name
+        # the parameter is fed its own batches, as before.
+        lends = gather is None and _names_parameter(
+            getattr(train_set, "train_batches", None), "borrowed")
+        copy_lent = lends and _aliases_host(self.ctx.mesh)
+        lend_kw = {"borrowed": True} if lends else {}
         if self._restored_data_state is not None:
             if int(self._restored_data_state.get("position_batches", 0)) == 0:
                 # epoch-boundary checkpoint: there is no mid-epoch offset
@@ -1456,6 +1508,10 @@ class Estimator:
             if gather is not None:  # (indices, mask): tiny per-step infeed
                 idx, mask = host_batch
                 return shard_batch(mesh, idx), shard_batch(mesh, mask)
+            if copy_lent:
+                host_batch = jax.tree_util.tree_map(np.array, host_batch)
+            elif lends:
+                data_obs["borrowed_batches"].inc()
             if len(host_batch) == 3:
                 xs, y, mask = host_batch
                 return (_shard(mesh, xs), _shard(mesh, y),
@@ -1629,7 +1685,7 @@ class Estimator:
                         lambda **skip_kw: _windowed_iter(
                             lambda **kw: train_set.train_batches(
                                 batch_size, shuffle=True, seed=rs.epoch,
-                                **skip_kw, **kw),
+                                **lend_kw, **skip_kw, **kw),
                             window),
                         resume_skip)
                 else:
@@ -1642,7 +1698,7 @@ class Estimator:
                         resume_skip)
                 for batch in _device_prefetch(
                         host_iter, _transfer, depth=infeed_depth,
-                        on_dequeue=on_dequeue, wait=wait,
+                        on_dequeue=on_dequeue, wait=wait, borrowed=lends,
                         assemble=Phase("infeed.assemble",
                                        data_obs["assemble_seconds"].inc,
                                        tracer, parent=epoch_span),

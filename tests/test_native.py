@@ -146,3 +146,94 @@ def test_multi_component_feature_set():
     for (bx1, bx2), by in fs.batches(6, shuffle=False):
         assert bx1.shape == (6, 4) and bx2.shape == (6, 2) and by.shape == (6, 1)
     fs.close()
+
+
+# ---------------------------------------------------------------------------
+# batches lent from the ring (ISSUE 27)
+# ---------------------------------------------------------------------------
+
+
+def _lending_set(multi=False, n_slots=2):
+    from analytics_zoo_tpu.data.pmem import NativeCachedFeatureSet
+
+    rng = np.random.default_rng(5)
+    x = rng.normal(size=(23, 5)).astype(np.float32)
+    y = rng.integers(0, 3, size=23).astype(np.int32)
+    if multi:
+        x = [x, rng.integers(0, 9, size=(23, 2)).astype(np.int32)]
+    return NativeCachedFeatureSet(x, y, n_slots=n_slots)
+
+
+def _addresses(batch):
+    import jax
+
+    return [a.ctypes.data for a in jax.tree_util.tree_leaves(batch[:2])]
+
+
+@pytest.mark.parametrize("multi", [False, True], ids=["one_x", "two_x"])
+def test_borrowed_batches_are_the_train_batches_without_the_copy(multi):
+    import jax
+
+    fs = _lending_set(multi)
+    for seed in (0, 7):
+        own = list(fs.train_batches(8, shuffle=True, seed=seed))
+        seen = []
+        for b, lent in enumerate(fs.train_batches(8, shuffle=True, seed=seed,
+                                                  borrowed=True)):
+            # same batches, same wrap-padded tail, same mask, same order
+            jax.tree_util.tree_map(np.testing.assert_array_equal, lent, own[b])
+            assert (jax.tree_util.tree_structure(lent)
+                    == jax.tree_util.tree_structure(own[b]))
+            assert not any(a.flags.owndata
+                           for a in jax.tree_util.tree_leaves(lent[:2]))
+            seen.append(_addresses(lent))
+        assert len(seen) == len(own) == 3
+        assert own[-1][2].tolist() == [1.0] * 7 + [0.0]
+        # views into the ring's two slots, handed round; the copies are not
+        assert seen[0] == seen[2] != seen[1]
+        assert len({tuple(_addresses(o)) for o in own}) == 3
+    fs.close()
+
+
+def test_a_borrowed_batch_is_overwritten_after_the_next_step_a_copy_is_not():
+    fs = _lending_set(n_slots=2)
+    own = [x for x, _, _ in fs.train_batches(8, shuffle=False)]
+    it = fs.train_batches(8, shuffle=False, borrowed=True)
+    first = next(it)[0]
+    np.testing.assert_array_equal(first, own[0])
+    next(it)
+    third = next(it)[0]             # batch 2 lands in batch 0's slot
+    assert third.ctypes.data == first.ctypes.data
+    np.testing.assert_array_equal(first, own[2])
+    fs.close()
+
+
+def test_borrowed_batches_skip_and_window_like_the_copies():
+    """Neither form takes ``start_step`` or ``window``: the estimator's helpers
+    fall back for both alike (skipped batches are assembled and dropped,
+    the window is sliced after the take), and give the same rows."""
+    import jax
+
+    from analytics_zoo_tpu.engine.estimator import _skip_steps, _windowed_iter
+
+    fs = _lending_set()
+
+    def batches(lend_kw, skip, window):
+        out = []
+        it = _skip_steps(
+            lambda **skip_kw: _windowed_iter(
+                lambda **kw: fs.train_batches(8, shuffle=True, seed=3,
+                                              **lend_kw, **skip_kw, **kw),
+                window), skip)
+        for item in it:
+            out.append(jax.tree_util.tree_map(np.array, item))
+        return out
+
+    for skip, window in ((0, None), (2, None), (1, (2, 6)), (3, None)):
+        own = batches({}, skip, window)
+        lent = batches({"borrowed": True}, skip, window)
+        assert len(own) == len(lent) == 3 - skip
+        jax.tree_util.tree_map(np.testing.assert_array_equal, lent, own)
+        if window:
+            assert all(b[0].shape == (4, 5) for b in lent)
+    fs.close()
