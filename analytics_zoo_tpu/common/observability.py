@@ -1264,6 +1264,38 @@ def training_metrics() -> Dict[str, Any]:
     }
 
 
+def lm_train_metrics() -> Dict[str, Any]:
+    """What a language model's train step reports beside its loss, fed at
+    ``train.drain`` from the statistics the step returned (no fetch of its
+    own): ``tokens`` (counter ``zoo_train_tokens_total``), ``assignments``
+    (labeled counter ``zoo_moe_assignments_total{held="true"|"false"}``: a
+    token's pick of an expert, by whether this chip holds that expert) and
+    the summaries ``load_max`` / ``load_mean`` (``zoo_moe_expert_tokens_max``
+    / ``zoo_moe_expert_tokens_mean``: a sample a step and expert layer, the
+    most and the mean tokens over the experts held). One call per model —
+    the model holds the children."""
+    reg = get_registry()
+    assignments = reg.counter(
+        "zoo_moe_assignments_total",
+        "Token-to-expert assignments routed by expert layers in training, "
+        "by whether the expert is held here.", labels=("held",))
+    return {
+        "tokens": reg.counter(
+            "zoo_train_tokens_total",
+            "Tokens a language model's train steps computed.").labels(),
+        "assignments_held": assignments.labels(held="true"),
+        "assignments_absent": assignments.labels(held="false"),
+        "load_max": reg.summary(
+            "zoo_moe_expert_tokens_max",
+            "Most tokens routed to one held expert, a sample a step and "
+            "expert layer.").labels(),
+        "load_mean": reg.summary(
+            "zoo_moe_expert_tokens_mean",
+            "Mean tokens routed to a held expert, a sample a step and "
+            "expert layer.").labels(),
+    }
+
+
 def capture_metrics() -> Dict[str, Any]:
     """The serving capture tap's metric children in the global registry
     (:mod:`analytics_zoo_tpu.flywheel.capture`): ``sampled`` (counter

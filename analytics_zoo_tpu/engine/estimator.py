@@ -1056,7 +1056,11 @@ class Estimator:
                 xs = device_transform(xs)
             pred, new_state = model.apply(cast(params), model_state, cast(xs),
                                           training=True, rng=rng)
-            if hasattr(pred, "astype"):
+            if hasattr(pred, "astype") and not getattr(
+                    criterion, "takes_compute_dtype", False):
+                # (a loss over [rows, tokens, vocabulary] logits upcasts a
+                # block of tokens at a time itself: a float32 copy of the
+                # whole batch's logits is 1.6 GB at 16 384 x 25 024)
                 pred = pred.astype(jnp.float32)
             if mask is not None and ps_criterion is not None:
                 # exact tail-batch semantics: wrap-pad duplicates get zero
@@ -1082,6 +1086,7 @@ class Estimator:
             opt_shardings = self._opt_state_shardings(self.tstate.opt_state)
         update_mask = (self._update_mask(self.tstate.params)
                        if self.tstate is not None else None)
+        stats_fn = getattr(model, "train_stats", None)
 
         def train_step(tstate: TrainState, batch, rng, cache=None):
             if device_gather is not None:
@@ -1103,28 +1108,39 @@ class Estimator:
                 grads = jax.tree_util.tree_map(
                     lambda g, m: g if m else jnp.zeros_like(g),
                     grads, update_mask)
-            if k_accum > 1:
-                # count-weighted accumulation: loss_fn reports how many valid
-                # samples its gradient averages over (sum(mask) on any masked
-                # per-sample path, the full batch dim otherwise), so the
-                # K-window mean equals the true K x batch gradient
-                updates, new_opt = tx.update(
-                    grads, tstate.opt_state, tstate.params, count)
-            else:
-                updates, new_opt = tx.update(
-                    grads, tstate.opt_state, tstate.params)
-            if update_mask is not None:
-                # and zero the *updates* too, so decoupled weight decay
-                # (AdamWeightDecay) can't drift frozen parameters
-                updates = jax.tree_util.tree_map(
-                    lambda u, m: u if m else jnp.zeros_like(u),
-                    updates, update_mask)
-            if opt_shardings is not None:
-                # pin the ZeRO-1 layout across steps so XLA keeps moments
-                # sharded (reduce-scatter grads, all-gather updated params)
-                new_opt = jax.lax.with_sharding_constraint(new_opt, opt_shardings)
-            new_params = optax.apply_updates(tstate.params, updates)
-            return TrainState(new_params, new_mstate, new_opt, tstate.step + 1), data_loss
+            with jax.named_scope("optimizer"):
+                if k_accum > 1:
+                    # count-weighted accumulation: loss_fn reports how many
+                    # valid samples its gradient averages over (sum(mask) on
+                    # any masked per-sample path, the full batch dim
+                    # otherwise), so the K-window mean equals the true
+                    # K x batch gradient
+                    updates, new_opt = tx.update(
+                        grads, tstate.opt_state, tstate.params, count)
+                else:
+                    updates, new_opt = tx.update(
+                        grads, tstate.opt_state, tstate.params)
+                if update_mask is not None:
+                    # and zero the *updates* too, so decoupled weight decay
+                    # (AdamWeightDecay) can't drift frozen parameters
+                    updates = jax.tree_util.tree_map(
+                        lambda u, m: u if m else jnp.zeros_like(u),
+                        updates, update_mask)
+                if opt_shardings is not None:
+                    # pin the ZeRO-1 layout across steps so XLA keeps moments
+                    # sharded (reduce-scatter grads, all-gather updated
+                    # params)
+                    new_opt = jax.lax.with_sharding_constraint(
+                        new_opt, opt_shardings)
+                new_params = optax.apply_updates(tstate.params, updates)
+            new_ts = TrainState(new_params, new_mstate, new_opt,
+                                tstate.step + 1)
+            if stats_fn is not None:
+                # small per-step statistics the model keeps in its state
+                # (routing counts, tokens) ride out beside the loss: one
+                # result, one fetch at the drain, no sync of their own
+                return new_ts, (data_loss, stats_fn(new_mstate))
+            return new_ts, data_loss
 
         return train_step
 
@@ -1551,8 +1567,21 @@ class Estimator:
                     first_it, dev_losses = pending.popleft()
                     # ONE fetch; ravel: the fused-fit path yields (E, steps)
                     with drain:     # the host waits for the device here
-                        vals = np.asarray(dev_losses)
+                        if isinstance(dev_losses, tuple):
+                            # (loss, the model's step statistics): fetched
+                            # together, so the statistics wait for nothing
+                            vals, stats = jax.device_get(dev_losses)
+                        else:
+                            vals, stats = np.asarray(dev_losses), None
+                    lead = np.ndim(vals)   # a fused dispatch stacks steps
                     vals = np.atleast_1d(vals).ravel()
+                    if stats is not None:
+                        stats = {name: np.reshape(v, (len(vals),)
+                                                  + np.shape(v)[lead:])
+                                 for name, v in stats.items()}
+                        for j in range(len(vals)):     # a step at a time
+                            self.model.record_train_stats(
+                                {name: v[j] for name, v in stats.items()})
                     rs.loss = float(vals[-1])
                     epoch_loss += float(vals.sum())
                     epoch_batches += len(vals)

@@ -46,7 +46,10 @@ from analytics_zoo_tpu.keras.layers.extras import (
 from analytics_zoo_tpu.keras.layers.attention import (
     MultiHeadAttention, TransformerBlock, TransformerLayer, BERT,
 )
-from analytics_zoo_tpu.keras.layers.moe import MoE
+from analytics_zoo_tpu.keras.layers.moe import MoE, SparseMoE
+from analytics_zoo_tpu.keras.layers.decoder import (
+    RMSNorm, SwiGLU, GroupedQueryAttention, DecoderBlock, rotary_embedding,
+)
 from analytics_zoo_tpu.keras.engine.topology import Input, InputLayer
 
 __all__ = [n for n in dir() if not n.startswith("_")]

@@ -1,4 +1,7 @@
-"""MoE as a layer — the expert-parallel FFN in the standard layer library.
+"""MoE as layers — expert feed-forwards in the standard layer library:
+``MoE`` (top-1, capacity, Switch) and ``SparseMoE`` (sigmoid top-k, nothing
+dropped, shared experts, a share of the experts held: today's sparse
+decoders).
 
 Wraps :mod:`analytics_zoo_tpu.parallel.moe` (top-1 dispatch/combine, the
 Mesh-TF/Switch formulation) as a KerasLayer with a residual connection, so
@@ -20,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from analytics_zoo_tpu.keras.engine.base import (
-    KerasLayer, Regularizer, Shape, unique_name,
+    KerasLayer, Regularizer, Shape, normal_init, unique_name,
 )
 
 
@@ -79,3 +82,93 @@ class MoE(KerasLayer):
                      "w_out": params["w_out"]}, flat,
                     capacity_factor=self.capacity_factor)
         return x + y.reshape(shape)
+
+
+# initial weights of the decoder layers (here and in decoder.py): normal(0,
+# 0.02), the `initializer_range` the published decoders state
+DECODER_INIT = normal_init(0.02)
+
+
+class SparseMoE(KerasLayer):
+    """Sigmoid top-k expert layer that drops nothing, with shared experts and
+    a selection bias, over the experts this chip holds.
+
+    ``y = SwiGLU_shared(x) + sum over a token's k picks e of w_e SwiGLU_e(x)``:
+    ``s = sigmoid(W_r x)`` in float32 over all ``n_experts``; the ``top_k``
+    largest of ``s + b`` are picked (``b``, the selection bias, is state, not
+    a weight: it steers the pick and gets no gradient); ``w = s[picked]``,
+    normalised to sum 1 (``route_norm``) and scaled by ``route_scale``.
+
+    ``experts_held = (offset, count)``: of the ``n_experts`` the router
+    scores, this layer holds weights for ``count``, numbered from ``offset``
+    — one chip's share of an expert-parallel group. It routes over all of
+    them and computes the part of the sum its own experts give; on one chip
+    no exchange is made and the absent experts' part is left out. Default:
+    all of them. No capacity: shapes are fixed whatever the routing
+    (parallel/moe.py ``held_experts_ffn``).
+
+    State, returned updated from a training call as batch-norm statistics
+    are: ``select_bias`` (n_experts,), after a step
+    ``b += bias_rate * sign(mean(n) - n_e)`` then centred, ``n_e`` the
+    step's tokens routed to expert e (the auxiliary-loss-free balancing of
+    Wang et al. 2024); ``expert_tokens`` (n_experts,), that ``n`` itself,
+    which ``Estimator.train`` hands to the counters with the loss.
+    """
+
+    has_state = True
+
+    def __init__(self, n_experts: int, width: int, top_k: int,
+                 experts_held=None, n_shared: int = 1,
+                 route_norm: bool = True, route_scale: float = 1.0,
+                 bias_rate: float = 0.001, input_shape=None, name=None):
+        super().__init__(input_shape, name or unique_name("sparse_moe"))
+        self.n_experts, self.width, self.top_k = int(n_experts), int(width), int(top_k)
+        offset, count = experts_held or (0, self.n_experts)
+        if not (0 <= offset and count >= 1 and offset + count <= self.n_experts):
+            raise ValueError(f"experts_held {(offset, count)} is not a range "
+                             f"of the {self.n_experts} experts")
+        self.experts_held = (int(offset), int(count))
+        self.n_shared = int(n_shared)
+        self.route_norm, self.route_scale = route_norm, float(route_scale)
+        self.bias_rate = float(bias_rate)
+
+    def build(self, input_shape: Shape):
+        d, init = input_shape[-1], DECODER_INIT
+        count = self.experts_held[1]
+        self.add_weight("router", (d, self.n_experts), init)
+        if self.n_shared:
+            self.add_weight("shared_w_gate_up",
+                            (d, 2 * self.width * self.n_shared), init)
+            self.add_weight("shared_w_down",
+                            (self.width * self.n_shared, d), init)
+        self.add_weight("experts_w_gate_up", (count, d, 2 * self.width), init)
+        self.add_weight("experts_w_down", (count, self.width, d), init)
+        self.add_state("select_bias", (self.n_experts,), "zeros")
+        self.add_state("expert_tokens", (self.n_experts,), "zeros")
+
+    def call(self, params, x, state=None, training=False, **kw):
+        from analytics_zoo_tpu.parallel.moe import held_experts_ffn, route_topk
+
+        state = state or self.init_state()
+        shape = x.shape
+        flat = x.reshape(-1, shape[-1])
+        with jax.named_scope("moe.route"):
+            picked, weights, counts = route_topk(
+                flat, params["router"], state["select_bias"], self.top_k,
+                self.route_norm, self.route_scale)
+        with jax.named_scope("moe.experts"):
+            y = held_experts_ffn(
+                flat, picked, weights, params["experts_w_gate_up"],
+                params["experts_w_down"], self.n_experts,
+                self.experts_held[0])
+        if self.n_shared:
+            with jax.named_scope("moe.shared"):
+                gate, up = jnp.split(flat @ params["shared_w_gate_up"], 2,
+                                     axis=-1)
+                y = y + (jax.nn.silu(gate) * up) @ params["shared_w_down"]
+        if training:
+            bias = state["select_bias"] + self.bias_rate * jnp.sign(
+                jnp.mean(counts) - counts)
+            state = {"select_bias": bias - jnp.mean(bias),
+                     "expert_tokens": counts}
+        return y.reshape(shape), state

@@ -2,7 +2,8 @@
 
 Families: image classification (ResNet-50 catalog), object detection (SSD),
 recommendation (NeuralCF, WideAndDeep), anomaly detection, text
-classification, text matching (KNRM), seq2seq.
+classification, text matching (KNRM), seq2seq, and a causal decoder-only
+language model (CausalLM) over the decoder block library.
 """
 
 from analytics_zoo_tpu.models.common import ZooModel, Ranker
@@ -13,9 +14,10 @@ from analytics_zoo_tpu.models.recommendation import (
 from analytics_zoo_tpu.models.anomalydetection import AnomalyDetector
 from analytics_zoo_tpu.models.seq2seq import Seq2seq
 from analytics_zoo_tpu.models.textmatching import KNRM
+from analytics_zoo_tpu.models.causal_lm import CausalLM
 
 __all__ = [
     "ZooModel", "Ranker", "TextClassifier", "NeuralCF", "WideAndDeep",
     "ColumnFeatureInfo", "Recommender", "SessionRecommender",
-    "AnomalyDetector", "Seq2seq", "KNRM",
+    "AnomalyDetector", "Seq2seq", "KNRM", "CausalLM",
 ]

@@ -1,0 +1,187 @@
+"""Causal decoder-only language model over the decoder block library
+(keras/layers/decoder.py, keras/layers/moe.py): token embedding, a stack of
+``DecoderBlock``s whose attention kind (``sliding_attention`` /
+``full_attention``) and feed-forward kind (leading dense layers, then expert
+layers) come from the configuration, a final RMS norm and an untied head.
+``apply`` ends in logits over the vocabulary held here, in the compute type;
+train it with ``compile(optimizer, loss="token_crossentropy_from_logits")``
+and ``Estimator.train`` / ``fit`` like any other ``KerasNet``.
+
+One chip of an expert-parallel group holds a share of each expert layer's
+experts (``experts_held``) and a slice of the vocabulary (``vocab_size`` is
+the slice; ids are drawn from it): see ``SparseMoE``.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Dict, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from analytics_zoo_tpu.keras.engine.base import unique_name
+from analytics_zoo_tpu.keras.engine.topology import KerasNet
+from analytics_zoo_tpu.keras.layers.core import Dense
+from analytics_zoo_tpu.keras.layers.decoder import (
+    DecoderBlock, GroupedQueryAttention, RMSNorm, SwiGLU, rms_norm,
+)
+from analytics_zoo_tpu.keras.layers.embeddings import Embedding
+from analytics_zoo_tpu.keras.layers.moe import DECODER_INIT, SparseMoE
+
+
+class CausalLM(KerasNet):
+    """See the module docstring. ``layer_types``: one of
+    ``"sliding_attention"`` (rotary positions, window ``sliding_window``) or
+    ``"full_attention"`` (causal, no positional encoding) a layer;
+    the first ``num_dense_layers`` layers get a dense ``SwiGLU`` of
+    ``dense_width``, the others a ``SparseMoE``. ``embed_scale``: the
+    embedding's multiplier (sqrt(hidden) with muP). State: each expert layer's
+    selection bias and step counts, and ``tokens``, the tokens of the last
+    training step; ``train_stats`` hands the counts to ``Estimator.train``,
+    which brings them out with the loss and gives them back to
+    ``record_train_stats`` at the drain."""
+
+    def __init__(self, vocab_size: int, hidden_size: int,
+                 layer_types: Sequence[str], n_head: int, n_kv_head: int,
+                 head_dim: int, dense_width: int, num_dense_layers: int = 0,
+                 n_experts: int = 0, experts_held: Optional[Tuple[int, int]] = None,
+                 expert_width: int = 0, top_k: int = 1, n_shared: int = 1,
+                 sliding_window: int = 2048, rope_theta: float = 10000.0,
+                 epsilon: float = 1e-5, embed_scale: Optional[float] = None,
+                 route_norm: bool = True, route_scale: float = 1.0,
+                 bias_rate: float = 0.001, seq_len: Optional[int] = None,
+                 dtype: Optional[str] = "bfloat16", remat: bool = True,
+                 name: Optional[str] = None):
+        super().__init__(name or unique_name("causal_lm"))
+        self.vocab_size, self.hidden_size = int(vocab_size), int(hidden_size)
+        self.seq_len, self.epsilon = seq_len, epsilon
+        self.embed_scale = (math.sqrt(hidden_size) if embed_scale is None
+                            else float(embed_scale))
+        self.dtype = None if dtype is None else jnp.dtype(dtype)
+        self.embed = Embedding(vocab_size, hidden_size, init=DECODER_INIT,
+                               name=self.name + "_embed")
+        self.embed.ensure_built((None, seq_len))
+        self.blocks = []
+        for i, kind in enumerate(layer_types):
+            if kind not in ("sliding_attention", "full_attention"):
+                raise ValueError(f"layer {i}: unknown attention kind {kind!r}")
+            sliding = kind == "sliding_attention"
+            attn = GroupedQueryAttention(
+                n_head, n_kv_head, head_dim,
+                window=sliding_window if sliding else None,
+                rope_theta=rope_theta if sliding else None,
+                epsilon=epsilon, name=f"{self.name}_l{i}_attn")
+            if i < num_dense_layers:
+                mlp = SwiGLU(dense_width, name=f"{self.name}_l{i}_mlp")
+            else:
+                mlp = SparseMoE(n_experts, expert_width, top_k, experts_held,
+                                n_shared, route_norm, route_scale, bias_rate,
+                                name=f"{self.name}_l{i}_moe")
+            block = DecoderBlock(attn, mlp, epsilon, dtype, remat,
+                                 name=f"{self.name}_l{i}")
+            block.ensure_built((None, seq_len, hidden_size))
+            self.blocks.append(block)
+        self.final_norm = RMSNorm(epsilon, name=self.name + "_final_norm")
+        self.final_norm.ensure_built((None, seq_len, hidden_size))
+        self.head = Dense(vocab_size, init=DECODER_INIT, bias=False,
+                          name=self.name + "_head")
+        self.head.ensure_built((None, seq_len, hidden_size))
+        held = [b.mlp.experts_held for b in self.blocks if b.has_state]
+        self.experts_held = held[0] if held else None
+        self._obs = None
+
+    @classmethod
+    def from_config(cls, cfg: Dict, **kw) -> "CausalLM":
+        """From a published ``afmoe``-style config (Trinity): its key names,
+        plus ``router_num_experts`` (the router's width) where ``num_experts``
+        counts only the experts held, from ``experts_held_offset``."""
+        held = cfg["num_experts"]
+        total = cfg.get("router_num_experts", held)
+        return cls(
+            vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
+            layer_types=cfg["layer_types"],
+            n_head=cfg["num_attention_heads"],
+            n_kv_head=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+            dense_width=cfg["intermediate_size"],
+            num_dense_layers=cfg["num_dense_layers"], n_experts=total,
+            experts_held=(cfg.get("experts_held_offset", 0), held),
+            expert_width=cfg["moe_intermediate_size"],
+            top_k=cfg["num_experts_per_tok"],
+            n_shared=cfg["num_shared_experts"],
+            sliding_window=cfg["sliding_window"], rope_theta=cfg["rope_theta"],
+            epsilon=cfg["rms_norm_eps"],
+            embed_scale=(math.sqrt(cfg["hidden_size"])
+                         if cfg.get("mup_enabled") else 1.0),
+            route_norm=cfg["route_norm"], route_scale=cfg["route_scale"],
+            bias_rate=cfg["load_balance_coeff"], **kw)
+
+    # -- model protocol --------------------------------------------------
+
+    def layers(self):
+        return [self.embed, *self.blocks, self.final_norm, self.head]
+
+    def init(self, rng):
+        params, state = super().init(rng)
+        state[self.name] = {"tokens": jnp.zeros((), jnp.float32)}
+        return params, state
+
+    def apply(self, params, state, x, training=False, rng=None):
+        """x: token ids (rows, tokens) -> (logits (rows, tokens, vocabulary)
+        in the compute type, the new state)."""
+        ids = x[0] if isinstance(x, (list, tuple)) else x
+        h = self.embed.call(params[self.embed.name], ids) * self.embed_scale
+        new_state = dict(state)
+        for block in self.blocks:
+            if block.has_state:
+                h, new_state[block.name] = block.call(
+                    params[block.name], h, state=state.get(block.name),
+                    training=training)
+            else:
+                h = block.call(params[block.name], h)
+        h = rms_norm(h, params[self.final_norm.name]["gain"], self.epsilon)
+        kernel = params[self.head.name]["kernel"]
+        logits = h @ (kernel if self.dtype is None else kernel.astype(self.dtype))
+        if training:
+            new_state[self.name] = {
+                "tokens": jnp.asarray(ids.size, jnp.float32)}
+        return logits, new_state
+
+    def get_output_shape(self):
+        return (None, self.seq_len, self.vocab_size)
+
+    def get_input_shape(self):
+        return (None, self.seq_len)
+
+    # -- step statistics -------------------------------------------------
+
+    def train_stats(self, model_state) -> Dict:
+        """What a train step returns beside its loss: the step's tokens and,
+        a row an expert layer, the tokens routed to each expert."""
+        stats = {"tokens": model_state[self.name]["tokens"]}
+        moe = [model_state[b.name]["expert_tokens"] for b in self.blocks
+               if b.has_state]
+        if moe:
+            stats["expert_tokens"] = jnp.stack(moe)
+        return stats
+
+    def record_train_stats(self, stats: Dict) -> None:
+        """One step's statistics, on the host at ``train.drain``, into the
+        counters (``common.observability.lm_train_metrics``)."""
+        if self._obs is None:
+            from analytics_zoo_tpu.common.observability import lm_train_metrics
+
+            self._obs = lm_train_metrics()
+        obs = self._obs
+        obs["tokens"].inc(float(stats["tokens"]))
+        counts = stats.get("expert_tokens")
+        if counts is None:
+            return
+        lo, n = self.experts_held
+        held = np.asarray(counts)[:, lo:lo + n]
+        obs["assignments_held"].inc(float(held.sum()))
+        obs["assignments_absent"].inc(float(np.sum(counts) - held.sum()))
+        for layer in held:
+            obs["load_max"].observe(float(layer.max()))
+            obs["load_mean"].observe(float(layer.mean()))

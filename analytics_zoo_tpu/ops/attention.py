@@ -1,12 +1,16 @@
 """Attention op: single entry point the layer library calls.
 
 Dispatches between the Pallas flash-attention kernel (ops/flash_attention.py)
-and a fused-by-XLA jnp path. Both take (B, N, S, D) q/k/v plus an additive
-bias/mask. The default routes by the size of the S^2 logits tensor (see
-_flash_bytes_threshold): XLA at product shapes, the O(S)-memory Pallas
-kernel where the logits tensor would dominate HBM. A kernel that fails to
-compile or run raises — only a shape outside the kernel's envelope
-(``NotImplementedError``) takes the XLA path, with a warning.
+and a fused-by-XLA jnp path. Both take (B, N, S, D) q and (B, N_kv, S, D)
+k/v (N_kv = N, or fewer key-value heads that divide N: grouped-query
+attention), an additive bias/mask, a causal flag and, with it, a sliding
+``window`` (query i sees key j iff 0 <= i - j < window; the kernels skip
+the blocks wholly outside it). The default routes by the size of the S^2
+logits tensor (see _flash_bytes_threshold): XLA at product shapes, the
+O(S)-memory Pallas kernel where the logits tensor would dominate HBM. A
+kernel that fails to compile or run raises — only a shape outside the
+kernel's envelope (``NotImplementedError``) takes the XLA path, with a
+warning.
 """
 
 from __future__ import annotations
@@ -80,15 +84,33 @@ def _auto_use_flash(q, k) -> bool:
     return logits_bytes >= threshold
 
 
+def check_window_and_heads(q, k, causal: bool, window) -> None:
+    """What both paths ask of a ``window`` and of grouped heads."""
+    if window is not None and (not causal or int(window) < 1):
+        raise ValueError("a window is the newest `window` >= 1 keys of a "
+                         "causal mask: pass causal=True")
+    if q.shape[1] % k.shape[1]:
+        raise ValueError(f"{q.shape[1]} query heads do not share "
+                         f"{k.shape[1]} key-value heads evenly")
+
+
 def _reference_attention(q, k, v, bias: Optional[jax.Array], causal: bool,
                          scale: float, dropout_rate: float = 0.0,
-                         dropout_rng: Optional[jax.Array] = None) -> jax.Array:
+                         dropout_rng: Optional[jax.Array] = None,
+                         window: Optional[int] = None) -> jax.Array:
+    group = q.shape[1] // k.shape[1]
+    if group > 1:      # grouped key-value heads: head h reads h // group
+        k = jnp.repeat(k, group, axis=1)
+        v = jnp.repeat(v, group, axis=1)
     logits = jnp.einsum("bnqd,bnkd->bnqk", q, k) * scale
     if bias is not None:
         logits = logits + bias
     if causal:
         s_q, s_k = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((s_q, s_k), jnp.bool_), k=s_k - s_q)
+        if window is not None:   # the `window` newest causal keys only
+            mask = mask & ~jnp.tril(jnp.ones((s_q, s_k), jnp.bool_),
+                                    k=s_k - s_q - int(window))
         logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
     # softmax in f32 for bf16 streams
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
@@ -104,14 +126,21 @@ def scaled_dot_product_attention(q, k, v, bias: Optional[jax.Array] = None,
                                  scale: Optional[float] = None,
                                  dropout_rate: float = 0.0,
                                  dropout_rng: Optional[jax.Array] = None,
-                                 use_flash: Optional[bool] = None) -> jax.Array:
-    """q/k/v: (batch, heads, seq, head_dim). bias: additive, broadcastable to
-    (batch, heads, q_len, k_len) — use large negatives for padding masks.
+                                 use_flash: Optional[bool] = None,
+                                 window: Optional[int] = None) -> jax.Array:
+    """q: (batch, heads, seq, head_dim); k/v the same, or with fewer
+    key-value heads that divide the query heads (grouped-query attention:
+    query head h reads key-value head h // (heads / key-value heads)).
+    bias: additive, broadcastable to (batch, heads, q_len, k_len) — use
+    large negatives for padding masks. ``window`` (needs ``causal``): query
+    i sees key j iff 0 <= i - j < window — the sliding-window mask; on the
+    kernel path blocks wholly outside the window are skipped, not masked.
     ``dropout_rate`` is attention-probability dropout (reference semantics);
     it forces the XLA path (the flash kernel has no prob-dropout)."""
     global _warned_fallback
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    check_window_and_heads(q, k, causal, window)
     explicit = use_flash is True
     if use_flash is None:
         # At product shapes (BERT seq 128/512) the logits tensor is small
@@ -125,7 +154,8 @@ def scaled_dot_product_attention(q, k, v, bias: Optional[jax.Array] = None,
         try:
             from analytics_zoo_tpu.ops.flash_attention import flash_attention
 
-            return flash_attention(q, k, v, bias=bias, causal=causal, scale=scale)
+            return flash_attention(q, k, v, bias=bias, causal=causal,
+                                   scale=scale, window=window)
         except NotImplementedError as e:
             # Shape/bias outside kernel support. Warn when the caller
             # explicitly demanded the kernel — and also when the dispatcher
@@ -141,4 +171,4 @@ def scaled_dot_product_attention(q, k, v, bias: Optional[jax.Array] = None,
                     "this shape was routed to the kernel to avoid",
                     "requested" if explicit else "auto-selected", e)
     return _reference_attention(q, k, v, bias, causal, scale,
-                                dropout_rate, dropout_rng)
+                                dropout_rate, dropout_rng, window)

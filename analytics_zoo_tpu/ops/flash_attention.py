@@ -157,15 +157,34 @@ def _maybe_bias(kernel, has_bias: bool, n_in: int):
     return adapted
 
 
+def _masked(s, qi, ki, block_q: int, block_k: int, causal_offset: int,
+            window):
+    """Scores of block (qi, ki) with the keys a query may not see at
+    _NEG_INF: bottom-right aligned causal (matches the XLA reference's
+    tril(k=s_k-s_q): query i attends keys <= i + (s_k - s_q)) and, with a
+    ``window``, only the ``window`` newest of those."""
+    q_pos = qi * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0) + causal_offset
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    seen = q_pos >= k_pos
+    if window is not None:
+        seen = jnp.logical_and(seen, q_pos - k_pos < window)
+    return jnp.where(seen, s, _NEG_INF)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref, *, scale: float, causal: bool,
                 blocks_k: int, block_q: int, block_k: int,
-                causal_offset: int, has_bias: bool):
+                causal_offset: int, has_bias: bool, window, steps_k: int):
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    t = pl.program_id(2)
+    # with a window the innermost axis walks only the K blocks a q block's
+    # window can touch, from the first of them
+    ki = t + _k_base(qi, block_q, block_k, causal_offset, window)
     cdt = _compute_dtype(q_ref)
 
-    @pl.when(ki == 0)
+    @pl.when(t == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -179,13 +198,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         if has_bias:
             s = s + bias_ref[0, 0].astype(jnp.float32)[None, :]
         if causal:
-            # bottom-right alignment (matches the XLA reference's
-            # tril(k=s_k-s_q)): query i attends keys <= i + (s_k - s_q)
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0) + causal_offset
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            s = _masked(s, qi, ki, block_q, block_k, causal_offset, window)
         m_prev = m_ref[...]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -196,32 +209,64 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         m_ref[...] = m_new
 
     if causal:
-        # fully-masked K blocks above the diagonal contribute nothing:
-        # skip their compute, keep the running statistics
-        pl.when(_causal_block_live(qi, ki, block_q, block_k,
-                                   causal_offset))(compute)
+        # fully-masked K blocks (above the diagonal, or wholly older than
+        # the window) contribute nothing: skip their compute, keep the
+        # running statistics. A row with no key in a live block adds
+        # exp(0) terms at the _NEG_INF maximum; the first real key's
+        # alpha = exp(_NEG_INF - m) = 0 wipes them.
+        pl.when(_causal_block_live(qi, ki, block_q, block_k, causal_offset,
+                                   window, blocks_k))(compute)
     else:
         compute()
 
-    @pl.when(ki == blocks_k - 1)
+    @pl.when(t == steps_k - 1)
     def _flush():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
         lse_ref[0, 0] = (m_ref[...] + jnp.log(l))[:, 0]
 
 
-def _pcall(kernel, interpret: bool, **kw):
+def _pcall(kernel, interpret: bool, name: str, **kw):
     """Shared pallas_call plumbing for all three kernels: interpret flag
     plus the (TPU-only) grid dimension semantics — two parallel outer axes,
-    sequential innermost axis carrying the accumulator scratch."""
+    sequential innermost axis carrying the accumulator scratch. ``name`` is
+    the kernel's stable name in a device trace (``zoo_flash_fwd`` /
+    ``zoo_flash_dq`` / ``zoo_flash_dkv``): the benchmark's readers find the
+    kernels by it."""
     if not interpret:
         kw["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
-    return pl.pallas_call(kernel, interpret=interpret, **kw)
+    return pl.pallas_call(kernel, interpret=interpret, name=name, **kw)
+
+
+def _k_base(qi, block_q: int, block_k: int, causal_offset: int, window):
+    """The first K block a q block's window can touch: where the innermost
+    axis starts when a window bounds it (0 without one)."""
+    if window is None:
+        return 0
+    return jnp.maximum(qi * block_q + causal_offset - (window - 1), 0) // block_k
+
+
+def _q_base(ki, block_q: int, block_k: int, causal_offset: int, window):
+    """The first q block that can see K block ``ki`` (0 without a window:
+    the axis is walked whole)."""
+    if window is None:
+        return 0
+    return jnp.maximum(ki * block_k - causal_offset, 0) // block_q
+
+
+def _inner_steps(blocks: int, block_outer: int, block_inner: int, window):
+    """Steps of the innermost axis: all ``blocks`` without a window; with
+    one, the most inner blocks that ``block_outer + window - 1`` positions
+    can touch."""
+    if window is None:
+        return blocks
+    return min(blocks, (block_outer + window - 2) // block_inner + 2)
 
 
 def _stream_clamps(causal: bool, block_q: int, block_k: int,
-                   causal_offset: int, blocks_q: int, blocks_k: int):
+                   causal_offset: int, blocks_q: int, blocks_k: int,
+                   window=None):
     """Index-map clamps that stop the pipeline DMA-ing dead causal blocks.
 
     ``pl.when`` only skips the *compute* of a fully-masked block — the
@@ -231,26 +276,36 @@ def _stream_clamps(causal: bool, block_q: int, block_k: int,
     dead step revisit an already-fetched block, which the pallas pipeline
     elides. Returns (k_stream_idx, q_stream_idx): the K-block index for a
     given (q-row j, step t) and the q-block index for a given
-    (k-block j, step t)."""
+    (k-block j, step t). With a ``window`` step t counts from the first
+    block the window can touch (``_k_base`` / ``_q_base``)."""
     if not causal:
         return (lambda j, t: t), (lambda j, t: t)
 
     def k_stream(j, t):
         # last live K block for q row j: max q_pos = (j+1)*bq - 1 + off
         last = ((j + 1) * block_q - 1 + causal_offset) // block_k
-        return jnp.minimum(t, jnp.clip(last, 0, blocks_k - 1))
+        first = _k_base(j, block_q, block_k, causal_offset, window)
+        return jnp.clip(first + t, first, jnp.clip(last, 0, blocks_k - 1))
 
     def q_stream(j, t):
         # first live q block for K block j: q_pos >= j*bk - off
         first = (j * block_k - causal_offset) // block_q
-        return jnp.maximum(t, jnp.clip(first, 0, blocks_q - 1))
+        first = jnp.clip(first, 0, blocks_q - 1)
+        if window is None:
+            return jnp.maximum(t, first)
+        # last: q_pos - k_pos < window for the block's newest key
+        last = ((j + 1) * block_k - 1 + window - 1 - causal_offset) // block_q
+        return jnp.clip(first + t, first, jnp.clip(last, 0, blocks_q - 1))
 
     return k_stream, q_stream
 
 
 def _flash_forward(q, k, v, bias_flat, scale: float, causal: bool,
-                   block_q: int, block_k: int):
-    """q/k/v flattened to (bn, s, d); bias_flat (bn, 1, s_k) or None.
+                   block_q: int, block_k: int, window=None):
+    """q/k/v flattened to (bn, s, d); bias_flat (bn, 1, s_k) or None. With
+    grouped key-value heads k/v are (bn // group, s, d): q row i reads
+    key-value row i // group (rows are batch-major, so the heads of one
+    group are neighbours).
     Returns (out, lse) with lse (bn, 1, s_q) f32. The aux arrays ride as
     rank-3 so TPU block shapes are (1, 1, s) — the mosaic lowering requires
     the trailing two block dims to be (8k, 128k) or full. Grid layout and
@@ -259,20 +314,24 @@ def _flash_forward(q, k, v, bias_flat, scale: float, causal: bool,
     s_k = k.shape[1]
     dv = v.shape[-1]
     blocks_k = s_k // block_k
+    group = bn // k.shape[0]
     interpret = _interpret()
     has_bias = bias_flat is not None
     ks, _ = _stream_clamps(causal, block_q, block_k, s_k - s_q,
-                           s_q // block_q, blocks_k)
+                           s_q // block_q, blocks_k, window)
+    steps_k = _inner_steps(blocks_k, block_q, block_k, window)
 
     kernel = _maybe_bias(functools.partial(
         _fwd_kernel, scale=scale, causal=causal, blocks_k=blocks_k,
         block_q=block_q, block_k=block_k, causal_offset=s_k - s_q,
-        has_bias=has_bias), has_bias, n_in=3)
+        has_bias=has_bias, window=window, steps_k=steps_k), has_bias, n_in=3)
 
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, ks(j, t), 0)),
-        pl.BlockSpec((1, block_k, dv), lambda i, j, t: (i, ks(j, t), 0)),
+        pl.BlockSpec((1, block_k, d),
+                     lambda i, j, t: (i // group, ks(j, t), 0)),
+        pl.BlockSpec((1, block_k, dv),
+                     lambda i, j, t: (i // group, ks(j, t), 0)),
     ]
     operands = [q, k, v]
     if has_bias:
@@ -281,8 +340,8 @@ def _flash_forward(q, k, v, bias_flat, scale: float, causal: bool,
         operands.append(bias_flat)
 
     out, lse = _pcall(
-        kernel, interpret,
-        grid=(bn, s_q // block_q, blocks_k),
+        kernel, interpret, "zoo_flash_fwd",
+        grid=(bn, s_q // block_q, steps_k),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda i, j, t: (i, j, 0)),
@@ -316,22 +375,36 @@ def _flash_forward(q, k, v, bias_flat, scale: float, causal: bool,
 
 
 def _causal_block_live(qi, ki, block_q: int, block_k: int,
-                       causal_offset: int):
+                       causal_offset: int, window=None, blocks=None,
+                       blocks_q=None):
     """True iff any (q, k) pair in block (qi, ki) satisfies
     q_pos >= k_pos: max q_pos = (qi+1)*block_q - 1 + causal_offset,
-    min k_pos = ki*block_k."""
-    return (qi + 1) * block_q - 1 + causal_offset >= ki * block_k
+    min k_pos = ki*block_k; with a ``window`` also q_pos - k_pos < window
+    for some pair: min q_pos - max k_pos < window. ``blocks`` /
+    ``blocks_q``: the number of K / q blocks, for a window's shifted axis,
+    whose last steps can run past the end."""
+    live = (qi + 1) * block_q - 1 + causal_offset >= ki * block_k
+    if window is not None:
+        live = jnp.logical_and(
+            live, qi * block_q + causal_offset
+            - ((ki + 1) * block_k - 1) < window)
+        if blocks is not None:
+            live = jnp.logical_and(live, ki < blocks)
+        if blocks_q is not None:
+            live = jnp.logical_and(live, qi < blocks_q)
+    return live
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
                dq_ref, acc_ref, *, scale: float, causal: bool, blocks_k: int,
                block_q: int, block_k: int, causal_offset: int,
-               has_bias: bool):
+               has_bias: bool, window, steps_k: int):
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    t = pl.program_id(2)
+    ki = t + _k_base(qi, block_q, block_k, causal_offset, window)
     cdt = _compute_dtype(q_ref)
 
-    @pl.when(ki == 0)
+    @pl.when(t == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -346,11 +419,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
         if has_bias:
             s = s + bias_ref[0, 0].astype(jnp.float32)[None, :]
         if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0) + causal_offset
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            s = _masked(s, qi, ki, block_q, block_k, causal_offset, window)
         p = jnp.exp(s - lse)                          # (bq, bk) f32
         dp = _mm_nt(do, v, cdt)                       # (bq, bk)
         ds = p * (dp - delta)
@@ -359,12 +428,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
     if causal:
         # fully-masked blocks above the diagonal: skip the compute (their
         # contribution is exactly zero); the scratch keeps accumulating
-        pl.when(_causal_block_live(qi, ki, block_q, block_k,
-                                   causal_offset))(compute)
+        pl.when(_causal_block_live(qi, ki, block_q, block_k, causal_offset,
+                                   window, blocks_k))(compute)
     else:
         compute()
 
-    @pl.when(ki == blocks_k - 1)
+    @pl.when(t == steps_k - 1)
     def _flush():
         dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
@@ -372,12 +441,16 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
                 dk_ref, dv_ref, db_ref, dk_acc, dv_acc, db_acc, *,
                 scale: float, causal: bool, blocks_q: int, block_q: int,
-                block_k: int, causal_offset: int, has_bias: bool):
+                block_k: int, causal_offset: int, has_bias: bool,
+                window, steps_q: int, group: int):
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    t = pl.program_id(2)
+    # the innermost axis walks the q blocks of each of the group's query
+    # heads in turn: one K/V block gathers dk/dv from all of them
+    qi = t % steps_q + _q_base(ki, block_q, block_k, causal_offset, window)
     cdt = _compute_dtype(q_ref)
 
-    @pl.when(qi == 0)
+    @pl.when(t == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -395,11 +468,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
         if has_bias:
             s = s + bias_ref[0, 0].astype(jnp.float32)[None, :]
         if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0) + causal_offset
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            s = _masked(s, qi, ki, block_q, block_k, causal_offset, window)
         p = jnp.exp(s - lse)                          # (bq, bk) f32
         dv_acc[...] += _mm_tn(p, do, cdt)
         dp = _mm_nt(do, v, cdt)                       # (bq, bk)
@@ -411,12 +480,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
     if causal:
         # q blocks entirely above the diagonal contribute exactly zero to
         # this k block — skip their compute, keep the accumulators
-        pl.when(_causal_block_live(qi, ki, block_q, block_k,
-                                   causal_offset))(compute)
+        pl.when(_causal_block_live(qi, ki, block_q, block_k, causal_offset,
+                                   window, blocks_q=blocks_q))(compute)
     else:
         compute()
 
-    @pl.when(qi == blocks_q - 1)
+    @pl.when(t == group * steps_q - 1)
     def _flush():
         dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -425,14 +494,18 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
 
 
 def _flash_backward(q, k, v, bias_flat, out, lse, g, scale: float,
-                    causal: bool, block_q: int, block_k: int, g_lse=None):
+                    causal: bool, block_q: int, block_k: int, g_lse=None,
+                    window=None):
     bn, s_q, d = q.shape
     s_k = k.shape[1]
     dv_dim = v.shape[-1]
+    group = bn // k.shape[0]
     has_bias = bias_flat is not None
     interpret = _interpret()
     blocks_q = s_q // block_q
     blocks_k = s_k // block_k
+    steps_k = _inner_steps(blocks_k, block_q, block_k, window)
+    steps_q = _inner_steps(blocks_q, block_k, block_q, window)
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, None, :]  # (bn, 1, s_q)
@@ -440,14 +513,16 @@ def _flash_backward(q, k, v, bias_flat, out, lse, g, scale: float,
         delta = delta - g_lse.astype(jnp.float32)
 
     ks, qs = _stream_clamps(causal, block_q, block_k, s_k - s_q,
-                            blocks_q, blocks_k)
+                            blocks_q, blocks_k, window)
 
     # dq: grid (bn, q-block, k-block) — q/do/lse/delta resident across the
     # sequential k axis, K/V streamed block-by-block by the pipeline
     dq_specs = [
         pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, ks(j, t), 0)),
-        pl.BlockSpec((1, block_k, dv_dim), lambda i, j, t: (i, ks(j, t), 0)),
+        pl.BlockSpec((1, block_k, d),
+                     lambda i, j, t: (i // group, ks(j, t), 0)),
+        pl.BlockSpec((1, block_k, dv_dim),
+                     lambda i, j, t: (i // group, ks(j, t), 0)),
         pl.BlockSpec((1, block_q, dv_dim), lambda i, j, t: (i, j, 0)),
         pl.BlockSpec((1, 1, block_q), lambda i, j, t: (i, 0, j)),
         pl.BlockSpec((1, 1, block_q), lambda i, j, t: (i, 0, j)),
@@ -461,24 +536,36 @@ def _flash_backward(q, k, v, bias_flat, out, lse, g, scale: float,
         _maybe_bias(functools.partial(
             _dq_kernel, scale=scale, causal=causal, blocks_k=blocks_k,
             block_q=block_q, block_k=block_k, causal_offset=s_k - s_q,
-            has_bias=has_bias), has_bias, n_in=6),
-        interpret,
-        grid=(bn, blocks_q, blocks_k),
+            has_bias=has_bias, window=window, steps_k=steps_k),
+            has_bias, n_in=6),
+        interpret, "zoo_flash_dq",
+        grid=(bn, blocks_q, steps_k),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bn, s_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
     )(*dq_ops)
 
-    # dk/dv/dbias: grid (bn, k-block, q-block) — K/V resident across the
-    # sequential q axis, Q/dO/lse/delta streamed block-by-block
+    # dk/dv/dbias: grid (key-value rows, k-block, group x q-block) — K/V
+    # resident across the sequential axis, Q/dO/lse/delta of each of the
+    # group's query heads streamed block-by-block
+    def qrow(i, t):
+        return i * group + t // steps_q
+
+    def qblk(j, t):
+        return qs(j, t % steps_q)
+
     dkv_specs = [
-        pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, qs(j, t), 0)),
+        pl.BlockSpec((1, block_q, d),
+                     lambda i, j, t: (qrow(i, t), qblk(j, t), 0)),
         pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, j, 0)),
         pl.BlockSpec((1, block_k, dv_dim), lambda i, j, t: (i, j, 0)),
-        pl.BlockSpec((1, block_q, dv_dim), lambda i, j, t: (i, qs(j, t), 0)),
-        pl.BlockSpec((1, 1, block_q), lambda i, j, t: (i, 0, qs(j, t))),
-        pl.BlockSpec((1, 1, block_q), lambda i, j, t: (i, 0, qs(j, t))),
+        pl.BlockSpec((1, block_q, dv_dim),
+                     lambda i, j, t: (qrow(i, t), qblk(j, t), 0)),
+        pl.BlockSpec((1, 1, block_q),
+                     lambda i, j, t: (qrow(i, t), 0, qblk(j, t))),
+        pl.BlockSpec((1, 1, block_q),
+                     lambda i, j, t: (qrow(i, t), 0, qblk(j, t))),
     ]
     dkv_ops = [q, k, v, g, lse, delta]
     if has_bias:
@@ -489,9 +576,10 @@ def _flash_backward(q, k, v, bias_flat, out, lse, g, scale: float,
         _maybe_bias(functools.partial(
             _dkv_kernel, scale=scale, causal=causal, blocks_q=blocks_q,
             block_q=block_q, block_k=block_k, causal_offset=s_k - s_q,
-            has_bias=has_bias), has_bias, n_in=6),
-        interpret,
-        grid=(bn, blocks_k, blocks_q),
+            has_bias=has_bias, window=window, steps_q=steps_q, group=group),
+            has_bias, n_in=6),
+        interpret, "zoo_flash_dkv",
+        grid=(bn // group, blocks_k, group * steps_q),
         in_specs=dkv_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, j, 0)),
@@ -499,9 +587,9 @@ def _flash_backward(q, k, v, bias_flat, out, lse, g, scale: float,
             pl.BlockSpec((1, 1, block_k), lambda i, j, t: (i, 0, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bn, s_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bn, s_k, dv_dim), v.dtype),
-            jax.ShapeDtypeStruct((bn, 1, s_k), jnp.float32),
+            jax.ShapeDtypeStruct((bn // group, s_k, d), k.dtype),
+            jax.ShapeDtypeStruct((bn // group, s_k, dv_dim), v.dtype),
+            jax.ShapeDtypeStruct((bn // group, 1, s_k), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -517,30 +605,32 @@ def _flash_backward(q, k, v, bias_flat, out, lse, g, scale: float,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _flash(q, k, v, bias_flat, scale: float, causal: bool,
-           block_q: int, block_k: int):
+           block_q: int, block_k: int, window=None):
     """Returns (out, lse) with lse (bn, 1, s_q) f32. The lse output is
     differentiable too: d(lse_i)/d(s_ij) = p_ij, which folds into the
     backward kernels as an extra ``+ g_lse`` inside the delta term — this is
     what lets ring attention merge per-shard flash partials and still get
     exact gradients through the merge."""
-    return _flash_forward(q, k, v, bias_flat, scale, causal, block_q, block_k)
+    return _flash_forward(q, k, v, bias_flat, scale, causal, block_q, block_k,
+                          window)
 
 
-def _flash_fwd_rule(q, k, v, bias_flat, scale, causal, block_q, block_k):
+def _flash_fwd_rule(q, k, v, bias_flat, scale, causal, block_q, block_k,
+                    window=None):
     out, lse = _flash_forward(q, k, v, bias_flat, scale, causal,
-                              block_q, block_k)
+                              block_q, block_k, window)
     return (out, lse), (q, k, v, bias_flat, out, lse)
 
 
-def _flash_bwd_rule(scale, causal, block_q, block_k, res, cts):
+def _flash_bwd_rule(scale, causal, block_q, block_k, window, res, cts):
     q, k, v, bias_flat, out, lse = res
     g, g_lse = cts
     # ds = p*(dp - delta) + g_lse*p  ==  p*(dp - (delta - g_lse))
     dq, dk, dv, dbias = _flash_backward(
         q, k, v, bias_flat, out, lse, g, scale, causal, block_q, block_k,
-        g_lse=g_lse)
+        g_lse=g_lse, window=window)
     if dbias is not None:
         # cotangent aval must match the primal's (dbias accumulates in f32)
         dbias = dbias.astype(bias_flat.dtype)
@@ -550,12 +640,18 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, res, cts):
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def _validate(q, k, scale, block_q: int, block_k: int):
+def _validate(q, k, scale, block_q: int, block_k: int, causal=True,
+              window=None, bias=None):
     """Shared support-envelope check for both public entry points; returns
     the resolved scale."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     s_q, s_k = q.shape[2], k.shape[2]
+    from analytics_zoo_tpu.ops.attention import check_window_and_heads
+
+    check_window_and_heads(q, k, causal, window)
+    if q.shape[1] != k.shape[1] and bias is not None:
+        raise NotImplementedError("bias with grouped key-value heads")
     if s_q % block_q or s_k % block_k:
         raise NotImplementedError(f"seq lens must tile ({block_q},{block_k})")
     if q.shape[-1] > 256:
@@ -566,18 +662,26 @@ def _validate(q, k, scale, block_q: int, block_k: int):
 def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
                     causal: bool = False, scale: Optional[float] = None,
                     block_q: Optional[int] = None,
-                    block_k: Optional[int] = None):
-    """Pallas path. q/k/v: (batch, heads, seq, head_dim); bias additive,
-    broadcastable to (batch, heads, 1, s_k) (padding-mask layout). Raises
-    NotImplementedError for unsupported shapes/bias so the dispatcher in
-    ops.attention falls back to the XLA reference implementation.
-    ``block_q``/``block_k`` override the seq-aware default tile sizes per
-    call (the flash_bench autotune sweep)."""
+                    block_k: Optional[int] = None,
+                    window: Optional[int] = None):
+    """Pallas path. q: (batch, heads, seq, head_dim); k/v the same, or with
+    fewer (key-value) heads that divide the query heads: query head h reads
+    key-value head h // (heads / key-value heads). bias additive,
+    broadcastable to (batch, heads, 1, s_k) (padding-mask layout).
+    ``window`` (with ``causal``): a query sees only the ``window`` newest of
+    its causal keys, itself included; blocks wholly outside are neither
+    computed nor fetched, and the kernels' innermost axis walks only the
+    blocks a window can touch. Raises NotImplementedError for unsupported
+    shapes/bias so the dispatcher in ops.attention falls back to the XLA
+    reference implementation. ``block_q``/``block_k`` override the
+    seq-aware default tile sizes per call (the flash_bench autotune
+    sweep)."""
     block_q, block_k = _resolve_blocks(block_q, block_k,
                                        q.shape[2], k.shape[2])
-    scale = _validate(q, k, scale, block_q, block_k)
+    scale = _validate(q, k, scale, block_q, block_k, causal, window, bias)
+    window = None if window is None else int(window)
     b, n, s_q, d = q.shape
-    s_k = k.shape[2]
+    s_k, n_kv = k.shape[2], k.shape[1]
 
     bias_flat = None
     if bias is not None:
@@ -592,9 +696,9 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
             bias[:, :, 0, :], (b, n, s_k)).reshape(b * n, 1, s_k)
 
     bn = b * n
-    out, _ = _flash(q.reshape(bn, s_q, d), k.reshape(bn, s_k, d),
-                    v.reshape(bn, s_k, v.shape[-1]), bias_flat, scale, causal,
-                    block_q, block_k)
+    out, _ = _flash(q.reshape(bn, s_q, d), k.reshape(b * n_kv, s_k, d),
+                    v.reshape(b * n_kv, s_k, v.shape[-1]), bias_flat, scale,
+                    causal, block_q, block_k, window)
     return out.reshape(b, n, s_q, v.shape[-1])
 
 

@@ -96,6 +96,12 @@ SPECS = {
     "Mul": (dict(), (8,)),
     "MulConstant": (dict(constant=2.0), (8,)),
     "MultiHeadAttention": (dict(n_head=2), SEQ8),
+    "GroupedQueryAttention": (dict(n_head=4, n_kv_head=2, head_dim=4,
+                                   window=3, rope_theta=10000.0), SEQ8),
+    "RMSNorm": (dict(), (8,)),
+    "SparseMoE": (dict(n_experts=4, width=8, top_k=2, experts_held=(1, 2)),
+                  SEQ8),
+    "SwiGLU": (dict(width=16), SEQ8),
     "Narrow": (dict(dim=1, offset=1, length=4), (8,)),
     "Negative": (dict(), (8,)),
     "PReLU": (dict(), (8,)),
@@ -156,6 +162,7 @@ EXCLUDED = {
     "SparseEmbedding": "covered in test_layer_extras (sparse input)",
     "ConvLSTM3D": "covered by test_golden_layers (heavy; 5D scan)",
     "BERT": "4-input composite; covered by test_attention",
+    "DecoderBlock": "composite of layer objects; covered by test_causal_lm",
     "TransformerLayer": "composite; covered by test_attention",
     "WithinChannelLRN2D": "alias-style variant of LRN2D",
 }
