@@ -1272,8 +1272,12 @@ def lm_train_metrics() -> Dict[str, Any]:
     token's pick of an expert, by whether this chip holds that expert) and
     the summaries ``load_max`` / ``load_mean`` (``zoo_moe_expert_tokens_max``
     / ``zoo_moe_expert_tokens_mean``: a sample a step and expert layer, the
-    most and the mean tokens over the experts held). One call per model —
-    the model holds the children."""
+    most and the mean tokens over the experts held), ``moe_calls`` /
+    ``moe_calls_compact`` (counters ``zoo_moe_calls_total`` /
+    ``zoo_moe_calls_compact_total``: expert-layer calls of training steps,
+    and those whose held assignments went through in the one compacted pass,
+    as the step itself reported). One call per model — the model holds the
+    children."""
     reg = get_registry()
     assignments = reg.counter(
         "zoo_moe_assignments_total",
@@ -1293,6 +1297,13 @@ def lm_train_metrics() -> Dict[str, Any]:
             "zoo_moe_expert_tokens_mean",
             "Mean tokens routed to a held expert, a sample a step and "
             "expert layer.").labels(),
+        "moe_calls": reg.counter(
+            "zoo_moe_calls_total",
+            "Expert-layer calls of training steps.").labels(),
+        "moe_calls_compact": reg.counter(
+            "zoo_moe_calls_compact_total",
+            "Expert-layer calls of training steps whose held assignments "
+            "went through in one compacted pass.").labels(),
     }
 
 

@@ -111,8 +111,11 @@ class SparseMoE(KerasLayer):
     are: ``select_bias`` (n_experts,), after a step
     ``b += bias_rate * sign(mean(n) - n_e)`` then centred, ``n_e`` the
     step's tokens routed to expert e (the auxiliary-loss-free balancing of
-    Wang et al. 2024); ``expert_tokens`` (n_experts,), that ``n`` itself,
-    which ``Estimator.train`` hands to the counters with the loss.
+    Wang et al. 2024); ``expert_tokens`` (n_experts,), that ``n`` itself, and
+    ``compact`` (), 1 where the step's held assignments went through in one
+    compacted pass and 0 where they overflowed it or no such pass exists
+    (``held_experts_ffn``): ``Estimator.train`` hands both to the counters
+    with the loss.
     """
 
     has_state = True
@@ -145,6 +148,7 @@ class SparseMoE(KerasLayer):
         self.add_weight("experts_w_down", (count, self.width, d), init)
         self.add_state("select_bias", (self.n_experts,), "zeros")
         self.add_state("expert_tokens", (self.n_experts,), "zeros")
+        self.add_state("compact", (), "zeros")
 
     def call(self, params, x, state=None, training=False, **kw):
         from analytics_zoo_tpu.parallel.moe import held_experts_ffn, route_topk
@@ -157,7 +161,7 @@ class SparseMoE(KerasLayer):
                 flat, params["router"], state["select_bias"], self.top_k,
                 self.route_norm, self.route_scale)
         with jax.named_scope("moe.experts"):
-            y = held_experts_ffn(
+            y, compact = held_experts_ffn(
                 flat, picked, weights, params["experts_w_gate_up"],
                 params["experts_w_down"], self.n_experts,
                 self.experts_held[0])
@@ -170,5 +174,6 @@ class SparseMoE(KerasLayer):
             bias = state["select_bias"] + self.bias_rate * jnp.sign(
                 jnp.mean(counts) - counts)
             state = {"select_bias": bias - jnp.mean(bias),
-                     "expert_tokens": counts}
+                     "expert_tokens": counts,
+                     "compact": compact.astype(jnp.float32)}
         return y.reshape(shape), state

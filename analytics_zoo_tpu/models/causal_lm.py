@@ -158,12 +158,14 @@ class CausalLM(KerasNet):
 
     def train_stats(self, model_state) -> Dict:
         """What a train step returns beside its loss: the step's tokens and,
-        a row an expert layer, the tokens routed to each expert."""
+        a row an expert layer, the tokens routed to each expert and whether
+        the held ones went through in the compacted pass."""
         stats = {"tokens": model_state[self.name]["tokens"]}
-        moe = [model_state[b.name]["expert_tokens"] for b in self.blocks
-               if b.has_state]
+        moe = [model_state[b.name] for b in self.blocks if b.has_state]
         if moe:
-            stats["expert_tokens"] = jnp.stack(moe)
+            stats["expert_tokens"] = jnp.stack(
+                [m["expert_tokens"] for m in moe])
+            stats["moe_compact"] = jnp.stack([m["compact"] for m in moe])
         return stats
 
     def record_train_stats(self, stats: Dict) -> None:
@@ -178,6 +180,8 @@ class CausalLM(KerasNet):
         counts = stats.get("expert_tokens")
         if counts is None:
             return
+        obs["moe_calls"].inc(len(counts))
+        obs["moe_calls_compact"].inc(float(np.sum(stats["moe_compact"])))
         lo, n = self.experts_held
         held = np.asarray(counts)[:, lo:lo + n]
         obs["assignments_held"].inc(float(held.sum()))

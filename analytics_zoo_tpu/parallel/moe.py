@@ -130,6 +130,10 @@ def place_moe_params(params, mesh: Mesh, expert_axis: str = "expert"):
 # rows x contraction x columns of one grouped-product tile; the largest of
 # the published sizes' divisors that the 16 MiB of scoped VMEM holds
 _GMM_TILING = (512, 1024, 1024)
+# the largest source of a row gather that the chip serves at its fast rate:
+# 32 768 rows out of 64 MiB take 0.24 ms on a v5e, out of 128 MiB 1.16 ms,
+# sorted or not (the compiler keeps the smaller one in the chip's near memory)
+_GATHER_SOURCE_BYTES = 64 << 20
 # tokens of one chunk through the experts held: a sorted chunk holds chunk*k
 # rows whatever the routing, so the chunk bounds the layer's buffers
 _CHUNK_TOKENS = 4096
@@ -188,6 +192,12 @@ _rows_from_sorted.defvjp(lambda y, order, inv: (y[inv], order),
                          lambda order, g: (g[order], None, None))
 
 
+def _tiling(m: int, kdim: int, n: int):
+    """``_GMM_TILING``, no tile larger than the product it cuts."""
+    return (min(_GMM_TILING[0], m), min(_GMM_TILING[1], kdim),
+            min(_GMM_TILING[2], n))
+
+
 def _gmm(lhs, rhs, sizes, offset):
     """Rows of ``lhs`` times their expert's matrix: jax's bundled grouped
     matrix product (megablox), told which experts ``rhs`` holds."""
@@ -195,11 +205,7 @@ def _gmm(lhs, rhs, sizes, offset):
 
     from analytics_zoo_tpu.ops.flash_attention import _interpret
 
-    m, kdim = lhs.shape
-    n = rhs.shape[-1]
-    tiling = (min(_GMM_TILING[0], m), min(_GMM_TILING[1], kdim),
-              min(_GMM_TILING[2], n))
-    return gmm(lhs, rhs, sizes, lhs.dtype, tiling,
+    return gmm(lhs, rhs, sizes, lhs.dtype, _tiling(*lhs.shape, rhs.shape[-1]),
                jnp.asarray(offset, jnp.int32), None, False, _interpret())
 
 
@@ -222,17 +228,11 @@ def _held_chunk(x, picked, weights, w_gate_up, w_down, n_experts: int,
                    axis=1).astype(x.dtype)
 
 
-def held_experts_ffn(x, picked, weights, w_gate_up, w_down, n_experts: int,
-                     offset: int = 0):
-    """The held experts' part of a top-k SwiGLU expert layer, nothing
-    dropped: ``sum over the picks of a token that fall on a held expert of
-    weight * W_down_e(silu(W_gate_e x) * W_up_e x)``. ``x`` (T, d);
-    ``picked`` / ``weights`` (T, k) from :func:`route_topk` (expert numbers
-    over all ``n_experts``); ``w_gate_up`` (count, d, 2h) with gate and up
-    side by side, ``w_down`` (count, h, d): the experts numbered ``offset``
-    .. ``offset + count - 1``. Tokens go through in chunks of
-    ``_CHUNK_TOKENS``, each rematerialised in the backward pass. On one chip
-    no exchange is made: what the absent experts would add is left out."""
+def _held_chunks(x, picked, weights, w_gate_up, w_down, n_experts: int,
+                 offset: int):
+    """All the tokens through :func:`_held_chunk`, ``_CHUNK_TOKENS`` at a
+    time, each chunk rematerialised in the backward pass: buffers of
+    chunk * k rows whatever the routing."""
     t, d = x.shape
     chunk = min(_CHUNK_TOKENS, t)
     if t % chunk:
@@ -252,3 +252,206 @@ def held_experts_ffn(x, picked, weights, w_gate_up, w_down, n_experts: int,
                                      picked.reshape(n, chunk, k),
                                      weights.reshape(n, chunk, k)))
     return y.reshape(t, d)
+
+
+# The held rows only. Of a call's t*k assignments the experts held get their
+# share, count / n_experts of them from a uniform router; a buffer of twice
+# that share holds them in expert order, and everything between the router and
+# the sum back into tokens works on that buffer: one pass a call, no chunks,
+# nothing rematerialised but an elementwise product. Rows past the held total
+# belong to nobody. The grouped product never writes them (given the held
+# groups' sizes alone it fills nothing in), so they are whatever the memory
+# held, and nothing reads them: the way back is a gather by each held pick's
+# own row.
+
+
+def _held_capacity(t: int, k: int, count: int, n_experts: int) -> int:
+    """Rows of the compacted buffer: twice the held experts' share of the
+    t*k assignments, rounded up to the grouped product's row tile."""
+    tile = _GMM_TILING[0]
+    share = -(-2 * t * k * count // n_experts)
+    return -(-share // tile) * tile
+
+
+def _megablox():
+    """The module of jax's grouped products themselves (the package's own
+    ``gmm`` is their differentiable wrapper, which the chunks use)."""
+    import importlib
+
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+def _held_gmm(lhs, rhs, sizes, transpose_rhs: bool = False):
+    """Rows of ``lhs`` (r, .) times their expert's matrix (transposed on
+    request), ``rhs`` and ``sizes`` over the held experts alone: the rows past
+    ``sum(sizes)`` are not written."""
+    from analytics_zoo_tpu.ops.flash_attention import _interpret
+
+    m, kdim = lhs.shape
+    n = rhs.shape[1 if transpose_rhs else 2]
+    return _megablox().gmm(lhs, rhs, sizes, lhs.dtype, _tiling(m, kdim, n),
+                           None, None, transpose_rhs, _interpret())
+
+
+def _held_tgmm(lhs, rhs, sizes):
+    """(count, columns of lhs, columns of rhs): an expert's sum over its rows
+    of lhs_row^T rhs_row; rows past ``sum(sizes)`` are masked out."""
+    from analytics_zoo_tpu.ops.flash_attention import _interpret
+
+    m, kdim = lhs.shape
+    return _megablox().tgmm(lhs.swapaxes(0, 1), rhs, sizes, lhs.dtype,
+                            _tiling(m, kdim, rhs.shape[1]), None,
+                            sizes.shape[0], None, _interpret())
+
+
+def _swiglu(gate_up):
+    """(r, 2h), gate and up side by side -> silu(gate) * up (r, h)."""
+    gate, up = jnp.split(gate_up, 2, axis=-1)
+    return jax.nn.silu(gate) * up
+
+
+def _sum_of_held(rows, slot, held, weights=None):
+    """(t, d): a token's float32 sum, over those of its picks that are
+    ``held`` (t, k), of ``rows[slot]``, each times its weight where given.
+    All t*k rows are gathered (a pick that is not held reads row 0 and counts
+    as nothing), a block of columns at a time, the block small enough for
+    the fast gather."""
+    r, d = rows.shape
+    blocks = -(-r * d * rows.dtype.itemsize // _GATHER_SOURCE_BYTES)
+    while d % blocks:
+        blocks += 1
+    out = []
+    for cols in jnp.split(rows, blocks, axis=1):
+        mine = jnp.where(held[..., None], cols[slot], 0).astype(jnp.float32)
+        if weights is not None:
+            mine = mine * weights[..., None]
+        out.append(jnp.sum(mine, axis=1))
+    return jnp.concatenate(out, axis=1).astype(rows.dtype)
+
+
+def _held_compact(x, weights, w_gate_up, w_down, key, sizes, rows: int):
+    """Every token through the experts held in one pass over ``rows`` rows.
+    ``key`` (t*k,): an assignment's expert among the ``count`` held, or
+    ``count``; ``sizes`` (count,): the held experts' rows, ``rows`` or fewer
+    in all. Returns y (t, d) and what the backward pass keeps."""
+    k = weights.shape[-1]
+    # held assignments first, by expert, then by token: the source of each row
+    source = jnp.argsort(key, stable=True)[:rows].astype(jnp.int32)
+    token = source // k
+    # a held pick's row; the rows past the held total send theirs to picks
+    # that are not held, where nobody looks
+    slot = jnp.zeros_like(key).at[source].set(
+        jnp.arange(rows, dtype=jnp.int32), unique_indices=True)
+    held = (key < sizes.shape[0]).reshape(-1, k)
+    slot = jnp.where(held, slot.reshape(-1, k), 0)
+    xs = x[token]
+    gate_up = _held_gmm(xs, w_gate_up, sizes)
+    ys = _held_gmm(_swiglu(gate_up), w_down, sizes)
+    y = _sum_of_held(ys, slot, held, weights)
+    return y, (token, source, slot, xs, gate_up, ys)
+
+
+def _held_compact_bwd(kept, weights, w_gate_up, w_down, key, sizes, g):
+    """The gradients of :func:`_held_compact` to x, weights and both expert
+    tensors, from ``g`` (t, d): gathers of r rows and the grouped products'
+    own transposes; the sum of a token's rows is float32 as forward."""
+    token, source, slot, xs, gate_up, ys = kept
+    held = (key < sizes.shape[0]).reshape(slot.shape)
+    g_rows = g[token].astype(jnp.float32)
+    d_ys = (g_rows * weights.reshape(-1)[source][:, None]).astype(ys.dtype)
+    d_row = jnp.sum(ys.astype(jnp.float32) * g_rows, axis=-1)
+    # (a scatter of r numbers to their picks, not a gather of t*k; what the
+    # rows past the held total send lands on picks that are not held)
+    d_weights = jnp.zeros(key.shape, jnp.float32).at[source].set(
+        d_row, unique_indices=True).reshape(slot.shape)
+    d_weights = jnp.where(held, d_weights, 0).astype(weights.dtype)
+    hidden, swiglu_vjp = jax.vjp(_swiglu, gate_up)
+    d_down = _held_tgmm(hidden, d_ys, sizes)
+    (d_gate_up,) = swiglu_vjp(_held_gmm(d_ys, w_down, sizes, True))
+    d_gate_up_w = _held_tgmm(xs, d_gate_up, sizes)
+    d_xs = _held_gmm(d_gate_up, w_gate_up, sizes, True)
+    return _sum_of_held(d_xs, slot, held), d_weights, d_gate_up_w, d_down
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _held_ffn(x, picked, weights, w_gate_up, w_down, key, sizes, compact,
+              n_experts: int, offset: int):
+    """One pass over the compacted rows where ``compact`` says they fit, the
+    chunks of all t*k rows where not; the backward pass takes the same
+    side."""
+    return _held_ffn_fwd(x, picked, weights, w_gate_up, w_down, key, sizes,
+                         compact, n_experts, offset)[0]
+
+
+def _held_ffn_fwd(x, picked, weights, w_gate_up, w_down, key, sizes, compact,
+                  n_experts, offset):
+    rows = _held_capacity(*picked.shape, sizes.shape[0], n_experts)
+
+    def one_pass():
+        return _held_compact(x, weights, w_gate_up, w_down, key, sizes, rows)
+
+    def chunks():
+        y = _held_chunks(x, picked, weights, w_gate_up, w_down, n_experts,
+                         offset)
+        kept = jax.eval_shape(one_pass)[1]
+        return y, jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype), kept)
+
+    y, kept = jax.lax.cond(compact, one_pass, chunks)
+    return y, (kept, x, picked, weights, w_gate_up, w_down, key, sizes,
+               compact)
+
+
+def _held_ffn_bwd(n_experts, offset, res, g):
+    kept, x, picked, weights, w_gate_up, w_down, key, sizes, compact = res
+
+    def chunks():
+        _, vjp = jax.vjp(
+            lambda x_, w_, a, b: _held_chunks(x_, picked, w_, a, b, n_experts,
+                                              offset),
+            x, weights, w_gate_up, w_down)
+        return vjp(g)
+
+    d_x, d_weights, d_gate_up, d_down = jax.lax.cond(
+        compact,
+        lambda: _held_compact_bwd(kept, weights, w_gate_up, w_down, key,
+                                  sizes, g),
+        chunks)
+    return d_x, None, d_weights, d_gate_up, d_down, None, None, None
+
+
+_held_ffn.defvjp(_held_ffn_fwd, _held_ffn_bwd)
+
+
+def held_experts_ffn(x, picked, weights, w_gate_up, w_down, n_experts: int,
+                     offset: int = 0):
+    """The held experts' part of a top-k SwiGLU expert layer, nothing
+    dropped: ``sum over the picks of a token that fall on a held expert of
+    weight * W_down_e(silu(W_gate_e x) * W_up_e x)``. ``x`` (T, d);
+    ``picked`` / ``weights`` (T, k) from :func:`route_topk` (expert numbers
+    over all ``n_experts``); ``w_gate_up`` (count, d, 2h) with gate and up
+    side by side, ``w_down`` (count, h, d): the experts numbered ``offset``
+    .. ``offset + count - 1``. On one chip no exchange is made: what the
+    absent experts would add is left out.
+
+    Returns ``(y, compact)``. The held assignments go through in one pass
+    over a buffer of :func:`_held_capacity` rows, twice the held experts'
+    share; ``compact`` (a bool scalar on the device) says that they fitted. A
+    call whose routing sends more than that to the experts held takes all
+    T*k assignments through in chunks of ``_CHUNK_TOKENS`` tokens instead,
+    each rematerialised in the backward pass; so does every call, with no
+    choice compiled in, where the experts held are half of all or more and
+    the buffer would hold every assignment anyway."""
+    t, k = picked.shape
+    count = w_gate_up.shape[0]
+    rows = _held_capacity(t, k, count, n_experts)
+    if rows >= t * k:
+        return (_held_chunks(x, picked, weights, w_gate_up, w_down, n_experts,
+                             offset), jnp.zeros((), jnp.bool_))
+    local = picked.reshape(-1) - offset
+    key = jnp.where((local >= 0) & (local < count), local, count)
+    sizes = jnp.sum(jax.nn.one_hot(key, count, dtype=jnp.int32), axis=0)
+    compact = jnp.sum(sizes) <= rows
+    return _held_ffn(x, picked, weights, w_gate_up, w_down, key, sizes,
+                     compact, n_experts, offset), compact

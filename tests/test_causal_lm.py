@@ -54,6 +54,11 @@ def _rows(cfg=CFG, n=2, seed=3):
     return t[:, :-1], t[:, 1:]
 
 
+def _counter(text, name):
+    return sum(float(line.split()[-1]) for line in text.splitlines()
+               if line.startswith(name + " ") or line.startswith(name + "{"))
+
+
 def _close(a, b, tol):
     a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
     assert np.max(np.abs(a - b)) <= tol * max(1.0, np.max(np.abs(b))), (
@@ -147,6 +152,10 @@ def test_three_estimator_steps_with_adam_and_the_bias_update():
     text = get_registry().render()
     assert "zoo_train_tokens_total" in text
     assert 'zoo_moe_assignments_total{held="true"}' in text
+    # two expert layers a step, three steps; half the experts held, so no
+    # compacted pass exists to take
+    assert _counter(text, "zoo_moe_calls_total") >= 6
+    assert "zoo_moe_calls_compact_total" in text
 
 
 @pytest.mark.parametrize("window", [None, 8, 13, 200])
@@ -276,6 +285,188 @@ def test_a_router_forced_onto_one_held_expert_drops_nothing(chunks_of_64):
         np.asarray(new["expert_tokens"]), [0, 0, 0, 128, 0, 0, 0, 128])
     _close(y, want, 1e-5)                 # all 128 tokens, none dropped
     assert float(jnp.min(jnp.max(jnp.abs(y), axis=-1))) > 0
+
+
+def _share_of_sixteen(seed=0, tokens=1024):
+    """Two of sixteen experts held, top-2, 1024 tokens: 2048 assignments, a
+    compacted buffer of 512 rows (twice the 256 a uniform router sends)."""
+    from analytics_zoo_tpu.keras.layers import SparseMoE
+
+    cfg = dict(CFG, num_experts=2, router_num_experts=16,
+               experts_held_offset=6)
+    w = ref.init_weights(cfg, jax.random.PRNGKey(seed))["layers"][1]
+    layer = SparseMoE(16, 32, top_k=2, experts_held=(6, 2), n_shared=0,
+                      route_scale=2.826)
+    layer.ensure_built((None, 64))
+    p = {"router": 4.0 * w["router"],       # scores spread enough to pick by
+         "experts_w_gate_up": jnp.concatenate(
+             [w["experts"]["w_gate"], w["experts"]["w_up"]], axis=-1),
+         "experts_w_down": w["experts"]["w_down"]}
+    m = jax.random.normal(jax.random.PRNGKey(seed + 1), (tokens, 64))
+    g = jax.random.normal(jax.random.PRNGKey(seed + 2), (tokens, 64))
+    return cfg, w, layer, p, m, g
+
+
+def _value_and_grads(layer, p, m, g, bias):
+    """y, the step's state, and the gradients of sum(y * g) to x, the router
+    and both expert tensors."""
+    state = {"select_bias": bias, "expert_tokens": jnp.zeros_like(bias)}
+
+    def loss(p_, m_):
+        y, st = layer.call(p_, m_, state=state, training=True)
+        return jnp.sum(y * g), (y, st)
+
+    (_, (y, st)), grads = jax.value_and_grad(loss, (0, 1), has_aux=True)(p, m)
+    return y, st, (grads[1], grads[0]["router"],
+                   grads[0]["experts_w_gate_up"], grads[0]["experts_w_down"])
+
+
+def _chunks_only(monkeypatch):
+    """The parent's expert layer: every call through the chunks."""
+    from analytics_zoo_tpu.parallel import moe
+
+    monkeypatch.setattr(moe, "_held_capacity", lambda t, k, *_: t * k)
+
+
+def _reference_value_and_grads(cfg, w, p, m, g, bias):
+    """The same from benchmark/reference/trinity.py's ``expert_layer`` less
+    its shared expert, in the program's layout."""
+    def loss(w_, m_):
+        y, _ = ref.expert_layer(w_, m_, bias, cfg)
+        return jnp.sum((y - ref._swiglu(w_["shared"], m_, jnp.matmul)) * g), y
+
+    w = dict(w, router=p["router"])
+    (_, y), (dw, dm) = jax.value_and_grad(loss, (0, 1), has_aux=True)(w, m)
+    y = y - ref._swiglu(w["shared"], m, jnp.matmul)
+    return y, (dm, dw["router"], jnp.concatenate(
+        [dw["experts"]["w_gate"], dw["experts"]["w_up"]], axis=-1),
+        dw["experts"]["w_down"])
+
+
+NAMES = ("x", "router", "experts_w_gate_up", "experts_w_down")
+
+
+@pytest.mark.parametrize("against", ["chunks", "reference"])
+def test_the_compacted_pass_is_the_expert_layer(against, chunks_of_64,
+                                                monkeypatch):
+    """(a) random routing, 2 of 16 held: the one pass over 512 rows gives the
+    chunks' output and gradients, and the reference's."""
+    cfg, w, layer, p, m, g = _share_of_sixteen()
+    bias = 0.01 * jax.random.normal(jax.random.PRNGKey(7), (16,))
+    with jax.default_matmul_precision("highest"):
+        y, st, grads = _value_and_grads(layer, p, m, g, bias)
+        assert float(st["compact"]) == 1.0
+        held = float(jnp.sum(st["expert_tokens"][6:8]))
+        assert 128 < held <= 512            # a real share, inside the buffer
+        if against == "chunks":
+            _chunks_only(monkeypatch)
+            want_y, st2, want = _value_and_grads(layer, p, m, g, bias)
+            assert float(st2["compact"]) == 0.0
+        else:
+            want_y, want = _reference_value_and_grads(cfg, w, p, m, g, bias)
+    _close(y, want_y, 1e-5)
+    for name, a, b in zip(NAMES, grads, want):
+        assert float(jnp.max(jnp.abs(b))) > 0, name
+        _close(a, b, 1e-4)
+
+
+def test_more_held_rows_than_the_buffer_go_through_the_chunks(chunks_of_64,
+                                                              monkeypatch):
+    """(b) a bias that sends both picks of every token to the two held
+    experts: 2048 held rows for a buffer of 512, so the chunks run; every
+    token comes back, and the step's statistic says the pass was not taken."""
+    cfg, w, layer, p, m, g = _share_of_sixteen()
+    bias = jnp.zeros((16,)).at[6].set(10.0).at[7].set(9.0)
+    with jax.default_matmul_precision("highest"):
+        y, st, grads = _value_and_grads(layer, p, m, g, bias)
+        want_y, want = _reference_value_and_grads(cfg, w, p, m, g, bias)
+        # and it is the parent's computation, to the last bit
+        _chunks_only(monkeypatch)
+        same_y, _, same = _value_and_grads(layer, p, m, g, bias)
+    assert float(st["compact"]) == 0.0
+    np.testing.assert_array_equal(
+        np.asarray(st["expert_tokens"]), [0] * 6 + [1024, 1024] + [0] * 8)
+    assert float(jnp.min(jnp.max(jnp.abs(y), axis=-1))) > 0   # none dropped
+    _close(y, want_y, 1e-5)
+    for a, b, c in zip(grads, want, same):
+        _close(a, b, 1e-4)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(same_y))
+
+
+def test_rows_past_the_held_total_are_never_read(chunks_of_64, monkeypatch):
+    """(c) on the chip the grouped product leaves the rows between the held
+    total and the buffer's end as the memory was; the interpreter leaves
+    zeros. Poisoned with NaN, forward and backward: the same output and
+    gradients, all finite."""
+    from analytics_zoo_tpu.parallel import moe
+
+    cfg, w, layer, p, m, g = _share_of_sixteen(seed=3)
+    bias = jnp.zeros((16,))
+    with jax.default_matmul_precision("highest"):
+        want_y, _, want = _value_and_grads(layer, p, m, g, bias)
+        real, poisoned = moe._held_gmm, []
+
+        def gmm(lhs, rhs, sizes, transpose_rhs=False):
+            out = real(lhs, rhs, sizes, transpose_rhs)
+            live = jnp.arange(out.shape[0])[:, None] < jnp.sum(sizes)
+            poisoned.append(out.shape)
+            return jnp.where(live, out, jnp.nan)
+
+        monkeypatch.setattr(moe, "_held_gmm", gmm)
+        y, st, grads = _value_and_grads(layer, p, m, g, bias)
+    assert float(st["compact"]) == 1.0
+    # two forward, two backward (and the forward's shapes traced once more)
+    assert len(poisoned) >= 4 and {s[0] for s in poisoned} == {512}
+    for a, b in zip((y, *grads), (want_y, *want)):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _has_cond(jaxpr) -> bool:
+    """A `cond` among the layer's own operations (a kernel's body aside)."""
+    from jax._src import core
+
+    def inner(eqn):
+        if eqn.primitive.name == "pallas_call":
+            return
+        for v in eqn.params.values():
+            for u in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(u, core.ClosedJaxpr):
+                    yield u.jaxpr
+                elif isinstance(u, core.Jaxpr):
+                    yield u
+
+    return any(e.primitive.name == "cond" or any(map(_has_cond, inner(e)))
+               for e in jaxpr.eqns)
+
+
+@pytest.mark.parametrize("held,n_experts,k,tokens,compacted", [
+    ((2, 4), 8, 2, 128, False),        # half of the experts: the cases above
+    ((0, 8), 8, 2, 1024, False),       # all of them
+    ((1, 2), 4, 2, 4096, False),       # the serialization sweep's share
+    ((6, 2), 16, 2, 1024, True),       # an eighth
+    ((0, 16), 128, 8, 16384, True),    # the benchmark's cell
+])
+def test_a_compacted_pass_exists_only_where_it_is_smaller(held, n_experts, k,
+                                                          tokens, compacted):
+    """(d) the buffer is twice the held share of the assignments, a multiple
+    of the row tile; where that is all of them no second path is built."""
+    from analytics_zoo_tpu.parallel import moe
+
+    rows = moe._held_capacity(tokens, k, held[1], n_experts)
+    assert rows % 512 == 0 and rows >= 2 * tokens * k * held[1] / n_experts
+    assert rows - 512 < 2 * tokens * k * held[1] / n_experts
+    assert (rows < tokens * k) == compacted
+    S = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(lambda *a: moe.held_experts_ffn(
+        *a, n_experts, held[0]))(
+            S((tokens, 8), jnp.float32), S((tokens, k), jnp.int32),
+            S((tokens, k), jnp.float32), S((held[1], 8, 16), jnp.float32),
+            S((held[1], 8, 8), jnp.float32))
+    assert _has_cond(jaxpr.jaxpr) == compacted
+    if compacted and tokens == 16384:
+        assert rows == 32768
 
 
 def test_the_eight_shares_add_up_to_the_uncut_expert_layer():
