@@ -31,11 +31,20 @@ def manifest(root: str = ROOT) -> dict:
     return _json(os.path.join(root, "BENCHMARK.json"))
 
 
+def held_out(root: str = ROOT) -> dict:
+    """The entries taken out of BENCHMARK.json, as they stood there: no cell
+    of theirs runs (none has limits), the tests hold them to the contract's
+    letters while they wait."""
+    return _json(os.path.join(root, manifest(root)["paths"][0],
+                              "held_out.json"))
+
+
 def resolve(name: str, root: str = ROOT) -> dict:
     """The cell `name`: its entry, its configuration (the file's top level
     with `assumed` folded in), its traffic mix, its limits and the metrics it
     reports with and without a trace."""
     bench = manifest(root)
+    here = os.path.join(root, bench["paths"][0])
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
         raise SystemExit(f"no workload {name!r}; known: {sorted(cells)}")
@@ -43,7 +52,6 @@ def resolve(name: str, root: str = ROOT) -> dict:
     conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
     cfg = _json(os.path.join(root, conf["file"]))
     cfg.update(cfg.get("assumed", {}))
-    here = os.path.join(root, bench["paths"][0])
     traffic = _json(os.path.join(here, "traffic", cell["traffic"] + ".json"))
     limits = _json(os.path.join(here, "limits", name + ".json"))
 

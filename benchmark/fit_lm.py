@@ -10,12 +10,17 @@ drives the smaller models, with what that size and that model force:
   keeps the start and the first gradient on the host, and carries the
   selection bias, which a step updates outside the gradient;
 - the traced run keeps the device planes for a reduction by kernel and scope
-  (`trace_lm.py`) and hands the compiled step's text over for the scopes.
+  (`trace_lm.py`) and hands the compiled step's text over for the scopes;
+- a traffic file may state `row_sets`: the seed then gives so many sets of
+  one call's rows, and the call of the estimator's epoch e is fed set e mod
+  `row_sets`, so that no row is fed twice in a run (what replayed rows and
+  what fresh ones do to the expert load, call by call: PERF.md, PR 32).
 """
 
 from __future__ import annotations
 
 import gc
+import json
 import time
 
 import numpy as np
@@ -37,6 +42,26 @@ def counters() -> dict:
     return out
 
 
+def call_series(marks: list) -> dict:
+    """What each call of the window took and what its router did, from the
+    clock and the counters read after every call (`marks`: the window's start,
+    then one a call): seconds, the held share of its assignments in %, and how
+    many of its expert-layer calls overflowed the compacted buffer."""
+    out = {"call_s": [], "call_held_share": [], "call_overflows": []}
+    for (t0, c0), (t1, c1) in zip(marks, marks[1:]):
+        def d(name):
+            return c1.get(name, 0.0) - c0.get(name, 0.0)
+
+        out["call_s"].append(t1 - t0)
+        total = d("zoo_moe_assignments_total")
+        out["call_held_share"].append(
+            d("zoo_moe_assignments_total_held") / total * 100.0 if total
+            else None)
+        out["call_overflows"].append(
+            d("zoo_moe_calls_total") - d("zoo_moe_calls_compact_total"))
+    return out
+
+
 def first_gradient(opt_state, b1: float):
     """The first gradient as the optimizer got it, from its state after one
     step: Adam's first moment is (1 - b1) times that gradient. On the host."""
@@ -51,7 +76,34 @@ def first_gradient(opt_state, b1: float):
     raise ValueError("no first moment in the optimizer's state")
 
 
-def first_steps(prog, fs, traffic: dict, tape, b1: float) -> dict:
+def row_sets(cfg: dict, traffic: dict, seed: int) -> tuple:
+    """All the rows of a run, (inputs, labels), one draw from the seed with a
+    leading axis of sets: `row_sets` of them (one where the traffic file
+    states none), each the rows of one call."""
+    sets = traffic.get("row_sets", 1)
+    n = traffic["batch"] * traffic["steps_per_call"]
+    x, y = data.rows(cfg, sets * n, np.random.default_rng(seed))
+    return (x.reshape(sets, n, *x.shape[1:]), y.reshape(sets, n, *y.shape[1:]))
+
+
+class Feed:
+    """Each set of rows behind a cached feature set of its own, all built at
+    set-up; `of(epoch)` is the one a call of that epoch trains on."""
+
+    def __init__(self, traffic: dict, xs, ys):
+        make = cells.load(traffic["feature_set"])
+        self.sets = [make(traffic, x, y) for x, y in zip(xs, ys)]
+
+    def of(self, epoch: int):
+        return self.sets[epoch % len(self.sets)]
+
+    def close(self) -> None:
+        for fs in self.sets:
+            if hasattr(fs, "close"):
+                fs.close()
+
+
+def first_steps(prog, feed: Feed, traffic: dict, tape, b1: float) -> dict:
     """Drive the estimator through its first steps with the window's own
     call: stop after step 1 and after step `check_steps`, and keep what the
     state showed there, on the host in the reference's layout."""
@@ -63,13 +115,16 @@ def first_steps(prog, fs, traffic: dict, tape, b1: float) -> dict:
     def kept(tree):
         return prog.to_reference_layout(jax.device_get(tree), np)
 
+    def train_to(step):
+        est.train(feed.of(est.run_state.epoch), prog.criterion,
+                  end_trigger=MaxIteration(step), batch_size=batch)
+
     est._ensure_state()
     start = kept(est.tstate.params)
-    est.train(fs, prog.criterion, end_trigger=MaxIteration(1), batch_size=batch)
+    train_to(1)
     first = prog.to_reference_layout(
         first_gradient(est.tstate.opt_state, b1), np)
-    est.train(fs, prog.criterion, end_trigger=MaxIteration(steps),
-              batch_size=batch)
+    train_to(steps)
     end = kept(est.tstate.params)
     change = jax.tree_util.tree_map(np.subtract, end, start)
     return {"losses": [tape.losses[k + 1] for k in range(steps)],
@@ -194,17 +249,20 @@ def lower_precision(stated: str):
     return safe
 
 
-def reference_steps(cfg, traffic, x, y, took, start, cast=lambda t: t,
+def reference_steps(cfg, traffic, xs, ys, took, start, cast=lambda t: t,
                     batch_rows=None) -> dict:
-    """The plain reference through the same steps from the same start
-    (`batch_rows`: only so many rows of each batch, a planted fault)."""
+    """The plain reference through the same steps from the same start, on
+    the rows the program was fed: `took` = (epoch, batch) of each step, the
+    epoch's set (`Feed.of`) in the epoch's order (`batch_rows`: only so many
+    rows of each batch, a planted fault)."""
     import jax.numpy as jnp
 
-    ref, batch, n = models.reference(cfg), traffic["batch"], len(y)
+    ref, batch = models.reference(cfg), traffic["batch"]
     order = cells.load(traffic["epoch_order"])
     batches = []
     for epoch, k in took:
-        idx = order(epoch, n)[k * batch:(k + 1) * batch][:batch_rows]
+        x, y = xs[epoch % len(xs)], ys[epoch % len(ys)]
+        idx = order(epoch, len(y))[k * batch:(k + 1) * batch][:batch_rows]
         batches.append((jnp.asarray(x[idx]), jnp.asarray(y[idx])))
     spec = cfg["optimizer"]
     opt = cells.load(spec["reference"])(**spec["args"])
@@ -236,16 +294,15 @@ def run(cell: dict, seed: int, seconds: float, traced: bool, t_start: float,
     cfg, traffic = cell["config"], cell["traffic"]
     dev = harness.device(cell["chips"], any_platform)
     zoo.init_nncontext()
-    rng = np.random.default_rng(seed)
-    x, y = data.rows(cfg, traffic["batch"] * traffic["steps_per_call"], rng)
+    xs, ys = row_sets(cfg, traffic, seed)
     # (the program first: where it lacks the model, as a parent commit does,
     # the run ends here, before the feed's threads exist)
     prog = models_lm.Program(cfg, seed)
-    fs = cells.load(traffic["feature_set"])(traffic, x, y)
+    feed = Feed(traffic, xs, ys)
     est, tape = prog.est, fit.LossTape()
     est.train_summary = tape
     b1 = cfg["optimizer"]["args"].get("beta_1", 0.9)
-    seen = first_steps(prog, fs, traffic, tape, b1)
+    seen = first_steps(prog, feed, traffic, tape, b1)
     start = seen.pop("start")
     took = fit.steps_taken(traffic)
 
@@ -253,7 +310,8 @@ def run(cell: dict, seed: int, seconds: float, traced: bool, t_start: float,
 
     def call():
         with spans("bench.train_call"):
-            est.train(fs, prog.criterion, batch_size=traffic["batch"],
+            est.train(feed.of(est.run_state.epoch), prog.criterion,
+                      batch_size=traffic["batch"],
                       end_trigger=MaxEpoch(est.run_state.epoch + 1))
             jax.block_until_ready(est.tstate)
 
@@ -269,11 +327,16 @@ def run(cell: dict, seed: int, seconds: float, traced: bool, t_start: float,
         ctx["counters"]["window_start"] = counters()
         t0 = time.perf_counter()
         setup_s = t0 - t_start
-        while time.perf_counter() - t0 < seconds:
+        marks = [(t0, ctx["counters"]["window_start"])]
+        while marks[-1][0] - t0 < seconds:
             call()
             calls += 1
-        elapsed = time.perf_counter() - t0
-        ctx["counters"]["window_end"] = counters()
+            marks.append((time.perf_counter(), counters()))
+        elapsed, ctx["counters"]["window_end"] = marks[-1][0] - t0, marks[-1][1]
+    ctx["series"] = call_series(marks)
+    harness.log("calls " + json.dumps(
+        {k: [v if v is None else round(v, 3) for v in vs]
+         for k, vs in ctx["series"].items()}))
     steps = calls * traffic["steps_per_call"]
     items = steps * traffic["batch"] * traffic.get("items_per_row", 1)
     seq = cfg["seq_len"]
@@ -292,9 +355,8 @@ def run(cell: dict, seed: int, seconds: float, traced: bool, t_start: float,
                 f" on the chip, resident set {host_peak_gb():.1f} GB")
 
     # free the program's state, then let the reference follow
-    if hasattr(fs, "close"):
-        fs.close()
-    del fs, prog, est, call, kept
+    feed.close()
+    del feed, prog, est, call, kept
     # jax's cache of jitted functions holds the step, the step's closure the
     # estimator, and the estimator its 8.5 GB of state (in a cycle with the
     # model): drop the cache, then collect
@@ -303,7 +365,7 @@ def run(cell: dict, seed: int, seconds: float, traced: bool, t_start: float,
     still = (jax.devices()[0].memory_stats() or {}).get("bytes_in_use", 0)
     harness.log(f"program freed: {still / 1e9:.2f} GB still in use")
     t_ref = time.perf_counter()
-    want = reference_steps(cfg, traffic, x, y, took, start)
+    want = reference_steps(cfg, traffic, xs, ys, took, start)
     numbers = fit_numbers(seen, want)
     harness.log(f"reference followed {len(took)} steps and was compared in "
                 f"{time.perf_counter() - t_ref:.1f} s; peak resident set "
@@ -312,5 +374,8 @@ def run(cell: dict, seed: int, seconds: float, traced: bool, t_start: float,
             "numbers": numbers,
             "seen": seen, "want": want, "start": start,
             "end_to_end": {
-                "train_items_per_s_per_chip": items / elapsed / cell["chips"],
+                # the rate under the name the traffic file gives it: a later cell
+                # whose runs agree more closely can bring a rate, and a bound,
+                # of its own
+                traffic["rate_metric"]: items / elapsed / cell["chips"],
                 "setup_s": setup_s}}

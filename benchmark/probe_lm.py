@@ -32,14 +32,14 @@ def readings(cell, seed, faults: bool, seconds: float, half: bool = True):
     import jax
     import numpy as np
 
-    from benchmark import data, fit, fit_lm
+    from benchmark import fit, fit_lm
 
     cfg, traffic = cell["config"], cell["traffic"]
     run = fit_lm.run(cell, seed, seconds, False, time.perf_counter())
     yield "program", dict(
         run["numbers"], losses=run["seen"]["losses"],
         reference_losses=run["want"]["losses"],
-        items_per_s=run["end_to_end"]["train_items_per_s_per_chip"],
+        items_per_s=run["end_to_end"][traffic["rate_metric"]],
         memory_peak_bytes=run["ctx"]["memory"]["memory_peak_bytes"])
     if not faults:
         return
@@ -56,9 +56,8 @@ def readings(cell, seed, faults: bool, seconds: float, half: bool = True):
     leaf[...] = moved
     del run, seen, leaf, moved
     gc.collect()
-    x, y = data.rows(cfg, traffic["batch"] * traffic["steps_per_call"],
-                     np.random.default_rng(seed))
-    low = fit_lm.reference_steps(cfg, traffic, x, y, took, start,
+    xs, ys = fit_lm.row_sets(cfg, traffic, seed)
+    low = fit_lm.reference_steps(cfg, traffic, xs, ys, took, start,
                                  fit_lm.lower_precision(cfg["compute_dtype"]))
     yield "control_lower_precision", fit_lm.fit_numbers(low, want)
     del low
@@ -69,7 +68,7 @@ def readings(cell, seed, faults: bool, seconds: float, half: bool = True):
     del still
     if not half:
         return
-    half = fit_lm.reference_steps(cfg, traffic, x, y, took, start,
+    half = fit_lm.reference_steps(cfg, traffic, xs, ys, took, start,
                                   batch_rows=traffic["batch"] // 2)
     yield "fault_half_batch", fit_lm.fit_numbers(half, want)
 

@@ -9,16 +9,27 @@ import pytest
 
 from benchmark import cells, readers
 from benchmark.tests import tiny
+from benchmark.tests.contract import WIDTH
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 BENCH = cells.manifest()
-ALL_NAMES = ([("config", c["name"]) for c in BENCH["configs"]]
-             + [("workload", w["name"]) for w in BENCH["workloads"]]
-             + [("traffic", w["traffic"]) for w in BENCH["workloads"]]
+HELD = cells.held_out()          # held to the same letters while they wait
+
+
+def _both(key):
+    return BENCH[key] + HELD[key]
+
+
+ALL_NAMES = ([("config", c["name"]) for c in _both("configs")]
+             + [("workload", w["name"]) for w in _both("workloads")]
+             + [("traffic", w["traffic"]) for w in _both("workloads")]
              + [("metric", m["name"])
                 for m in BENCH["end_to_end"] + BENCH["per_layer"]]
-             + [("reduced", k) for c in BENCH["configs"] for k in c["reduced"]])
+             + [("held-out-metric", m["name"])
+                for m in HELD["end_to_end"] + HELD["per_layer"]]
+             + [("reduced", k) for c in _both("configs") for k in c["reduced"]])
+PROVED = sorted(w["name"] for w in BENCH["workloads"])
 
 
 def test_top_level_keys():
@@ -33,8 +44,9 @@ def test_names(kind, name):
     assert NAME.match(name), (kind, name)
 
 
-@pytest.mark.parametrize("metric", BENCH["end_to_end"] + BENCH["per_layer"],
-                         ids=lambda m: m["name"])
+@pytest.mark.parametrize(
+    "metric", _both("end_to_end") + _both("per_layer"),
+    ids=lambda m: m["name"] + "@" + ",".join(m.get("workloads", ["all"])))
 def test_metric_entries(metric):
     assert UNIT.match(metric["unit"])
     assert metric["better"] in ("lower", "higher")
@@ -64,7 +76,7 @@ def test_names_are_unique_and_lines_short():
     assert all(w["chips"] in (1, 4) for w in BENCH["workloads"])
 
 
-@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
+@pytest.mark.parametrize("cell", PROVED)
 def test_cell_resolves_from_files_by_name(cell):
     c = cells.resolve(cell)
     for spec in (c["config"]["program"], c["config"]["reference"],
@@ -83,15 +95,16 @@ def test_cell_resolves_from_files_by_name(cell):
 
 
 def test_every_file_is_under_paths_and_configs_used():
-    used = {w["config"] for w in BENCH["workloads"]}
-    for c in BENCH["configs"]:
+    used = {w["config"] for w in _both("workloads")}
+    assert {w["config"] for w in BENCH["workloads"]} == {
+        c["name"] for c in BENCH["configs"]}
+    for c in _both("configs"):
         assert c["name"] in used
         assert c["file"].startswith(BENCH["paths"][0] + "/")
         with open(os.path.join(cells.ROOT, c["file"])) as f:
             cfg = json.load(f)
         assert set(c["reduced"]) == set(cfg["reduced"])
-        assert not any(k.endswith(("_dim", "_rank", "_size")) or "hidden" in k
-                       and "dropout" not in k for k in c["reduced"])
+        assert not [k for k in c["reduced"] if WIDTH.search(k)]
 
 
 def test_a_cell_is_added_without_editing_a_file(tmp_path):
@@ -117,13 +130,11 @@ def test_a_reader_that_finds_nothing_returns_nothing():
 
 
 def test_held_out_entries_name_files_that_are_there():
-    with open(os.path.join(cells.HERE, "held_out.json")) as f:
-        held = json.load(f)
-    for c in held["configs"]:
+    for c in HELD["configs"]:
         assert os.path.exists(os.path.join(cells.ROOT, c["file"]))
-    for w in held["workloads"]:
+    for w in HELD["workloads"]:
         assert os.path.exists(os.path.join(
             cells.HERE, "traffic", w["traffic"] + ".json"))
         assert w["name"] not in {x["name"] for x in BENCH["workloads"]}
-    for m in held["end_to_end"] + held["per_layer"]:
+    for m in HELD["end_to_end"] + HELD["per_layer"]:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"])

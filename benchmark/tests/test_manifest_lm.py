@@ -1,33 +1,21 @@
-"""The entries PR 29 added to BENCHMARK.json, held to the contract's letters.
-
-`test_manifest.py`'s `test_every_file_is_under_paths_and_configs_used` fails
-since PR 29 on its last assertion alone: it takes any `reduced` key that ends
-in `_size` or holds `hidden` for a width, and so refuses `vocab_size` (a
-vocabulary's slice) and `num_hidden_layers` (a depth), both cuts the contract
-allows. That file is an accepted one and not this PR's to edit (PERF.md, Open
-question 17). Everything that assertion guards is checked here with the
-contract's own list of widths, so a later breakage of these entries shows."""
+"""The entries PR 29 added to BENCHMARK.json, held to the contract's letters:
+`vocab_size` (a vocabulary's slice) and `num_hidden_layers` (a depth) are cuts
+the contract allows, a width is not (`contract.WIDTH`, which
+`test_manifest.py` holds every configuration to). Since PR 32 the cell reports
+its rate as `train_tokens_per_s_per_chip`, under a bound of its own, and what
+it shared with the image cell under names of its own (`<name>.lm`)."""
 
 import json
 import os
-import re
 
 import pytest
 
 from benchmark import cells
+from benchmark.tests.contract import WIDTH
 
 BENCH = cells.manifest()
 CELL, CONFIG = "trinity-mini.fit-seq8k", "trinity-mini"
-# what `reduced` may never name: a hidden, intermediate, latent, state or
-# projection size, a key that ends in `_dim` or `_rank`, a head size, an
-# expansion factor, the experts a token
-WIDTH = re.compile(r"(^|_)(hidden|intermediate|latent|state|proj\w*)_size$"
-                   r"|_dim$|_rank$|^head_(dim|size)$|expan\w*_factor"
-                   r"|^num_experts_per_tok$")
-NEW_METRICS = ("train_attn_kernel_roofline", "train_moe_experts_roofline",
-               "train_attn_device_share", "train_moe_device_share",
-               "train_optimizer_device_share", "train_moe_load_max_over_mean",
-               "train_moe_held_share")
+RATE = "train_tokens_per_s_per_chip"
 
 
 def _entry():
@@ -89,16 +77,43 @@ def test_the_cell_and_its_metrics_are_appended_and_nothing_else_moved():
     cell = next(w for w in BENCH["workloads"] if w["name"] == CELL)
     assert cell == dict(cell, config=CONFIG, traffic="fit-seq8k", chips=1)
     assert len(cell["why"]) <= 200 and len(_entry()["why"]) <= 200
+    # what the image cell reports, the same under the cell's own names, and
+    # the cell's own metrics: the tail of `per_layer`, in the order they came
+    image = {m["name"]: m for m in BENCH["per_layer"]
+             if m.get("workloads") == ["resnet50.fit-hostfed"]}
     mine = {m["name"]: m for m in BENCH["per_layer"]
             if m.get("workloads") == [CELL]}
-    assert tuple(mine) == NEW_METRICS
-    assert [m["name"] for m in BENCH["per_layer"]][-len(mine):] == list(mine)
-    for m in mine.values():
-        assert m["moves"] == "train_items_per_s_per_chip"
-        assert m["unit"] == ("%" if "share" in m["name"] or "roofline"
-                             in m["name"] else "ratio")
+    assert not [m["name"] for m in BENCH["per_layer"]
+                if len(m.get("workloads", [])) > 1]
+    twins = [n for n in mine if n.endswith(".lm")]
+    own = [n for n in mine if n not in twins]
+    assert len(own) >= 7
+    assert [m["name"] for m in BENCH["per_layer"]][-len(mine):] == twins + own
+    assert twins == [n + ".lm" for n in image]
+    for name, m in mine.items():
+        assert m["moves"] == RATE
+        if name in twins:      # the image cell's entry, and its reader's file
+            theirs = image[name[:-len(".lm")]]
+            assert dict(theirs, name=name, moves=RATE, workloads=[CELL]) == m
+            specs = []
+            for n in (name, theirs["name"]):
+                with open(os.path.join(cells.HERE, "metrics", n + ".json")) as f:
+                    specs.append(json.load(f))
+            assert specs[0] == specs[1]
+        else:
+            assert m["unit"] == ("%" if "share" in name or "roofline" in name
+                                 else "ratio")
     resolved = cells.resolve(CELL)
-    assert {m["name"] for m in resolved["end_to_end"]} == {
-        "train_items_per_s_per_chip", "setup_s"}
-    assert set(mine) <= {m["name"] for m in resolved["per_layer"]}
-    assert "train_step_mfu" in {m["name"] for m in resolved["per_layer"]}
+    rate = {m["name"]: m for m in resolved["end_to_end"]}
+    assert set(rate) == {RATE, "setup_s"}
+    assert rate[RATE]["workloads"] == [CELL]
+    assert rate[RATE] == dict(rate[RATE], unit="tokens/s/chip",
+                              better="higher", source="host_clock")
+    assert resolved["traffic"]["rate_metric"] == RATE
+    # the image cell's rate keeps its 1 % and is the image cell's alone
+    items = next(m for m in BENCH["end_to_end"]
+                 if m["name"] == "train_items_per_s_per_chip")
+    assert items["bound"] == 0.01
+    assert items["workloads"] == ["resnet50.fit-hostfed"]
+    assert {m["name"] for m in resolved["per_layer"]} == {"compile_s"} | set(mine)
+    assert "train_step_mfu.lm" in mine

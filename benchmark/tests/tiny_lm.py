@@ -2,8 +2,11 @@
 benchmark like `tiny.py`'s: Trinity's block at toy widths, 4 of 8 experts
 held from number 2, a window of 8 in 32 tokens."""
 
+import itertools
 import json
 import os
+
+import numpy as np
 
 from benchmark.tests import tiny
 
@@ -34,15 +37,43 @@ TRAFFIC = {"driver": "benchmark.fit_lm:run",
            "epoch_order": "benchmark.fit:numpy_order", "fused": False,
            "batch": 2, "steps_per_call": 4, "items_per_row": 32,
            "check_steps": 3, "reference_row_block": 1, "trace_seconds": 1,
-           "module_pattern": "^jit_train_"}
+           "module_pattern": "^jit_train_", "row_sets": 4,
+           "rate_metric": "train_tokens_per_s_per_chip"}
 # the numbers the real cell's limits name, at toy readings on the CPU (bf16
 # against float32 at widths of 64; sound largest / control smallest over four
 # seeds): grad_diff_best_leaf 0.049 / 0.24, grad_norm_gap 0.017 / 0.052,
-# change_norm_gap 0.015 (a leaf left unmoved reads 1), loss_gap_3 1.4e-3
-# (half a batch 0.028)
-LIMITS = {"loss_gap_3": 5e-3, "grad_norm_gap": 0.04,
+# change_norm_gap 0.015 (a leaf left unmoved reads 1); half a batch reads 0.34
+# by grad_norm_gap_median_leaf. No loss: on rows a step has not seen before,
+# neither the control nor half a batch moves it past what sound runs read
+# (PERF.md, PR 32)
+LIMITS = {"grad_norm_gap": 0.04,
           "grad_norm_gap_median_leaf": 6e-3, "change_norm_gap": 0.05,
           "change_norm_gap_median_leaf": 7.5e-3, "grad_diff_best_leaf": 0.12}
+
+
+class Recorded:
+    """A feature set that keeps a copy of every batch the program draws from
+    it, as (the set's number, the epoch's seed, inputs, labels) in `fed`."""
+
+    def __init__(self, inner, number: int, fed: list):
+        self._inner, self._number, self._fed = inner, number, fed
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def train_batches(self, batch_size, shuffle=True, seed=0, borrowed=False):
+        for x, y, mask in self._inner.train_batches(batch_size, shuffle, seed,
+                                                    borrowed=borrowed):
+            self._fed.append((self._number, seed, np.array(x), np.array(y)))
+            yield x, y, mask
+
+
+def recording(feature_set, fed: list):
+    """`feature_set` (a traffic file's) with every set it makes `Recorded`,
+    numbered in the order they are made."""
+    numbers = itertools.count()
+    return lambda traffic, x, y: Recorded(feature_set(traffic, x, y),
+                                          next(numbers), fed)
 
 
 def add_cell(root: str) -> str:
