@@ -13,7 +13,7 @@ loaded model — rebuilt on the subsystems PRs 1–9 put in place:
 - **compile cost** amortizes through the model's persistent AOT cache
   (:meth:`~analytics_zoo_tpu.inference.inference_model.InferenceModel
   .set_aot_cache`): a restarted job replays the bucket ladder with zero
-  compiles — ``BENCH_BATCH.json`` pins this;
+  compiles (the cache's contract, ``tests/test_inference_aot_cache.py``);
 - **dispatch/fetch overlap** like the serving fast path: with
   ``pipeline_depth`` > 0 the loop keeps that many batches enqueued on
   the device (``do_dispatch``) before blocking on the oldest result

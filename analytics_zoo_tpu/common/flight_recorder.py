@@ -5,8 +5,8 @@ opt-in: the tracer is disabled in steady-state production, and the one
 time an operator needs a timeline — the seconds *before* an incident —
 is exactly when nobody had it enabled. The flight recorder closes that
 gap the way an aircraft recorder does: a bounded ring of **compact
-per-request event records** that is always on (the overhead gate in
-``BENCH_OBS.json`` pins it under 2% of request throughput), plus an
+per-request event records** that is always on (a request costs one
+append to a bounded deque and a handful of timestamp writes), plus an
 **anomaly-triggered atomic dump** so the last N requests before the
 incident are recoverable from disk after the process is gone.
 

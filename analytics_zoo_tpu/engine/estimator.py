@@ -876,7 +876,7 @@ class Estimator:
         TrainState leaf shardings. GSPMD is free to emit e.g. an optimizer
         moment with a different (equivalent-on-this-mesh) spec than it
         came in with; the flipped signature then re-traces the executable
-        on the call AFTER warmup — i.e. inside a bench's timed region
+        on the call AFTER warmup — i.e. inside a timed window
         (caught by test_bert_fit_path_bench_rehearsal). Pinning outputs
         to inputs makes every later call signature-identical."""
         assert self.tstate is not None

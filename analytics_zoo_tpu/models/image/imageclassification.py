@@ -11,7 +11,9 @@ TPU-first design choices (vs the reference's BigDL graphs):
 - Architectures are functional ``Model`` graphs; the whole forward compiles
   into one XLA program (BN fused into convs by XLA).
 
-ResNet-50 is the benchmark model (BASELINE.md north star: imgs/sec/chip).
+ResNet-50 is one of the benchmark's configurations
+(``benchmark/configs/resnet50.json``; the reference's published rate is in
+BASELINE.md).
 """
 
 from __future__ import annotations

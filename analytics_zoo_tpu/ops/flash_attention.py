@@ -674,8 +674,7 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     blocks a window can touch. Raises NotImplementedError for unsupported
     shapes/bias so the dispatcher in ops.attention falls back to the XLA
     reference implementation. ``block_q``/``block_k`` override the
-    seq-aware default tile sizes per call (the flash_bench autotune
-    sweep)."""
+    seq-aware default tile sizes per call."""
     block_q, block_k = _resolve_blocks(block_q, block_k,
                                        q.shape[2], k.shape[2])
     scale = _validate(q, k, scale, block_q, block_k, causal, window, bias)

@@ -20,7 +20,7 @@ preallocated per-(stage, slot) buffers of
 ``K - s`` slots at stage ``s``; naive GPipe wants all ``M``, so under
 the same budget it flushes in pool-sized chunks — fill P, drain P —
 and eats a (K-1)-deep bubble per chunk where 1F1B pays once. That is
-the measured gap the bench pins (docs/pipeline-parallel.md
+the gap the timeline shows (docs/pipeline-parallel.md
 "Bubble math"); with unbounded memory the two schedules tie and the
 difference is footprint only.
 """
@@ -213,7 +213,7 @@ class MicrobatchSchedule:
         on its own clock, each op starting when both the stage is free
         and its cross-stage dependency has finished. ``costs`` maps op
         kind → duration (default F=1, B=2, L=3 — backward ≈ 2× forward,
-        the usual rule of thumb; the bench feeds measured means)."""
+        the usual rule of thumb)."""
         return simulate_timeline(self.per_stage_ops(), self._deps, costs)
 
     def describe(self) -> Dict[str, object]:
@@ -274,9 +274,10 @@ def simulate_timeline(per_stage_ops: Sequence[Sequence[Op]], deps_fn,
 def bubble_fraction(num_stages: int, num_microbatches: int, mode: str,
                     slots: Optional[int] = None,
                     costs: Optional[Dict[str, float]] = None) -> float:
-    """Aggregate bubble fraction of one schedule configuration — the
-    number BENCH_PIPE.json records and CI gates (1F1B strictly below
-    naive GPipe at >= 4 microbatches under the equal slot budget)."""
+    """Aggregate bubble fraction of one schedule configuration: a count
+    over the simulated timeline's slots, which tests/test_pipeline.py
+    holds (1F1B strictly below naive GPipe at >= 4 microbatches under
+    the equal slot budget)."""
     sched = MicrobatchSchedule(num_stages, num_microbatches, mode=mode,
                                slots=slots)
     return sched.simulate(costs).bubble

@@ -21,8 +21,7 @@ Activations ride the preallocated per-(stage, slot) pools of
 dry-run of the event order (:meth:`MicrobatchSchedule.measured_slots`),
 so an over-budget schedule fails at setup, not mid-step.
 
-Parity contract (pinned by tests/test_pipeline.py and
-scripts/pipeline_bench.py):
+Parity contract (pinned by tests/test_pipeline.py):
 
 - GPipe and 1F1B produce BITWISE-identical losses/params: both fold
   per-microbatch gradient sums in fixed ascending-microbatch order
@@ -31,7 +30,7 @@ scripts/pipeline_bench.py):
 - Pipelined vs unpipelined on the same global batch is bitwise or
   documented-ULP: splitting one gemm into M microbatch gemms + adds
   reassociates the reduction (the PR 13 sum-vs-mean precedent; the
-  bench records the measured max ULP).
+  test states its ULP bound).
 - Dropout parity holds at M=1 only: the per-layer rng fold uses
   ABSOLUTE layer indices (StageSegment.indices), so a stage-split
   forward draws the unsplit model's masks, but every microbatch shares

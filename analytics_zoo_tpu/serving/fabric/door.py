@@ -409,7 +409,7 @@ class FleetDoor:
             self._fd.shutdown()
 
     def simulate_host_kill(self) -> None:
-        """Whole-host death, as tests and the bench need it: SIGKILL
+        """Whole-host death, as the tests need it: SIGKILL
         every worker, close the listener, stop heartbeating WITHOUT
         leaving — the membership record stays on disk exactly as a
         crashed host leaves it, so peers must detect the death by

@@ -100,7 +100,7 @@ Result-cache families (ISSUE 12 — engine-level, rendered from the
 
 Summaries expose ``quantile="0.5"/"0.95"/"0.99"`` samples; the JSON-side
 ``snapshot()`` carries the matching ``*_p50_s``/``*_p95_s``/``*_p99_s``
-keys (the p99 the hit-rate→latency bench curve plots).
+keys.
 """
 
 from __future__ import annotations

@@ -44,8 +44,7 @@ in-flight slots; queued requests survive onto the replacement thread.
 
 Wired through ``ServingEngine.register(sequence=...)``, the HTTP
 ``:generate`` endpoint, ``zoo_seq_*`` metrics and ``serving.decode_step``
-spans. Benchmarked by scripts/seq_serving_bench.py → BENCH_SEQ.json.
-See docs/serving.md ("Sequence serving").
+spans. See docs/serving.md ("Sequence serving").
 """
 
 from __future__ import annotations

@@ -68,8 +68,7 @@ def load_spec(spec: str) -> Callable:
 
     Two forms: ``package.module:build_engine`` (imported) and
     ``/path/to/file.py:build_engine`` (loaded from the file — what the
-    tests and the bench use, so a spec does not need to be
-    installable)."""
+    tests use, so a spec does not need to be installable)."""
     target, sep, attr = spec.rpartition(":")
     if not sep or not target or not attr:
         raise ValueError(

@@ -1,6 +1,6 @@
 """Inference perf harness — ref examples/vnni/bigdl/Perf.scala:61-68 (the
-imgs/sec loop over a catalog model, f32 vs INT8) — the user-facing
-counterpart of the driver-facing bench.py.
+imgs/sec loop over a catalog model, f32 vs INT8). An example for a user's
+own device; the repo's numbers come from benchmark/run.py (PERF.md).
 
 Measures steady-state predict throughput of a catalog image classifier,
 optionally through InferenceModel.do_quantize (weight-only int8) and/or

@@ -2,7 +2,7 @@
 synthetic ResNet-50 step built on flax.linen — a second, independently
 written implementation path (linen modules, linen BatchNorm, its own
 autodiff structure) — on the same chip with the same batch/dtype as
-bench.py's primary record. If both land at the same imgs/sec, the
+the benchmark's resnet50 configuration. If both land at the same imgs/sec, the
 "memory-wall roofline" argument becomes "parity with an independent
 implementation of the same model".
 

@@ -12,8 +12,8 @@ uninterrupted run's — no duplicate rows, no holes.
 The model is pure NumPy (a fixed-seed linear map with the serving
 fast-path dispatch/fetch split, so the overlapped loop is the one under
 the kill) — determinism across processes without a device in the loop;
-the real-XLA + AOT-cache geometry is covered by scripts/batch_bench.py
-and the in-process tests.
+the real-XLA + AOT-cache geometry is covered by the in-process tests
+(test_serving_mesh.py, test_inference_aot_cache.py).
 
 Usage: python _batch_worker.py <out_dir> <report.json>
 Env: AZOO_FT_CHAOS / AZOO_FT_CHAOS_SKIP (chaos.py), BATCH_RESUME=1.

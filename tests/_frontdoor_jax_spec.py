@@ -6,7 +6,7 @@ environment, so the InferenceModel built here persists its compiled
 executables automatically; a respawned worker (or a whole warm
 front-door restart) must compile zero times. Layer names are explicit
 because the parameter-dict keys are part of the AOT cache key — they
-must be restart-stable (see scripts/serving_bench.py).
+must be restart-stable (the test_inference_aot_cache.py idiom).
 """
 
 import os

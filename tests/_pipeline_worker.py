@@ -1,5 +1,4 @@
-"""Pipelined-training worker (launched by test_pipeline.py and
-scripts/pipeline_bench.py).
+"""Pipelined-training worker (launched by test_pipeline.py).
 
 One process running ``Estimator.train_pipelined`` over a K-stage
 StagePlan with M microbatches, committing stage-owned sharded

@@ -65,3 +65,45 @@ def load_script(base: str, relpath: str, prefix: str = "script"):
     sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
+
+
+# -- serving parity: compare like with like ---------------------------------
+# A request served through a bucket ladder ran under the flush's bucket
+# shape; a direct ``do_predict`` at the request's own row count is another
+# XLA:CPU program, equal only to a few units in the last place. These let a
+# test find the shape that served a request and build the same one directly.
+
+def record_flushes(model):
+    """Record every bucket-shaped batch the batcher dispatches to ``model``
+    (call before ``register``). Returns the list it fills."""
+    flushed = []
+    dispatch = model.do_dispatch
+
+    def recording_dispatch(x):
+        flushed.append(np.array(x))
+        return dispatch(x)
+
+    model.do_dispatch = recording_dispatch
+    return flushed
+
+
+def bucket_that_served(flushed, x):
+    """Row count of the recorded flush that carried the rows ``x``."""
+    n = x.shape[0]
+    return next(b.shape[0] for b in flushed
+                if any(np.array_equal(b[o:o + n], x)
+                       for o in range(b.shape[0] - n + 1)))
+
+
+def pad_rows(x, bucket):
+    """``x`` as the batcher assembles it alone in a flush: zeros below."""
+    pad = np.zeros((bucket - x.shape[0],) + x.shape[1:], x.dtype)
+    return np.concatenate([x, pad], axis=0)
+
+
+def max_ulp(a, b):
+    """Largest distance between two float32 arrays in units in the last
+    place (elementwise of one sign: softmax outputs, trained weights)."""
+    ia = np.asarray(a, np.float32).view(np.int32).astype(np.int64)
+    ib = np.asarray(b, np.float32).view(np.int32).astype(np.int64)
+    return int(np.max(np.abs(ia - ib)))

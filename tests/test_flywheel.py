@@ -418,16 +418,38 @@ def _engine_with_tap(tmp_path, **engine_kw):
     return engine, tap
 
 
-def test_capture_counts_each_request_once(tmp_path):
+@pytest.mark.parametrize("clients", [1, 8])
+def test_capture_counts_each_request_once(tmp_path, clients):
+    """One caller, then eight at once: with the tap on every predict is
+    answered (no client error) and lands in the segment exactly once."""
     engine, tap = _engine_with_tap(tmp_path)
+    per_client, errors = 10, []
     try:
         x = np.ones((1, 3), np.float32)
-        for _ in range(10):
-            engine.predict("m", x)
-        assert tap.metrics["sampled"].value >= 10
+        sampled0 = tap.metrics["sampled"].value
+
+        def client():
+            for _ in range(per_client):
+                try:
+                    np.testing.assert_array_equal(
+                        engine.predict("m", x), x * 2.0)
+                except Exception as e:  # noqa: BLE001 — counted, must be 0
+                    errors.append(e)
+
+        threads = [threading.Thread(target=client) for _ in range(clients)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+        assert not errors, errors
+        total = clients * per_client
+        assert tap.metrics["sampled"].value - sampled0 == total
         tap.flush()
         segment = tap.rotate("m")
-        assert len(list(writers.iter_output_rows(segment))) == 10
+        rows = list(writers.iter_output_rows(segment))
+        assert len(rows) == total
+        assert len({r["t"] for r in rows}) == total
     finally:
         tap.close()
         engine.shutdown()

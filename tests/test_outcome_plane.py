@@ -11,6 +11,7 @@ import os
 import shutil
 import subprocess
 import sys
+import threading
 import time
 import urllib.error
 import urllib.request
@@ -758,6 +759,56 @@ def test_http_outcome_single_and_batch(outcome_server):
     seg = store.rotate("dbl")
     rows = list(writers.iter_output_rows(seg))
     assert [r["t"] for r in rows] == ["tr-1", "tr-2", "tr-3"]
+
+
+def test_http_outcomes_beside_live_predicts_lose_nothing(outcome_server):
+    """Two labelers POST batches of 16 while four clients predict through
+    the same server: every POST is accepted whole, every predict is
+    answered, and the committed label segments hold each record once."""
+    base, engine, store = outcome_server
+    posts, batch, labelers, clients = 6, 16, 2, 4
+    payload = json.dumps({"instances": [[1.0, 2.0, 3.0]]}).encode()
+    errors = []
+
+    def labeler(seed):
+        for j in range(posts):
+            recs = [{"trace_id": f"l{seed}-{j}-{k}", "label": [float(k)],
+                     "ts": 1700000000.0 + j} for k in range(batch)]
+            try:
+                code, _, body = _post(
+                    f"{base}/v1/models/dbl:outcome",
+                    json.dumps({"outcomes": recs}).encode(),
+                    {"Content-Type": "application/json"})
+                assert code == 200
+                assert json.loads(body) == {"accepted": batch}
+            except Exception as e:  # noqa: BLE001 — counted, must be 0
+                errors.append(("label", e))
+
+    def client():
+        for _ in range(12):
+            try:
+                code, _, body = _post(
+                    f"{base}/v1/models/dbl:predict", payload,
+                    {"Content-Type": "application/json"})
+                assert code == 200
+                assert json.loads(body)["predictions"] == [[2.0, 4.0, 6.0]]
+            except Exception as e:  # noqa: BLE001 — counted, must be 0
+                errors.append(("predict", e))
+
+    threads = [threading.Thread(target=labeler, args=(i,))
+               for i in range(labelers)]
+    threads += [threading.Thread(target=client) for _ in range(clients)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors
+    store.rotate("dbl")
+    stats = store.describe("dbl")
+    assert stats["labels_total"] == labelers * posts * batch
+    assert stats["labels_unique"] == labelers * posts * batch
+    assert stats["duplicates"] == 0
 
 
 def test_http_outcome_errors(outcome_server):

@@ -13,6 +13,7 @@ import numpy as np
 import optax
 import pytest
 
+from conftest import max_ulp
 from analytics_zoo_tpu.mesh.config import MeshConfig, STAGE_AXIS
 from analytics_zoo_tpu.mesh.plan import ShardingPlan
 from analytics_zoo_tpu.pipeline import (
@@ -292,14 +293,6 @@ def _train_cell(num_stages, num_microbatches, mode, ckpt_dir=None,
     return np.concatenate([np.asarray(a).ravel() for a in flat])
 
 
-def _max_ulp(a, b):
-    if np.array_equal(a, b):
-        return 0
-    ia = a.view(np.int32).astype(np.int64)
-    ib = b.view(np.int32).astype(np.int64)
-    return int(np.max(np.abs(ia - ib)))
-
-
 def test_stage_split_alone_is_bitwise():
     """K≥2 with M=1 runs the same math in the same order — the stage cut
     must not perturb a single bit of the trained params."""
@@ -315,7 +308,7 @@ def test_microbatching_is_ulp_bounded_and_schedules_bitwise():
     base = _train_cell(1, 1, "1f1b")
     p1 = _train_cell(2, 2, "1f1b")
     pg = _train_cell(2, 2, "gpipe")
-    assert _max_ulp(base, p1) <= 64
+    assert max_ulp(base, p1) <= 64
     np.testing.assert_array_equal(p1, pg)
 
 
@@ -325,7 +318,7 @@ def test_parity_matrix_three_stages():
     np.testing.assert_array_equal(base, _train_cell(3, 1, "1f1b"))
     p1 = _train_cell(3, 4, "1f1b")
     pg = _train_cell(3, 4, "gpipe")
-    assert _max_ulp(base, p1) <= 64
+    assert max_ulp(base, p1) <= 64
     np.testing.assert_array_equal(p1, pg)
 
 

@@ -718,6 +718,50 @@ def test_metrics_families_in_one_scrape():
         engine.shutdown()
 
 
+def test_hot_keys_served_from_cache_are_bitwise_a_fresh_execution():
+    """Skewed traffic over a small pool through a real ``InferenceModel``:
+    every repeat is a hit, a hit is byte for byte what a fresh execution of
+    the same request gives, and one scrape carries every family. One
+    request at a time, so hit, miss and bypass all ran the 2-row bucket."""
+    import analytics_zoo_tpu as zoo
+    from analytics_zoo_tpu.inference.inference_model import InferenceModel
+    from analytics_zoo_tpu.keras.engine.topology import Sequential
+    from analytics_zoo_tpu.keras.layers import Dense
+
+    zoo.init_nncontext()
+    net = Sequential()
+    net.add(Dense(16, activation="relu", input_shape=(6,)))
+    net.add(Dense(4, activation="softmax"))
+    engine = ServingEngine(result_cache=ResultCacheConfig())
+    try:
+        engine.register("m", InferenceModel().do_load_keras(net),
+                        example_input=np.zeros((1, 6), np.float32),
+                        config=CFG)
+        rng = np.random.default_rng(7)
+        pool = [rng.normal(size=(2, 6)).astype(np.float32)
+                for _ in range(8)]
+        weights = 1.0 / np.arange(1, len(pool) + 1) ** 1.1   # Zipf(1.1)
+        draws = rng.choice(len(pool), size=64, p=weights / weights.sum())
+        first = {}
+        for k in draws:
+            fut = engine.predict_async("m", pool[k])
+            out = np.asarray(fut.result(timeout=10))
+            assert fut.cache_status == ("hit" if k in first else "miss")
+            np.testing.assert_array_equal(out, first.setdefault(k, out))
+        stats = engine.result_cache.stats()
+        assert stats["misses"] == len(first)
+        assert stats["hits"] == len(draws) - len(first)
+        for k, cached in first.items():
+            fresh = engine.predict("m", pool[k], bypass_cache=True)
+            np.testing.assert_array_equal(cached, np.asarray(fresh))
+        text = engine.metrics_text()
+        for fam in _FAMILIES:
+            assert f"# TYPE {fam}" in text, fam
+        assert f"zoo_serving_result_cache_hits_total {stats['hits']}" in text
+    finally:
+        engine.shutdown()
+
+
 def test_metrics_families_render_zero_without_cache():
     engine = ServingEngine()
     try:

@@ -277,9 +277,9 @@ def test_bert_trains_through_public_fit_over_device_cache():
 
 
 def test_bert_fit_path_bench_rehearsal():
-    """Dress rehearsal of bench._bert_fit_record's EXACT call pattern
-    (north star: >=0.55 MFU through the public path): warmup
-    train(MaxEpoch(E)) then timed train(MaxEpoch(2E)) must BOTH take the
+    """Dress rehearsal of a fused-fit measurement's call pattern (a warm
+    call, then the timed one, as any benchmark of the fused path makes):
+    train(MaxEpoch(E)) then train(MaxEpoch(2E)) must BOTH take the
     fused-fit dispatch with the SAME compiled executable — a retrace or
     recompile inside the timed region would corrupt the on-chip number
     (caught one: eager optax init left TP-pspec'd moments replicated
