@@ -165,7 +165,12 @@ class DecoderBlock(KerasLayer):
     ``mlp``: a built-or-unbuilt ``SwiGLU`` or ``SparseMoE``. ``dtype``: the
     compute type its weights and input are cast to inside the block.
     ``remat``: rematerialise each half in the backward pass (a layer's
-    activations then live only while its gradient is computed). Parameters
+    activations then live only while its gradient is computed). Of the
+    attention half a block keeps what only the flash kernel can make, its
+    output and log-sum-exp (``ops.flash_attention.FLASH_RESIDUALS``; 136 MB
+    a layer at 16 384 tokens of 32 heads x 128), and recomputes the norm,
+    the projections and rotary, not the kernel; on the XLA path (short
+    rows) it keeps nothing and recomputes the whole half. Parameters
     nest: ``{"attn": ..., "mlp": ..., "<norm>": {"gain": ...}}``. A block with
     an expert layer carries that layer's state (the router's selection bias
     and the step's tokens an expert) and returns it updated when training."""
@@ -220,13 +225,20 @@ class DecoderBlock(KerasLayer):
                 y = self.mlp.call(p["mlp"], m)
             return h + rms_norm(y, p["post_mlp_norm"]["gain"], eps), st
 
-        # (the expert layer's chunks are rematerialised inside this half's
-        # own checkpoint, so their forward pass runs three times; without the
-        # outer one the step's scratch grows from 3.5 to 5.8 GB: compiled for
-        # the chip, PR 29)
+        # What the attention half keeps is in the class's docstring. The
+        # other half keeps nothing: its forward pass, the expert layer's
+        # compacted pass included, runs twice (overflow chunks, rematerialised
+        # one by one inside, three times).
         if self.remat:
-            attn_half, mlp_half = (jax.checkpoint(attn_half),
-                                   jax.checkpoint(mlp_half))
+            # imported here like every use of the kernels' module: it brings
+            # Pallas, a second of import that a model on the XLA path skips
+            from analytics_zoo_tpu.ops.flash_attention import FLASH_RESIDUALS
+
+            attn_half = jax.checkpoint(
+                attn_half,
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *FLASH_RESIDUALS))
+            mlp_half = jax.checkpoint(mlp_half)
         if self.dtype is not None:
             x = x.astype(self.dtype)
         attn_keys = ("attn", "in_norm", "post_attn_norm")

@@ -28,6 +28,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -617,10 +618,19 @@ def _flash(q, k, v, bias_flat, scale: float, causal: bool,
                           window)
 
 
+# The two residuals only the forward kernel can make. A checkpoint whose
+# policy is ``save_only_these_names(*FLASH_RESIDUALS)`` keeps them and so
+# drops the kernel from its recomputation (DecoderBlock); under a bare
+# ``jax.checkpoint``, or none, a name is the identity.
+FLASH_RESIDUALS = ("zoo_flash_out", "zoo_flash_lse")
+
+
 def _flash_fwd_rule(q, k, v, bias_flat, scale, causal, block_q, block_k,
                     window=None):
     out, lse = _flash_forward(q, k, v, bias_flat, scale, causal,
                               block_q, block_k, window)
+    out = checkpoint_name(out, FLASH_RESIDUALS[0])
+    lse = checkpoint_name(lse, FLASH_RESIDUALS[1])
     return (out, lse), (q, k, v, bias_flat, out, lse)
 
 

@@ -208,6 +208,90 @@ def test_window_and_grouped_heads_in_the_flash_kernels(window, heads, blocks):
         _close(a, b, 2e-5)
 
 
+def _inner_jaxprs(eqn):
+    """The jaxprs an equation holds (a kernel's body aside)."""
+    from jax._src import core
+
+    if eqn.primitive.name == "pallas_call":
+        return
+    for v in eqn.params.values():
+        for u in (v if isinstance(v, (tuple, list)) else (v,)):
+            if isinstance(u, (core.ClosedJaxpr, core.Jaxpr)):
+                yield getattr(u, "jaxpr", u)
+
+
+def _kernel_calls(jaxpr, counts=None):
+    """Every `pallas_call` of a jaxpr by its kernel's name, nested ones too."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            counts[name] = counts.get(name, 0) + 1
+        for inner in _inner_jaxprs(eqn):
+            _kernel_calls(inner, counts)
+    return counts
+
+
+# What the attention half's checkpoint keeps: the kernel's two named
+# residuals (the block as it is), nothing (a bare `jax.checkpoint`, the block
+# before PR 34), or there is no checkpoint.
+_BLOCKS = {"kept": 1, "bare": 2, "none": 1}
+
+
+def _block_grads(kind, window, monkeypatch, count=False):
+    """Gradients of one `DecoderBlock` on the flash path (interpret mode) to
+    its input and every parameter; or its kernels' calls by name."""
+    from analytics_zoo_tpu.keras.layers import (DecoderBlock,
+                                                GroupedQueryAttention, SwiGLU)
+    from analytics_zoo_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_auto_use_flash", lambda q, k: True)
+    if kind == "bare":
+        monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                            lambda *names: None)
+    block = DecoderBlock(
+        GroupedQueryAttention(4, 2, 16, window=window,
+                              rope_theta=None if window is None else 1e4),
+        SwiGLU(128), remat=kind != "none")
+    block.ensure_built((None, 256, 64))
+    p = block.init_params(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 256, 64))
+    g = jax.random.normal(jax.random.PRNGKey(2), (2, 256, 64))
+
+    def loss(p, x):
+        return jnp.sum(g * block.call(p, x).astype(jnp.float32))
+
+    grad = jax.grad(loss, (0, 1))
+    if count:
+        return _kernel_calls(jax.make_jaxpr(grad)(p, x).jaxpr)
+    # operation by operation: what one whole XLA:CPU program rounds where
+    # depends on how it fused bfloat16 operations, not on what was computed
+    return jax.tree_util.tree_leaves(grad(p, x))
+
+
+@pytest.mark.parametrize("kind,forwards", list(_BLOCKS.items()))
+def test_a_block_runs_the_flash_forward_once(kind, forwards, monkeypatch):
+    """The checkpoint of the attention half keeps the kernel's output and
+    log-sum-exp, so the backward pass holds no second forward kernel; a bare
+    checkpoint holds two."""
+    assert _block_grads(kind, 128, monkeypatch, count=True) == {
+        "zoo_flash_fwd": forwards, "zoo_flash_dq": 1, "zoo_flash_dkv": 1}
+
+
+@pytest.mark.parametrize("window", [128, None])
+@pytest.mark.parametrize("kind", ["bare", "none"])
+def test_a_block_that_keeps_the_residuals_has_the_same_gradients(
+        kind, window, monkeypatch):
+    """Bit for bit: the backward kernels read the first pass's output and
+    log-sum-exp, which are the bits the second pass produced."""
+    got = _block_grads("kept", window, monkeypatch)
+    want = _block_grads(kind, window, monkeypatch)
+    assert len(got) == len(want) == 11
+    for a, b in zip(got, want):
+        assert np.isfinite(np.asarray(a, np.float32)).all()
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_a_window_needs_a_causal_mask():
     from analytics_zoo_tpu.ops.attention import scaled_dot_product_attention
 
@@ -425,20 +509,8 @@ def test_rows_past_the_held_total_are_never_read(chunks_of_64, monkeypatch):
 
 def _has_cond(jaxpr) -> bool:
     """A `cond` among the layer's own operations (a kernel's body aside)."""
-    from jax._src import core
-
-    def inner(eqn):
-        if eqn.primitive.name == "pallas_call":
-            return
-        for v in eqn.params.values():
-            for u in (v if isinstance(v, (tuple, list)) else (v,)):
-                if isinstance(u, core.ClosedJaxpr):
-                    yield u.jaxpr
-                elif isinstance(u, core.Jaxpr):
-                    yield u
-
-    return any(e.primitive.name == "cond" or any(map(_has_cond, inner(e)))
-               for e in jaxpr.eqns)
+    return any(e.primitive.name == "cond"
+               or any(map(_has_cond, _inner_jaxprs(e))) for e in jaxpr.eqns)
 
 
 @pytest.mark.parametrize("held,n_experts,k,tokens,compacted", [

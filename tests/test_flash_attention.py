@@ -36,9 +36,15 @@ def _padding_bias(key, s_k=S):
     return (1.0 - mask[:, None, None, :]) * -1e9
 
 
-def _check_fwd_and_grads(q, k, v, bias, causal):
+def _check_fwd_and_grads(q, k, v, bias, causal, called=lambda f: f):
     scale = D ** -0.5
-    out_f = flash_attention(q, k, v, bias=bias, causal=causal, scale=scale)
+
+    def flash(*arrays, bias=None):
+        # `called` sees arrays only: the mask flag and the scale stay static
+        return called(lambda *a, bias: flash_attention(
+            *a, bias=bias, causal=causal, scale=scale))(*arrays, bias=bias)
+
+    out_f = flash(q, k, v, bias=bias)
     out_r = _reference_attention(q, k, v, bias, causal, scale)
     np.testing.assert_allclose(out_f, out_r, **TOL)
 
@@ -46,8 +52,7 @@ def _check_fwd_and_grads(q, k, v, bias, causal):
 
     if bias is None:
         def loss_f(q_, k_, v_):
-            return jnp.vdot(flash_attention(q_, k_, v_, causal=causal,
-                                            scale=scale), g)
+            return jnp.vdot(flash(q_, k_, v_), g)
 
         def loss_r(q_, k_, v_):
             return jnp.vdot(_reference_attention(q_, k_, v_, None, causal,
@@ -56,8 +61,7 @@ def _check_fwd_and_grads(q, k, v, bias, causal):
         grads_r = jax.grad(loss_r, argnums=(0, 1, 2))(q, k, v)
     else:
         def loss_f(q_, k_, v_, b_):
-            return jnp.vdot(flash_attention(q_, k_, v_, bias=b_,
-                                            causal=causal, scale=scale), g)
+            return jnp.vdot(flash(q_, k_, v_, bias=b_), g)
 
         def loss_r(q_, k_, v_, b_):
             return jnp.vdot(_reference_attention(q_, k_, v_, b_, causal,
@@ -69,10 +73,61 @@ def _check_fwd_and_grads(q, k, v, bias, causal):
         np.testing.assert_allclose(gf, gr, err_msg=f"d{name}", **TOL)
 
 
+# How a caller that keeps no named residual reaches the kernels: as is,
+# inside a bare jax.checkpoint (TransformerLayer(remat=True)), under jax.jit
+# with no checkpoint (ring attention, serving).
+_CALLED = {"plain": lambda f: f, "checkpoint": jax.checkpoint, "jit": jax.jit}
+
+
+def _reference_with_lse(q, k, v, causal):
+    logits = jnp.einsum("bnqd,bnkd->bnqk", q, k) * D ** -0.5
+    if causal:
+        logits = jnp.where(jnp.tril(jnp.ones(logits.shape[-2:], bool)),
+                           logits, -jnp.inf)
+    return (_reference_attention(q, k, v, None, causal, D ** -0.5),
+            jax.nn.logsumexp(logits, axis=-1))
+
+
+def _out_lse_and_grads(attn, q, k, v):
+    """``attn(q, k, v)``'s (out, lse) and the gradients of a loss that reads
+    both, so that lse's cotangent reaches q and k."""
+    g = jax.random.normal(jax.random.PRNGKey(9), q.shape, jnp.float32)
+    g_lse = jax.random.normal(jax.random.PRNGKey(10), q.shape[:3], jnp.float32)
+
+    def loss(q_, k_, v_):
+        out, lse = attn(q_, k_, v_)
+        return jnp.vdot(out, g) + jnp.vdot(lse, g_lse), (out, lse)
+
+    grads, outs = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    return (*outs, *grads)
+
+
+@pytest.mark.parametrize("called", list(_CALLED))
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_no_bias(causal):
+def test_flash_no_bias(causal, called, monkeypatch):
+    """Against the reference, the log-sum-exp and its cotangent included;
+    and where no checkpoint carries a policy the residuals' names
+    (``FLASH_RESIDUALS``) change nothing, bit for bit."""
+    from analytics_zoo_tpu.ops import flash_attention as fa
+
     q, k, v = _qkv(jax.random.PRNGKey(0))
-    _check_fwd_and_grads(q, k, v, None, causal)
+    _check_fwd_and_grads(q, k, v, None, causal, _CALLED[called])
+
+    def with_lse():   # traced anew each time, so that the patch below shows
+        return _CALLED[called](lambda *a: fa.flash_attention_with_lse(
+            *a, causal=causal))
+
+    named = _out_lse_and_grads(with_lse(), q, k, v)
+    want = _out_lse_and_grads(
+        lambda *a: _reference_with_lse(*a, causal), q, k, v)
+    for a, b, what in zip(named, want, "out lse dq dk dv".split()):
+        np.testing.assert_allclose(a, b, err_msg=what, **TOL)
+    jaxpr = str(jax.make_jaxpr(
+        lambda *a: _out_lse_and_grads(with_lse(), *a))(q, k, v))
+    assert all(name in jaxpr for name in fa.FLASH_RESIDUALS)
+    monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+    for a, b in zip(named, _out_lse_and_grads(with_lse(), q, k, v)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("causal", [False, True])
