@@ -1276,8 +1276,10 @@ def lm_train_metrics() -> Dict[str, Any]:
     ``moe_calls_compact`` (counters ``zoo_moe_calls_total`` /
     ``zoo_moe_calls_compact_total``: expert-layer calls of training steps,
     and those whose held assignments went through in the one compacted pass,
-    as the step itself reported). One call per model — the model holds the
-    children."""
+    as the step itself reported), ``conv_token_layers`` (counter
+    ``zoo_lm_conv_token_layers_total``: tokens times short-convolution
+    layers of training steps, the mixer's work as it ran). One call per
+    model — the model holds the children."""
     reg = get_registry()
     assignments = reg.counter(
         "zoo_moe_assignments_total",
@@ -1304,6 +1306,10 @@ def lm_train_metrics() -> Dict[str, Any]:
             "zoo_moe_calls_compact_total",
             "Expert-layer calls of training steps whose held assignments "
             "went through in one compacted pass.").labels(),
+        "conv_token_layers": reg.counter(
+            "zoo_lm_conv_token_layers_total",
+            "Tokens times gated short-convolution layers a language model's "
+            "train steps computed.").labels(),
     }
 
 

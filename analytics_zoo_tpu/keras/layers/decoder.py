@@ -1,15 +1,18 @@
 """Decoder block library: the parts of today's open decoder-only language
 models, as layers.
 
-``RMSNorm``; ``rotary_embedding`` (a function: it has no weights);
-``GroupedQueryAttention`` (key-value heads fewer than query heads, optional
-per-head RMS norm of q and k, optional sigmoid output gate, causal, a sliding
-window or full, rotary positions or none); ``SwiGLU``; and ``DecoderBlock``,
-which wires them with four norms a layer around either a dense ``SwiGLU`` or
-a ``SparseMoE`` (keras/layers/moe.py):
+``RMSNorm``; ``rotary_embedding`` (a function: it has no weights); two token
+mixers, ``GroupedQueryAttention`` (key-value heads fewer than query heads,
+optional per-head RMS norm of q and k, optional sigmoid output gate, causal, a
+sliding window or full, rotary positions or none) and ``GatedShortConv`` (a
+gated causal convolution over a few neighbouring tokens, no attention at
+all); ``SwiGLU``; and ``DecoderBlock``, which wires one mixer and either a
+dense ``SwiGLU`` or a ``SparseMoE`` (keras/layers/moe.py) under one of two
+norm layouts, four norms a layer (``"sandwich"``) or the two pre-norms alone
+(``"pre"``):
 
-    h += post_attn_norm(attention(in_norm(h)))
-    h += post_mlp_norm(mlp(pre_mlp_norm(h)))
+    h += post_attn_norm(mixer(in_norm(h)))      |  h += mixer(in_norm(h))
+    h += post_mlp_norm(mlp(pre_mlp_norm(h)))    |  h += mlp(pre_mlp_norm(h))
 
 Mixed precision is the block's own: master weights stay float32 in the
 optimizer; a block casts the weights it is about to use to ``dtype`` inside
@@ -106,6 +109,8 @@ class GroupedQueryAttention(KerasLayer):
     fused input kernel ``w_in`` (d, (2 n_head [gated] or n_head + 2 n_kv_head)
     * head_dim) laid out q | k | v | g."""
 
+    block_key = "attn"      # a mixer's place in a block's parameters
+
     def __init__(self, n_head: int, n_kv_head: int, head_dim: int,
                  window: Optional[int] = None,
                  rope_theta: Optional[float] = None, qk_norm: bool = True,
@@ -123,6 +128,16 @@ class GroupedQueryAttention(KerasLayer):
     def _splits(self) -> Tuple[int, ...]:
         q, kv = self.n_head * self.head_dim, self.n_kv_head * self.head_dim
         return (q, kv, kv) + ((q,) if self.gated else ())
+
+    def kept_residuals(self) -> Tuple[str, ...]:
+        """What a block's rematerialised mixer half keeps of this mixer: what
+        only the flash kernel can make, its output and log-sum-exp (on the
+        XLA path, short rows, the names are nowhere and nothing is kept)."""
+        # imported here like every use of the kernels' module: it brings
+        # Pallas, a second of import that a model on the XLA path skips
+        from analytics_zoo_tpu.ops.flash_attention import FLASH_RESIDUALS
+
+        return FLASH_RESIDUALS
 
     def build(self, input_shape: Shape):
         d, init = input_shape[-1], DECODER_INIT
@@ -160,42 +175,92 @@ class GroupedQueryAttention(KerasLayer):
         return o @ params["w_out"]
 
 
+class GatedShortConv(KerasLayer):
+    """Gated short convolution over (B, S, d), the token mixer of the
+    conv-attention hybrids (LFM2): ``[B | C | X] = W_in u`` (d -> 3d, no
+    bias), ``z = B * X``, a causal depthwise convolution over the ``kernel``
+    newest tokens, ``c[t] = sum_j taps[:, j] * z[t - (kernel - 1) + j]`` with
+    ``z`` nought before the row's first token, and ``y = W_out (C * c)``: no
+    activation, no bias, no position. A token sees ``kernel - 1`` tokens
+    back and none ahead. Plain ``jax.numpy`` that XLA fuses (one pad,
+    ``kernel`` shifted slices), all of it under the scope ``conv.short``."""
+
+    block_key = "conv"
+
+    def __init__(self, kernel: int = 3, input_shape=None, name=None):
+        super().__init__(input_shape, name or unique_name("short_conv"))
+        if kernel < 1:
+            raise ValueError(f"a convolution over {kernel} tokens")
+        self.kernel = int(kernel)
+
+    def build(self, input_shape: Shape):
+        d, init = input_shape[-1], DECODER_INIT
+        self.add_weight("w_in", (d, 3 * d), init)
+        self.add_weight("taps", (d, self.kernel), init)
+        self.add_weight("w_out", (d, d), init)
+
+    def kept_residuals(self) -> Tuple[str, ...]:
+        """Nothing: the whole half is recomputed, like a feed-forward."""
+        return ()
+
+    def call(self, params, x, **kw):
+        s = x.shape[1]
+        with jax.named_scope("conv.short"):
+            b, c, xx = jnp.split(x @ params["w_in"], 3, axis=-1)
+            z = jnp.pad(b * xx, ((0, 0), (self.kernel - 1, 0), (0, 0)))
+            taps = params["taps"]
+            conv = sum(taps[:, j] * z[:, j:j + s] for j in range(self.kernel))
+            return (c * conv) @ params["w_out"]
+
+
 class DecoderBlock(KerasLayer):
-    """One pre- and post-normed decoder layer (see the module docstring).
-    ``mlp``: a built-or-unbuilt ``SwiGLU`` or ``SparseMoE``. ``dtype``: the
-    compute type its weights and input are cast to inside the block.
-    ``remat``: rematerialise each half in the backward pass (a layer's
-    activations then live only while its gradient is computed). Of the
-    attention half a block keeps what only the flash kernel can make, its
-    output and log-sum-exp (``ops.flash_attention.FLASH_RESIDUALS``; 136 MB
-    a layer at 16 384 tokens of 32 heads x 128), and recomputes the norm,
-    the projections and rotary, not the kernel; on the XLA path (short
-    rows) it keeps nothing and recomputes the whole half. Parameters
-    nest: ``{"attn": ..., "mlp": ..., "<norm>": {"gain": ...}}``. A block with
-    an expert layer carries that layer's state (the router's selection bias
-    and the step's tokens an expert) and returns it updated when training."""
+    """One decoder layer (see the module docstring). ``attn``: the token
+    mixer, a ``GroupedQueryAttention`` or a ``GatedShortConv``; ``mlp``: a
+    built-or-unbuilt ``SwiGLU`` or ``SparseMoE``. ``norms``: ``"sandwich"``,
+    a norm before and after each half, or ``"pre"``, the two before alone.
+    ``dtype``: the compute type its weights and input are cast to inside the
+    block. ``remat``: rematerialise each half in the backward pass (a layer's
+    activations then live only while its gradient is computed). What the
+    mixer's half keeps is the mixer's to say (``kept_residuals``): of
+    attention what only the flash kernel can make, its output and
+    log-sum-exp (136 MB a layer at 16 384 tokens of 32 heads x 128), so the
+    norm, the projections and rotary are recomputed, not the kernel; of a
+    short convolution nothing, the half is recomputed whole. Parameters
+    nest: ``{"attn" | "conv": ..., "mlp": ..., "<norm>": {"gain": ...}}``
+    (the mixer under its ``block_key``). A block with an expert layer carries
+    that layer's state (the router's selection bias and the step's tokens an
+    expert) and returns it updated when training."""
 
     NORMS = ("in_norm", "post_attn_norm", "pre_mlp_norm", "post_mlp_norm")
+    LAYOUTS = {"sandwich": NORMS, "pre": ("in_norm", "pre_mlp_norm")}
 
-    def __init__(self, attn: GroupedQueryAttention, mlp: KerasLayer,
+    def __init__(self, attn: KerasLayer, mlp: KerasLayer,
                  epsilon: float = 1e-5, dtype: Optional[str] = "bfloat16",
-                 remat: bool = True, input_shape=None, name=None):
+                 remat: bool = True, norms: str = "sandwich",
+                 input_shape=None, name=None):
         super().__init__(input_shape, name or unique_name("decoder_block"))
-        self.attn, self.mlp = attn, mlp
+        if norms not in self.LAYOUTS:
+            raise ValueError(f"unknown norm layout {norms!r}; known: "
+                             f"{sorted(self.LAYOUTS)}")
+        self.mixer, self.mlp, self.norms = attn, mlp, norms
+        # the attention mixer under the name it has always had; None on a
+        # layer that has none
+        self.attn = attn if isinstance(attn, GroupedQueryAttention) else None
         self.epsilon, self.remat = epsilon, remat
         self.dtype = None if dtype is None else jnp.dtype(dtype)
         self.has_state = bool(getattr(mlp, "has_state", False))
 
     def build(self, input_shape: Shape):
-        self.attn.ensure_built(input_shape)
+        self.mixer.ensure_built(input_shape)
         self.mlp.ensure_built(input_shape)
         self._norms = {n: RMSNorm(self.epsilon, name=f"{self.name}_{n}")
-                       for n in self.NORMS}
+                       for n in self.LAYOUTS[self.norms]}
         for norm in self._norms.values():
             norm.ensure_built(input_shape)
 
     def _parts(self):
-        return {"attn": self.attn, "mlp": self.mlp, **self._norms}
+        return {self.mixer.block_key: self.mixer, "mlp": self.mlp,
+                **self._norms}
 
     def init_params(self, rng):
         return {key: part.init_params(jax.random.fold_in(rng, i))
@@ -208,13 +273,16 @@ class DecoderBlock(KerasLayer):
         return self.mlp.init_state() if self.has_state else {}
 
     def call(self, params, x, state=None, training=False, **kw):
-        eps = self.epsilon
+        eps, mixer_key = self.epsilon, self.mixer.block_key
 
-        def attn_half(p, h):
+        def after(p, name, y):
+            return rms_norm(y, p[name]["gain"], eps) if name in p else y
+
+        def mixer_half(p, h):
             p = _cast(p, self.dtype)
-            a = self.attn.call(p["attn"],
-                               rms_norm(h, p["in_norm"]["gain"], eps))
-            return h + rms_norm(a, p["post_attn_norm"]["gain"], eps)
+            a = self.mixer.call(p[mixer_key],
+                                rms_norm(h, p["in_norm"]["gain"], eps))
+            return h + after(p, "post_attn_norm", a)
 
         def mlp_half(p, h, st):
             p = _cast(p, self.dtype)
@@ -223,27 +291,24 @@ class DecoderBlock(KerasLayer):
                 y, st = self.mlp.call(p["mlp"], m, state=st, training=training)
             else:
                 y = self.mlp.call(p["mlp"], m)
-            return h + rms_norm(y, p["post_mlp_norm"]["gain"], eps), st
+            return h + after(p, "post_mlp_norm", y), st
 
-        # What the attention half keeps is in the class's docstring. The
+        # What the mixer's half keeps is in the class's docstring. The
         # other half keeps nothing: its forward pass, the expert layer's
         # compacted pass included, runs twice (overflow chunks, rematerialised
         # one by one inside, three times).
         if self.remat:
-            # imported here like every use of the kernels' module: it brings
-            # Pallas, a second of import that a model on the XLA path skips
-            from analytics_zoo_tpu.ops.flash_attention import FLASH_RESIDUALS
-
-            attn_half = jax.checkpoint(
-                attn_half,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *FLASH_RESIDUALS))
+            kept = self.mixer.kept_residuals()
+            mixer_half = jax.checkpoint(
+                mixer_half,
+                policy=jax.checkpoint_policies.save_only_these_names(*kept)
+                if kept else None)
             mlp_half = jax.checkpoint(mlp_half)
         if self.dtype is not None:
             x = x.astype(self.dtype)
-        attn_keys = ("attn", "in_norm", "post_attn_norm")
-        h = attn_half({k: params[k] for k in attn_keys}, x)
+        mixer_keys = (mixer_key, "in_norm", "post_attn_norm")
+        h = mixer_half({k: params[k] for k in mixer_keys if k in params}, x)
         h, new_state = mlp_half(
-            {k: v for k, v in params.items() if k not in attn_keys}, h,
+            {k: v for k, v in params.items() if k not in mixer_keys}, h,
             state if self.has_state else None)
         return (h, new_state) if self.has_state else h

@@ -97,7 +97,9 @@ class SparseMoE(KerasLayer):
     ``s = sigmoid(W_r x)`` in float32 over all ``n_experts``; the ``top_k``
     largest of ``s + b`` are picked (``b``, the selection bias, is state, not
     a weight: it steers the pick and gets no gradient); ``w = s[picked]``,
-    normalised to sum 1 (``route_norm``) and scaled by ``route_scale``.
+    normalised to sum 1 (``route_norm``: over the sum plus ``route_eps``) and
+    scaled by ``route_scale``. ``n_shared=0``: no shared expert, everything
+    the layer gives a token comes from the experts held.
 
     ``experts_held = (offset, count)``: of the ``n_experts`` the router
     scores, this layer holds weights for ``count``, numbered from ``offset``
@@ -123,7 +125,8 @@ class SparseMoE(KerasLayer):
     def __init__(self, n_experts: int, width: int, top_k: int,
                  experts_held=None, n_shared: int = 1,
                  route_norm: bool = True, route_scale: float = 1.0,
-                 bias_rate: float = 0.001, input_shape=None, name=None):
+                 bias_rate: float = 0.001, route_eps: float = 1e-20,
+                 input_shape=None, name=None):
         super().__init__(input_shape, name or unique_name("sparse_moe"))
         self.n_experts, self.width, self.top_k = int(n_experts), int(width), int(top_k)
         offset, count = experts_held or (0, self.n_experts)
@@ -133,7 +136,7 @@ class SparseMoE(KerasLayer):
         self.experts_held = (int(offset), int(count))
         self.n_shared = int(n_shared)
         self.route_norm, self.route_scale = route_norm, float(route_scale)
-        self.bias_rate = float(bias_rate)
+        self.bias_rate, self.route_eps = float(bias_rate), float(route_eps)
 
     def build(self, input_shape: Shape):
         d, init = input_shape[-1], DECODER_INIT
@@ -159,7 +162,7 @@ class SparseMoE(KerasLayer):
         with jax.named_scope("moe.route"):
             picked, weights, counts = route_topk(
                 flat, params["router"], state["select_bias"], self.top_k,
-                self.route_norm, self.route_scale)
+                self.route_norm, self.route_scale, self.route_eps)
         with jax.named_scope("moe.experts"):
             y, compact = held_experts_ffn(
                 flat, picked, weights, params["experts_w_gate_up"],

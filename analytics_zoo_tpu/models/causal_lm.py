@@ -1,8 +1,9 @@
 """Causal decoder-only language model over the decoder block library
 (keras/layers/decoder.py, keras/layers/moe.py): token embedding, a stack of
-``DecoderBlock``s whose attention kind (``sliding_attention`` /
-``full_attention``) and feed-forward kind (leading dense layers, then expert
-layers) come from the configuration, a final RMS norm and an untied head.
+``DecoderBlock``s whose token mixer (``sliding_attention`` /
+``full_attention`` / ``conv``) and feed-forward kind (leading dense layers,
+then expert layers) come from the configuration, a final RMS norm and a
+head, its own or the embedding's transpose.
 ``apply`` ends in logits over the vocabulary held here, in the compute type;
 train it with ``compile(optimizer, loss="token_crossentropy_from_logits")``
 and ``Estimator.train`` / ``fit`` like any other ``KerasNet``.
@@ -25,23 +26,68 @@ from analytics_zoo_tpu.keras.engine.base import unique_name
 from analytics_zoo_tpu.keras.engine.topology import KerasNet
 from analytics_zoo_tpu.keras.layers.core import Dense
 from analytics_zoo_tpu.keras.layers.decoder import (
-    DecoderBlock, GroupedQueryAttention, RMSNorm, SwiGLU, rms_norm,
+    DecoderBlock, GatedShortConv, GroupedQueryAttention, RMSNorm, SwiGLU,
+    rms_norm,
 )
 from analytics_zoo_tpu.keras.layers.embeddings import Embedding
 from analytics_zoo_tpu.keras.layers.moe import DECODER_INIT, SparseMoE
 
 
+def _afmoe(cfg: Dict) -> Dict:
+    """Trinity: four norms a layer, a gated attention whose full layers
+    carry no position, one shared expert, a muP embedding, its own head."""
+    return dict(
+        head_dim=cfg["head_dim"], n_shared=cfg["num_shared_experts"],
+        sliding_window=cfg["sliding_window"], rope_theta=cfg["rope_theta"],
+        epsilon=cfg["rms_norm_eps"],
+        embed_scale=(math.sqrt(cfg["hidden_size"])
+                     if cfg.get("mup_enabled") else 1.0),
+        route_norm=cfg["route_norm"], route_scale=cfg["route_scale"],
+        bias_rate=cfg["load_balance_coeff"])
+
+
+def _lfm2_moe(cfg: Dict) -> Dict:
+    """LFM2: gated short convolutions beside full attention with rotary
+    positions, the two pre-norms alone, no attention gate, no shared
+    expert, a tied head (``tie_embeddings``, the family's convention
+    where the file does not say). ``bias_rate`` is the training
+    framework's, not the model file's: 0 leaves the bias at rest."""
+    return dict(
+        head_dim=cfg["hidden_size"] // cfg["num_attention_heads"],
+        n_shared=0, rope_theta=cfg["rope_parameters"]["rope_theta"],
+        rope_full_layers=True, gated_attention=False,
+        conv_kernel=cfg["conv_L_cache"], norms="pre",
+        epsilon=cfg["norm_eps"], embed_scale=1.0,
+        route_norm=cfg["norm_topk_prob"],
+        route_scale=cfg["routed_scaling_factor"], route_eps=1e-6,
+        bias_rate=(cfg.get("bias_rate", 0.001)
+                   if cfg["use_expert_bias"] else 0.0),
+        tie_embeddings=cfg.get("tie_embeddings", True))
+
+
+# a published config's `model_type` -> the constructor's arguments that its
+# own key names give
+_FAMILIES = {"afmoe": _afmoe, "lfm2_moe": _lfm2_moe}
+
+
 class CausalLM(KerasNet):
-    """See the module docstring. ``layer_types``: one of
-    ``"sliding_attention"`` (rotary positions, window ``sliding_window``) or
-    ``"full_attention"`` (causal, no positional encoding) a layer;
-    the first ``num_dense_layers`` layers get a dense ``SwiGLU`` of
-    ``dense_width``, the others a ``SparseMoE``. ``embed_scale``: the
-    embedding's multiplier (sqrt(hidden) with muP). State: each expert layer's
-    selection bias and step counts, and ``tokens``, the tokens of the last
-    training step; ``train_stats`` hands the counts to ``Estimator.train``,
-    which brings them out with the loss and gives them back to
-    ``record_train_stats`` at the drain."""
+    """See the module docstring. ``layer_types``: a layer's token mixer, one
+    of ``"sliding_attention"`` (rotary positions, window ``sliding_window``),
+    ``"full_attention"`` (causal; rotary positions where ``rope_full_layers``,
+    else no positional encoding) or ``"conv"`` (a ``GatedShortConv`` over
+    ``conv_kernel`` tokens); the first ``num_dense_layers`` layers get a
+    dense ``SwiGLU`` of ``dense_width``, the others a ``SparseMoE``.
+    ``gated_attention``: the attention's sigmoid output gate. ``norms``: a
+    block's norm layout (``DecoderBlock``). ``embed_scale``: the embedding's
+    multiplier (sqrt(hidden) with muP). ``tie_embeddings``: the head is the
+    embedding's transpose, one leaf of the parameters whose gradient is the
+    sum of both uses. State: each expert layer's selection bias and step
+    counts, and ``tokens``, the tokens of the last training step;
+    ``train_stats`` hands the counts to ``Estimator.train``, which brings
+    them out with the loss and gives them back to ``record_train_stats`` at
+    the drain."""
+
+    MIXERS = ("sliding_attention", "full_attention", "conv")
 
     def __init__(self, vocab_size: int, hidden_size: int,
                  layer_types: Sequence[str], n_head: int, n_kv_head: int,
@@ -51,7 +97,10 @@ class CausalLM(KerasNet):
                  sliding_window: int = 2048, rope_theta: float = 10000.0,
                  epsilon: float = 1e-5, embed_scale: Optional[float] = None,
                  route_norm: bool = True, route_scale: float = 1.0,
-                 bias_rate: float = 0.001, seq_len: Optional[int] = None,
+                 bias_rate: float = 0.001, route_eps: float = 1e-20,
+                 rope_full_layers: bool = False, gated_attention: bool = True,
+                 conv_kernel: int = 3, norms: str = "sandwich",
+                 tie_embeddings: bool = False, seq_len: Optional[int] = None,
                  dtype: Optional[str] = "bfloat16", remat: bool = True,
                  name: Optional[str] = None):
         super().__init__(name or unique_name("causal_lm"))
@@ -65,62 +114,72 @@ class CausalLM(KerasNet):
         self.embed.ensure_built((None, seq_len))
         self.blocks = []
         for i, kind in enumerate(layer_types):
-            if kind not in ("sliding_attention", "full_attention"):
-                raise ValueError(f"layer {i}: unknown attention kind {kind!r}")
+            if kind not in self.MIXERS:
+                raise ValueError(f"layer {i}: unknown kind {kind!r}; known: "
+                                 f"{list(self.MIXERS)}")
             sliding = kind == "sliding_attention"
-            attn = GroupedQueryAttention(
-                n_head, n_kv_head, head_dim,
-                window=sliding_window if sliding else None,
-                rope_theta=rope_theta if sliding else None,
-                epsilon=epsilon, name=f"{self.name}_l{i}_attn")
+            if kind == "conv":
+                mixer = GatedShortConv(conv_kernel,
+                                       name=f"{self.name}_l{i}_conv")
+            else:
+                mixer = GroupedQueryAttention(
+                    n_head, n_kv_head, head_dim,
+                    window=sliding_window if sliding else None,
+                    rope_theta=(rope_theta if sliding or rope_full_layers
+                                else None),
+                    gated=gated_attention, epsilon=epsilon,
+                    name=f"{self.name}_l{i}_attn")
             if i < num_dense_layers:
                 mlp = SwiGLU(dense_width, name=f"{self.name}_l{i}_mlp")
             else:
                 mlp = SparseMoE(n_experts, expert_width, top_k, experts_held,
                                 n_shared, route_norm, route_scale, bias_rate,
-                                name=f"{self.name}_l{i}_moe")
-            block = DecoderBlock(attn, mlp, epsilon, dtype, remat,
+                                route_eps, name=f"{self.name}_l{i}_moe")
+            block = DecoderBlock(mixer, mlp, epsilon, dtype, remat, norms,
                                  name=f"{self.name}_l{i}")
             block.ensure_built((None, seq_len, hidden_size))
             self.blocks.append(block)
+        self.conv_layers = sum(kind == "conv" for kind in layer_types)
         self.final_norm = RMSNorm(epsilon, name=self.name + "_final_norm")
         self.final_norm.ensure_built((None, seq_len, hidden_size))
-        self.head = Dense(vocab_size, init=DECODER_INIT, bias=False,
-                          name=self.name + "_head")
-        self.head.ensure_built((None, seq_len, hidden_size))
+        self.head = None
+        if not tie_embeddings:
+            self.head = Dense(vocab_size, init=DECODER_INIT, bias=False,
+                              name=self.name + "_head")
+            self.head.ensure_built((None, seq_len, hidden_size))
         held = [b.mlp.experts_held for b in self.blocks if b.has_state]
         self.experts_held = held[0] if held else None
         self._obs = None
 
     @classmethod
     def from_config(cls, cfg: Dict, **kw) -> "CausalLM":
-        """From a published ``afmoe``-style config (Trinity): its key names,
-        plus ``router_num_experts`` (the router's width) where ``num_experts``
-        counts only the experts held, from ``experts_held_offset``."""
+        """From a published config, by its ``model_type`` (``afmoe`` where it
+        states none): each family's own key names, plus ``router_num_experts``
+        (the router's width) where ``num_experts`` counts only the experts
+        held, from ``experts_held_offset``."""
+        kind = cfg.get("model_type", "afmoe")
+        if kind not in _FAMILIES:
+            raise ValueError(f"unknown model_type {kind!r}; known: "
+                             f"{sorted(_FAMILIES)}")
         held = cfg["num_experts"]
-        total = cfg.get("router_num_experts", held)
-        return cls(
+        shared = dict(
             vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
             layer_types=cfg["layer_types"],
             n_head=cfg["num_attention_heads"],
-            n_kv_head=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+            n_kv_head=cfg["num_key_value_heads"],
             dense_width=cfg["intermediate_size"],
-            num_dense_layers=cfg["num_dense_layers"], n_experts=total,
+            num_dense_layers=cfg["num_dense_layers"],
+            n_experts=cfg.get("router_num_experts", held),
             experts_held=(cfg.get("experts_held_offset", 0), held),
             expert_width=cfg["moe_intermediate_size"],
-            top_k=cfg["num_experts_per_tok"],
-            n_shared=cfg["num_shared_experts"],
-            sliding_window=cfg["sliding_window"], rope_theta=cfg["rope_theta"],
-            epsilon=cfg["rms_norm_eps"],
-            embed_scale=(math.sqrt(cfg["hidden_size"])
-                         if cfg.get("mup_enabled") else 1.0),
-            route_norm=cfg["route_norm"], route_scale=cfg["route_scale"],
-            bias_rate=cfg["load_balance_coeff"], **kw)
+            top_k=cfg["num_experts_per_tok"])
+        return cls(**shared, **_FAMILIES[kind](cfg), **kw)
 
     # -- model protocol --------------------------------------------------
 
     def layers(self):
-        return [self.embed, *self.blocks, self.final_norm, self.head]
+        tail = [self.final_norm] + ([self.head] if self.head else [])
+        return [self.embed, *self.blocks, *tail]
 
     def init(self, rng):
         params, state = super().init(rng)
@@ -141,7 +200,10 @@ class CausalLM(KerasNet):
             else:
                 h = block.call(params[block.name], h)
         h = rms_norm(h, params[self.final_norm.name]["gain"], self.epsilon)
-        kernel = params[self.head.name]["kernel"]
+        if self.head is None:      # tied: the embedding's transpose
+            kernel = params[self.embed.name]["embeddings"].T
+        else:
+            kernel = params[self.head.name]["kernel"]
         logits = h @ (kernel if self.dtype is None else kernel.astype(self.dtype))
         if training:
             new_state[self.name] = {
@@ -177,6 +239,8 @@ class CausalLM(KerasNet):
             self._obs = lm_train_metrics()
         obs = self._obs
         obs["tokens"].inc(float(stats["tokens"]))
+        # the step's tokens went through every short convolution the model has
+        obs["conv_token_layers"].inc(float(stats["tokens"]) * self.conv_layers)
         counts = stats.get("expert_tokens")
         if counts is None:
             return

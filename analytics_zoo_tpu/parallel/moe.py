@@ -140,18 +140,20 @@ _CHUNK_TOKENS = 4096
 
 
 def route_topk(x, router, select_bias, k: int, normalize: bool = True,
-               scale: float = 1.0):
+               scale: float = 1.0, eps: float = 1e-20):
     """Sigmoid top-k routing in float32. ``x`` (T, d), ``router`` (d, E),
     ``select_bias`` (E,): added to the scores for the pick only, outside the
-    gradient. Returns (picked (T, k) int32, weights (T, k) float32, counts
-    (E,) float32: the tokens routed to each expert)."""
+    gradient. ``normalize``: a token's weights over their sum plus ``eps``
+    (the published model files differ in it: 1e-20, 1e-6). Returns (picked
+    (T, k) int32, weights (T, k) float32, counts (E,) float32: the tokens
+    routed to each expert)."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _, picked = jax.lax.top_k(scores + jax.lax.stop_gradient(select_bias), k)
     w = jnp.take_along_axis(scores, picked, axis=-1)
     if normalize:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     counts = jnp.sum(jax.nn.one_hot(picked, scores.shape[-1],
                                     dtype=jnp.float32), axis=(0, 1))
     return picked.astype(jnp.int32), w * scale, counts

@@ -541,21 +541,42 @@ def test_a_compacted_pass_exists_only_where_it_is_smaller(held, n_experts, k,
         assert rows == 32768
 
 
-def test_the_eight_shares_add_up_to_the_uncut_expert_layer():
-    """The routed parts of all shares, plus the shared expert counted once,
-    equal the uncut reference's expert layer."""
+# the two families' expert layers: the reference, its configuration at the toy
+# widths with all 8 experts, the layer's own arguments, and whether a shared
+# expert stands beside the routed ones
+_LFM2_MOE = dict(CFG, norm_topk_prob=True, routed_scaling_factor=1)
+_EXPERT_LAYERS = {
+    "afmoe": ("benchmark.reference.trinity", CFG,
+              dict(route_scale=2.826), True),
+    "lfm2_moe": ("benchmark.reference.lfm2", _LFM2_MOE,
+                 dict(route_eps=1e-6), False),
+}
+
+
+@pytest.mark.parametrize("family", sorted(_EXPERT_LAYERS))
+def test_the_eight_shares_add_up_to_the_uncut_expert_layer(family):
+    """The routed parts of all shares, plus the shared expert (where the
+    family has one) counted once, equal the uncut reference's expert layer."""
+    import importlib
+
     from analytics_zoo_tpu.keras.layers import SparseMoE
 
-    whole = dict(CFG, num_experts=8, experts_held_offset=0)
-    w = ref.init_weights(whole, jax.random.PRNGKey(5))["layers"][1]
+    module, cfg, layer_args, has_shared = _EXPERT_LAYERS[family]
+    ref_ = importlib.import_module(module)
+    whole = dict(cfg, num_experts=8, experts_held_offset=0)
+    w = ref_.init_weights(whole, jax.random.PRNGKey(5))["layers"][1]
     m = jax.random.normal(jax.random.PRNGKey(6), (64, 64))
     bias = 0.01 * jax.random.normal(jax.random.PRNGKey(7), (8,))
+
+    def shared():      # what every chip computes alike
+        return ref_._swiglu(w["shared"], m, jnp.matmul) if has_shared else 0.0
+
     with jax.default_matmul_precision("highest"):
-        want, counts = ref.expert_layer(w, m, bias, whole)
+        want, counts = ref_.expert_layer(w, m, bias, whole)
         total = 0.0
         for offset in range(0, 8, 2):            # four shares of two
             share = SparseMoE(8, 32, top_k=2, experts_held=(offset, 2),
-                              n_shared=0, route_scale=2.826)
+                              n_shared=0, **layer_args)
             share.ensure_built((None, 64))
             held = {k: v[offset:offset + 2] for k, v in w["experts"].items()}
             p = {"router": w["router"],
@@ -568,13 +589,12 @@ def test_the_eight_shares_add_up_to_the_uncut_expert_layer():
             np.testing.assert_array_equal(np.asarray(st["expert_tokens"]),
                                           np.asarray(counts))
             # the reference, given the same share, computes the same part
-            part, _ = ref.expert_layer(
+            part, _ = ref_.expert_layer(
                 dict(w, experts=held), m, bias,
                 dict(whole, num_experts=2, experts_held_offset=offset))
-            shared = ref._swiglu(w["shared"], m, jnp.matmul)
-            _close(y, part - shared, 1e-5)
+            _close(y, part - shared(), 1e-5)
             total = total + y
-        total = total + ref._swiglu(w["shared"], m, jnp.matmul)
+        total = total + shared()
     _close(total, want, 1e-5)
 
 
