@@ -8,6 +8,11 @@ the standard tiled dq / dk-dv split (two kernels, each re-computing the
 probability tile from the saved per-row logsumexp), so *training* gets the
 memory and bandwidth win too — no O(S²) recompute fallback.
 
+Which (query block, key block) pairs a kernel visits is a block schedule,
+computed from the shapes at trace time (``_schedule``): a block no query of
+which sees any key (above the causal diagonal, older than a window) is no
+grid step. Not causal, the schedule is the whole rectangle.
+
 Additive bias is supported for the padding-mask layout (query dim == 1,
 broadcastable to ``(batch, heads, 1, s_k)``) — exactly what BERT's attention
 mask is — so masked BERT training stays on the fast path. d(bias) is
@@ -24,10 +29,11 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -74,7 +80,7 @@ def _resolve_blocks(block_q, block_k, s_q: int, s_k: int):
     The default tiles 512x512 whenever the sequence axes divide by 512:
     larger tiles amortize the per-block softmax bookkeeping and the grid
     step overhead over 16x the MXU work of a 128x128 tile. Whether that
-    is the fastest tiling on the current machine is not measured (S4 in
+    is the fastest tiling on the current machine is not measured (D9 in
     ROADMAP.md owns the sweep). Axes that don't divide by 512 keep the
     128 MXU floor.
     """
@@ -141,14 +147,177 @@ def _interpret() -> bool:
 
 
 # ---------------------------------------------------------------------------
-# Forward
+# The block schedule: which (query block, key block) pairs a kernel visits and
+# in what order. Known at trace time from the shapes alone; the one place that
+# knows which blocks are live.
 # ---------------------------------------------------------------------------
+
+# A step's word in the schedule's ``flags`` table: the first step of its
+# resident block, the last, and whether the step has a block to compute.
+_FIRST, _LAST, _LIVE = 1, 2, 4
+
+# The most live blocks a schedule may hold: its three int32 tables are
+# prefetched into SMEM, 1 MiB on a v5e, and this leaves a quarter of it.
+_MAX_STEPS = 1 << 16
+
+
+def _live_blocks(causal: bool, window, s_q: int, s_k: int, block_q: int,
+                 block_k: int) -> np.ndarray:
+    """Which blocks hold a (query, key) pair that is seen,
+    ``(s_q // block_q, s_k // block_k)``. Query i sits at position
+    ``i + s_k - s_q`` (bottom-right aligned, as the XLA reference's
+    ``tril(k=s_k - s_q)``) and sees the keys at or before it, with a
+    ``window`` only the ``window`` newest of them. Over a block
+    ``q_pos - k_pos`` takes every value between its ends, and a pair is seen
+    where that lies in ``[0, window)``. Not causal: every block."""
+    blocks_q, blocks_k = s_q // block_q, s_k // block_k
+    if not causal:
+        return np.ones((blocks_q, blocks_k), bool)
+    q_lo = np.arange(blocks_q)[:, None] * block_q + (s_k - s_q)
+    k_lo = np.arange(blocks_k)[None, :] * block_k
+    least = q_lo - (k_lo + block_k - 1)        # q_pos - k_pos at its ends
+    most = q_lo + block_q - 1 - k_lo
+    return (most >= 0) & (least < (np.inf if window is None else window))
+
+
+class _Schedule(NamedTuple):
+    """A kernel's walk over its blocks as two grid axes. Where a block is
+    dead the live ones lie end to end on the inner axis, (1, all the steps),
+    and three tables name them, one entry a step: the index of the block
+    that stays in VMEM (``resident``), of the one streamed against it
+    (``streamed``, ascending within a resident block, so sums keep their
+    order) and the step's ``flags``. Where every block is live (not causal:
+    the whole rectangle) the walk is ``direct``: the axes are (``blocks`` =
+    the resident blocks, ``steps`` = the streamed ones), the grid's own
+    indices are the blocks' and no table is read, so a resident operand
+    stands still along the inner axis as far as the compiler can see.
+    Forward and dq take a step once; dk/dv once for each of the ``heads``
+    query heads that share the key block, heads innermost, so its inner axis
+    is ``heads`` times as long and the tables are not."""
+    resident: np.ndarray
+    streamed: np.ndarray
+    flags: np.ndarray
+    blocks: int
+    steps: int
+    heads: int
+    direct: bool
+
+    @property
+    def tables(self):
+        return self.resident, self.streamed, self.flags
+
+    def step(self, t):
+        """The step, of one head's, that inner index ``t`` is."""
+        return t if self.heads == 1 else t // self.heads
+
+    def head(self, t):
+        return 0 if self.heads == 1 else t % self.heads
+
+    def resident_block(self, j, t, resident_ref):
+        return j if self.direct else resident_ref[self.step(t)]
+
+    def streamed_block(self, t, streamed_ref):
+        return self.step(t) if self.direct else streamed_ref[self.step(t)]
+
+    def this_step(self, resident_ref, streamed_ref, flags_ref):
+        """(resident block, streamed block, first, last, live) of the grid
+        step at hand: ``first`` / ``last`` bracket a resident block's steps,
+        every head of a group included."""
+        j, t = pl.program_id(1), pl.program_id(2)
+        if self.direct:
+            first, last, live = t == 0, t == self.steps * self.heads - 1, True
+        else:
+            flags = flags_ref[self.step(t)]
+            first, last = flags & _FIRST != 0, flags & _LAST != 0
+            live = flags & _LIVE != 0
+            if self.heads > 1:
+                first = jnp.logical_and(first, self.head(t) == 0)
+                last = jnp.logical_and(last, self.head(t) == self.heads - 1)
+        return (self.resident_block(j, t, resident_ref),
+                self.streamed_block(t, streamed_ref), first, last, live)
+
+    def when_live(self, live, compute) -> None:
+        """The step's block work: on a live step only, and with no branch
+        where every step is one."""
+        if self.direct or (self.flags & _LIVE).all():
+            compute()
+        else:
+            pl.when(live)(compute)
+
+
+@functools.lru_cache(maxsize=256)
+def _schedule(causal: bool, window, s_q: int, s_k: int, block_q: int,
+              block_k: int, resident: str = "q", heads: int = 1) -> _Schedule:
+    """The live blocks in the order a kernel walks them. ``resident="q"``
+    (forward, dq): a query block against its live key blocks.
+    ``resident="k"`` (dk/dv): a key block against its live query blocks. A
+    resident block with no live partner (queries before the first key, keys
+    older than every window) gets one step that is not ``_LIVE``, so that
+    its output is still initialised and written."""
+    live = _live_blocks(causal, window, s_q, s_k, block_q, block_k)
+    if resident == "k":
+        live = live.T
+    if live.all():      # direct: the tables are one word each, never read
+        return _Schedule(*[np.zeros(1, np.int32)] * 3, *live.shape, heads,
+                         True)
+    res, strm, flags = [], [], []
+    for r, row in enumerate(live):
+        partners = np.flatnonzero(row)
+        word = np.full(max(partners.size, 1), _LIVE if partners.size else 0)
+        word[0] |= _FIRST
+        word[-1] |= _LAST
+        res.append(np.full(word.size, r))
+        strm.append(partners if partners.size else np.zeros(1))
+        flags.append(word)
+    res, strm, flags = (np.concatenate(t).astype(np.int32)
+                        for t in (res, strm, flags))
+    return _Schedule(res, strm, flags, 1, flags.size, heads, False)
+
+
+def _index_maps(sched: _Schedule, resident: str, group: int):
+    """The index maps of a kernel's operands over its grid (row, j, t) and
+    the schedule's tables: a q block ``(1, block_q, d)``, a per-query vector
+    ``(1, 1, block_q)``, a K/V block and a per-key vector. ``resident="q"``:
+    a row is a query head, and reads key-value row ``row // group``.
+    ``resident="k"``: a row is a key-value head, and the step names the
+    query head of its group."""
+
+    def resident_blk(j, t, tables):
+        return sched.resident_block(j, t, tables[0])
+
+    def streamed_blk(j, t, tables):
+        return sched.streamed_block(t, tables[1])
+
+    if resident == "q":
+        q_of, k_of = resident_blk, streamed_blk
+    else:
+        q_of, k_of = streamed_blk, resident_blk
+
+    def q_row(i, t):
+        return i if resident == "q" else i * group + sched.head(t)
+
+    def kv_row(i):
+        return i // group if resident == "q" else i
+
+    def q_blk(i, j, t, *tables):
+        return q_row(i, t), q_of(j, t, tables), 0
+
+    def q_vec(i, j, t, *tables):
+        return q_row(i, t), 0, q_of(j, t, tables)
+
+    def kv_blk(i, j, t, *tables):
+        return kv_row(i), k_of(j, t, tables), 0
+
+    def k_vec(i, j, t, *tables):
+        return kv_row(i), 0, k_of(j, t, tables)
+
+    return q_blk, q_vec, kv_blk, k_vec
 
 
 def _maybe_bias(kernel, has_bias: bool, n_in: int):
     """Adapt a kernel written with a ``bias_ref`` slot to pallas' positional
     calling convention when no bias operand is passed. ``n_in`` counts the
-    input refs *before* the bias slot."""
+    refs *before* the bias slot (the schedule's three tables included)."""
     if has_bias:
         return kernel
 
@@ -174,18 +343,45 @@ def _masked(s, qi, ki, block_q: int, block_k: int, causal_offset: int,
     return jnp.where(seen, s, _NEG_INF)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale: float, causal: bool,
-                blocks_k: int, block_q: int, block_k: int,
-                causal_offset: int, has_bias: bool, window, steps_k: int):
-    qi = pl.program_id(1)
-    t = pl.program_id(2)
-    # with a window the innermost axis walks only the K blocks a q block's
-    # window can touch, from the first of them
-    ki = t + _k_base(qi, block_q, block_k, causal_offset, window)
+def _pcall(kernel, name: str, sched: _Schedule, rows: int, in_specs,
+           out_specs, out_shape, scratch_shapes, operands):
+    """Shared pallas_call plumbing for all three kernels: a grid of ``rows``
+    by the schedule's two axes (the innermost sequential, carrying the
+    accumulator scratch), the schedule's tables prefetched into SMEM for the
+    index maps and the kernel's ``pl.when``s, the interpret flag. ``name``
+    is the kernel's stable name in a device trace (``zoo_flash_fwd`` /
+    ``zoo_flash_dq`` / ``zoo_flash_dkv``): the benchmark's readers find the
+    kernels by it."""
+    interpret = _interpret()
+    kw = {}
+    if not interpret:
+        kw["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    return pl.pallas_call(
+        kernel, interpret=interpret, name=name, out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(rows, sched.blocks, sched.steps * sched.heads),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        **kw)(*sched.tables, *operands)
+
+
+# ---------------------------------------------------------------------------
+# Forward
+# ---------------------------------------------------------------------------
+
+
+def _fwd_kernel(resident_ref, streamed_ref, flags_ref, q_ref, k_ref, v_ref,
+                bias_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
+                scale: float, causal: bool, sched: _Schedule,
+                block_q: int, block_k: int, causal_offset: int,
+                has_bias: bool, window):
+    qi, ki, first, last, live = sched.this_step(resident_ref, streamed_ref,
+                                               flags_ref)
     cdt = _compute_dtype(q_ref)
 
-    @pl.when(t == 0)
+    @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -199,6 +395,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         if has_bias:
             s = s + bias_ref[0, 0].astype(jnp.float32)[None, :]
         if causal:
+            # A row with no key in a live block adds exp(0) terms at the
+            # _NEG_INF maximum; the first real key's
+            # alpha = exp(_NEG_INF - m) = 0 wipes them.
             s = _masked(s, qi, ki, block_q, block_k, causal_offset, window)
         m_prev = m_ref[...]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -209,96 +408,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         acc_ref[...] = acc_ref[...] * alpha + _mm(p, v, cdt)
         m_ref[...] = m_new
 
-    if causal:
-        # fully-masked K blocks (above the diagonal, or wholly older than
-        # the window) contribute nothing: skip their compute, keep the
-        # running statistics. A row with no key in a live block adds
-        # exp(0) terms at the _NEG_INF maximum; the first real key's
-        # alpha = exp(_NEG_INF - m) = 0 wipes them.
-        pl.when(_causal_block_live(qi, ki, block_q, block_k, causal_offset,
-                                   window, blocks_k))(compute)
-    else:
-        compute()
+    sched.when_live(live, compute)
 
-    @pl.when(t == steps_k - 1)
+    @pl.when(last)
     def _flush():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
         lse_ref[0, 0] = (m_ref[...] + jnp.log(l))[:, 0]
-
-
-def _pcall(kernel, interpret: bool, name: str, **kw):
-    """Shared pallas_call plumbing for all three kernels: interpret flag
-    plus the (TPU-only) grid dimension semantics — two parallel outer axes,
-    sequential innermost axis carrying the accumulator scratch. ``name`` is
-    the kernel's stable name in a device trace (``zoo_flash_fwd`` /
-    ``zoo_flash_dq`` / ``zoo_flash_dkv``): the benchmark's readers find the
-    kernels by it."""
-    if not interpret:
-        kw["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    return pl.pallas_call(kernel, interpret=interpret, name=name, **kw)
-
-
-def _k_base(qi, block_q: int, block_k: int, causal_offset: int, window):
-    """The first K block a q block's window can touch: where the innermost
-    axis starts when a window bounds it (0 without one)."""
-    if window is None:
-        return 0
-    return jnp.maximum(qi * block_q + causal_offset - (window - 1), 0) // block_k
-
-
-def _q_base(ki, block_q: int, block_k: int, causal_offset: int, window):
-    """The first q block that can see K block ``ki`` (0 without a window:
-    the axis is walked whole)."""
-    if window is None:
-        return 0
-    return jnp.maximum(ki * block_k - causal_offset, 0) // block_q
-
-
-def _inner_steps(blocks: int, block_outer: int, block_inner: int, window):
-    """Steps of the innermost axis: all ``blocks`` without a window; with
-    one, the most inner blocks that ``block_outer + window - 1`` positions
-    can touch."""
-    if window is None:
-        return blocks
-    return min(blocks, (block_outer + window - 2) // block_inner + 2)
-
-
-def _stream_clamps(causal: bool, block_q: int, block_k: int,
-                   causal_offset: int, blocks_q: int, blocks_k: int,
-                   window=None):
-    """Index-map clamps that stop the pipeline DMA-ing dead causal blocks.
-
-    ``pl.when`` only skips the *compute* of a fully-masked block — the
-    BlockSpec index maps advance regardless, so without clamping every
-    dead block still crosses HBM→VMEM (~2x the minimal K/V traffic for
-    causal). Clamping the streamed index to the live range makes every
-    dead step revisit an already-fetched block, which the pallas pipeline
-    elides. Returns (k_stream_idx, q_stream_idx): the K-block index for a
-    given (q-row j, step t) and the q-block index for a given
-    (k-block j, step t). With a ``window`` step t counts from the first
-    block the window can touch (``_k_base`` / ``_q_base``)."""
-    if not causal:
-        return (lambda j, t: t), (lambda j, t: t)
-
-    def k_stream(j, t):
-        # last live K block for q row j: max q_pos = (j+1)*bq - 1 + off
-        last = ((j + 1) * block_q - 1 + causal_offset) // block_k
-        first = _k_base(j, block_q, block_k, causal_offset, window)
-        return jnp.clip(first + t, first, jnp.clip(last, 0, blocks_k - 1))
-
-    def q_stream(j, t):
-        # first live q block for K block j: q_pos >= j*bk - off
-        first = (j * block_k - causal_offset) // block_q
-        first = jnp.clip(first, 0, blocks_q - 1)
-        if window is None:
-            return jnp.maximum(t, first)
-        # last: q_pos - k_pos < window for the block's newest key
-        last = ((j + 1) * block_k - 1 + window - 1 - causal_offset) // block_q
-        return jnp.clip(first + t, first, jnp.clip(last, 0, blocks_q - 1))
-
-    return k_stream, q_stream
 
 
 def _flash_forward(q, k, v, bias_flat, scale: float, causal: bool,
@@ -314,39 +430,31 @@ def _flash_forward(q, k, v, bias_flat, scale: float, causal: bool,
     bn, s_q, d = q.shape
     s_k = k.shape[1]
     dv = v.shape[-1]
-    blocks_k = s_k // block_k
     group = bn // k.shape[0]
-    interpret = _interpret()
     has_bias = bias_flat is not None
-    ks, _ = _stream_clamps(causal, block_q, block_k, s_k - s_q,
-                           s_q // block_q, blocks_k, window)
-    steps_k = _inner_steps(blocks_k, block_q, block_k, window)
+    sched = _schedule(causal, window, s_q, s_k, block_q, block_k)
 
     kernel = _maybe_bias(functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, blocks_k=blocks_k,
+        _fwd_kernel, scale=scale, causal=causal, sched=sched,
         block_q=block_q, block_k=block_k, causal_offset=s_k - s_q,
-        has_bias=has_bias, window=window, steps_k=steps_k), has_bias, n_in=3)
+        has_bias=has_bias, window=window), has_bias, n_in=6)
 
+    q_blk, q_vec, kv_blk, k_vec = _index_maps(sched, "q", group)
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
-        pl.BlockSpec((1, block_k, d),
-                     lambda i, j, t: (i // group, ks(j, t), 0)),
-        pl.BlockSpec((1, block_k, dv),
-                     lambda i, j, t: (i // group, ks(j, t), 0)),
+        pl.BlockSpec((1, block_q, d), q_blk),
+        pl.BlockSpec((1, block_k, d), kv_blk),
+        pl.BlockSpec((1, block_k, dv), kv_blk),
     ]
     operands = [q, k, v]
     if has_bias:
-        in_specs.append(
-            pl.BlockSpec((1, 1, block_k), lambda i, j, t: (i, 0, ks(j, t))))
+        in_specs.append(pl.BlockSpec((1, 1, block_k), k_vec))
         operands.append(bias_flat)
 
     out, lse = _pcall(
-        kernel, interpret, "zoo_flash_fwd",
-        grid=(bn, s_q // block_q, steps_k),
-        in_specs=in_specs,
+        kernel, "zoo_flash_fwd", sched, bn, in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda i, j, t: (i, j, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda i, j, t: (i, 0, j)),
+            pl.BlockSpec((1, block_q, dv), q_blk),
+            pl.BlockSpec((1, 1, block_q), q_vec),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bn, s_q, dv), q.dtype),
@@ -357,55 +465,35 @@ def _flash_forward(q, k, v, bias_flat, scale: float, causal: bool,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-    )(*operands)
+        operands=operands)
     return out, lse
 
 
 # ---------------------------------------------------------------------------
-# Backward: dq kernel (3-D grid over (bn, q-block, k-block)), dk/dv/dbias
-# kernel (3-D grid over (bn, k-block, q-block)). Both re-materialize the
-# probability tile from the saved logsumexp, accumulating in an f32 VMEM
-# scratch across the sequential innermost grid axis and flushing on its
-# last step. A whole-row design (K/V as full (1, s, d) blocks with an
-# in-kernel fori over pl.ds slices) unrolls into a Mosaic module whose
-# size grows with the sequence; this blocked-grid form, the same shape
-# jax's bundled kernel uses, keeps the module size independent of the
-# sequence length and lets the pallas pipeline stream K/V blocks instead
-# of holding whole rows in VMEM.
+# Backward: dq kernel (a q block resident, its live K/V blocks streamed),
+# dk/dv/dbias kernel (a K/V block resident, its live q blocks streamed, each
+# once for every query head of the group). Each grid is (rows, the schedule's
+# two axes): the live blocks only. Both re-materialize the probability tile
+# from the saved logsumexp, accumulating in an f32 VMEM scratch from a
+# resident block's first step and flushing on its last. A whole-row design
+# (K/V as full (1, s, d) blocks with an in-kernel fori over pl.ds slices)
+# unrolls into a Mosaic module whose size grows with the sequence; this
+# blocked-grid form keeps the module size independent of the sequence length
+# (the schedule's tables grow with the live blocks of one head, 2 080 int32
+# words three times at 32 768 keys) and lets the pallas pipeline stream K/V
+# blocks instead of holding whole rows in VMEM.
 # ---------------------------------------------------------------------------
 
 
-def _causal_block_live(qi, ki, block_q: int, block_k: int,
-                       causal_offset: int, window=None, blocks=None,
-                       blocks_q=None):
-    """True iff any (q, k) pair in block (qi, ki) satisfies
-    q_pos >= k_pos: max q_pos = (qi+1)*block_q - 1 + causal_offset,
-    min k_pos = ki*block_k; with a ``window`` also q_pos - k_pos < window
-    for some pair: min q_pos - max k_pos < window. ``blocks`` /
-    ``blocks_q``: the number of K / q blocks, for a window's shifted axis,
-    whose last steps can run past the end."""
-    live = (qi + 1) * block_q - 1 + causal_offset >= ki * block_k
-    if window is not None:
-        live = jnp.logical_and(
-            live, qi * block_q + causal_offset
-            - ((ki + 1) * block_k - 1) < window)
-        if blocks is not None:
-            live = jnp.logical_and(live, ki < blocks)
-        if blocks_q is not None:
-            live = jnp.logical_and(live, qi < blocks_q)
-    return live
-
-
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
-               dq_ref, acc_ref, *, scale: float, causal: bool, blocks_k: int,
-               block_q: int, block_k: int, causal_offset: int,
-               has_bias: bool, window, steps_k: int):
-    qi = pl.program_id(1)
-    t = pl.program_id(2)
-    ki = t + _k_base(qi, block_q, block_k, causal_offset, window)
+def _dq_kernel(resident_ref, streamed_ref, flags_ref, q_ref, k_ref, v_ref,
+               do_ref, lse_ref, delta_ref, bias_ref, dq_ref, acc_ref, *,
+               scale: float, causal: bool, sched: _Schedule, block_q: int,
+               block_k: int, causal_offset: int, has_bias: bool, window):
+    qi, ki, first, last, live = sched.this_step(resident_ref, streamed_ref,
+                                               flags_ref)
     cdt = _compute_dtype(q_ref)
 
-    @pl.when(t == 0)
+    @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -426,32 +514,25 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
         ds = p * (dp - delta)
         acc_ref[...] += _mm(ds, k, cdt)
 
-    if causal:
-        # fully-masked blocks above the diagonal: skip the compute (their
-        # contribution is exactly zero); the scratch keeps accumulating
-        pl.when(_causal_block_live(qi, ki, block_q, block_k, causal_offset,
-                                   window, blocks_k))(compute)
-    else:
-        compute()
+    sched.when_live(live, compute)
 
-    @pl.when(t == steps_k - 1)
+    @pl.when(last)
     def _flush():
         dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
-                dk_ref, dv_ref, db_ref, dk_acc, dv_acc, db_acc, *,
-                scale: float, causal: bool, blocks_q: int, block_q: int,
-                block_k: int, causal_offset: int, has_bias: bool,
-                window, steps_q: int, group: int):
-    ki = pl.program_id(1)
-    t = pl.program_id(2)
-    # the innermost axis walks the q blocks of each of the group's query
-    # heads in turn: one K/V block gathers dk/dv from all of them
-    qi = t % steps_q + _q_base(ki, block_q, block_k, causal_offset, window)
+def _dkv_kernel(resident_ref, streamed_ref, flags_ref, q_ref, k_ref, v_ref,
+                do_ref, lse_ref, delta_ref, bias_ref, dk_ref, dv_ref, db_ref,
+                dk_acc, dv_acc, db_acc, *, scale: float, causal: bool,
+                sched: _Schedule, block_q: int, block_k: int,
+                causal_offset: int, has_bias: bool, window):
+    # the steps of a K/V block walk its live q blocks, each for every query
+    # head of the group in turn: one K/V block gathers dk/dv from all of them
+    ki, qi, first, last, live = sched.this_step(resident_ref, streamed_ref,
+                                               flags_ref)
     cdt = _compute_dtype(q_ref)
 
-    @pl.when(t == 0)
+    @pl.when(first)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -478,15 +559,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
         if has_bias:
             db_acc[...] += jnp.sum(ds, axis=0)[None, :]
 
-    if causal:
-        # q blocks entirely above the diagonal contribute exactly zero to
-        # this k block — skip their compute, keep the accumulators
-        pl.when(_causal_block_live(qi, ki, block_q, block_k, causal_offset,
-                                   window, blocks_q=blocks_q))(compute)
-    else:
-        compute()
+    sched.when_live(live, compute)
 
-    @pl.when(t == group * steps_q - 1)
+    @pl.when(last)
     def _flush():
         dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -502,90 +577,64 @@ def _flash_backward(q, k, v, bias_flat, out, lse, g, scale: float,
     dv_dim = v.shape[-1]
     group = bn // k.shape[0]
     has_bias = bias_flat is not None
-    interpret = _interpret()
-    blocks_q = s_q // block_q
-    blocks_k = s_k // block_k
-    steps_k = _inner_steps(blocks_k, block_q, block_k, window)
-    steps_q = _inner_steps(blocks_q, block_k, block_q, window)
+    shapes = (causal, window, s_q, s_k, block_q, block_k)
+    sched_q = _schedule(*shapes)
+    sched_k = _schedule(*shapes, resident="k", heads=group)
+    static = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+                  causal_offset=s_k - s_q, has_bias=has_bias, window=window)
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, None, :]  # (bn, 1, s_q)
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32)
 
-    ks, qs = _stream_clamps(causal, block_q, block_k, s_k - s_q,
-                            blocks_q, blocks_k, window)
-
-    # dq: grid (bn, q-block, k-block) — q/do/lse/delta resident across the
-    # sequential k axis, K/V streamed block-by-block by the pipeline
+    # dq: q/do/lse/delta resident across a q block's steps, its live K/V
+    # blocks streamed one a step by the pipeline
+    q_blk, q_vec, kv_blk, k_vec = _index_maps(sched_q, "q", group)
     dq_specs = [
-        pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
-        pl.BlockSpec((1, block_k, d),
-                     lambda i, j, t: (i // group, ks(j, t), 0)),
-        pl.BlockSpec((1, block_k, dv_dim),
-                     lambda i, j, t: (i // group, ks(j, t), 0)),
-        pl.BlockSpec((1, block_q, dv_dim), lambda i, j, t: (i, j, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda i, j, t: (i, 0, j)),
-        pl.BlockSpec((1, 1, block_q), lambda i, j, t: (i, 0, j)),
+        pl.BlockSpec((1, block_q, d), q_blk),
+        pl.BlockSpec((1, block_k, d), kv_blk),
+        pl.BlockSpec((1, block_k, dv_dim), kv_blk),
+        pl.BlockSpec((1, block_q, dv_dim), q_blk),
+        pl.BlockSpec((1, 1, block_q), q_vec),
+        pl.BlockSpec((1, 1, block_q), q_vec),
     ]
     dq_ops = [q, k, v, g, lse, delta]
     if has_bias:
-        dq_specs.append(
-            pl.BlockSpec((1, 1, block_k), lambda i, j, t: (i, 0, ks(j, t))))
+        dq_specs.append(pl.BlockSpec((1, 1, block_k), k_vec))
         dq_ops.append(bias_flat)
     dq = _pcall(
         _maybe_bias(functools.partial(
-            _dq_kernel, scale=scale, causal=causal, blocks_k=blocks_k,
-            block_q=block_q, block_k=block_k, causal_offset=s_k - s_q,
-            has_bias=has_bias, window=window, steps_k=steps_k),
-            has_bias, n_in=6),
-        interpret, "zoo_flash_dq",
-        grid=(bn, blocks_q, steps_k),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j, t: (i, j, 0)),
+            _dq_kernel, sched=sched_q, **static), has_bias, n_in=9),
+        "zoo_flash_dq", sched_q, bn, dq_specs,
+        out_specs=pl.BlockSpec((1, block_q, d), q_blk),
         out_shape=jax.ShapeDtypeStruct((bn, s_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-    )(*dq_ops)
+        operands=dq_ops)
 
-    # dk/dv/dbias: grid (key-value rows, k-block, group x q-block) — K/V
-    # resident across the sequential axis, Q/dO/lse/delta of each of the
-    # group's query heads streamed block-by-block
-    def qrow(i, t):
-        return i * group + t // steps_q
-
-    def qblk(j, t):
-        return qs(j, t % steps_q)
-
+    # dk/dv/dbias: a row is a key-value head; K/V resident across a K block's
+    # steps, Q/dO/lse/delta of each of the group's query heads streamed
+    q_blk, q_vec, kv_blk, k_vec = _index_maps(sched_k, "k", group)
     dkv_specs = [
-        pl.BlockSpec((1, block_q, d),
-                     lambda i, j, t: (qrow(i, t), qblk(j, t), 0)),
-        pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, j, 0)),
-        pl.BlockSpec((1, block_k, dv_dim), lambda i, j, t: (i, j, 0)),
-        pl.BlockSpec((1, block_q, dv_dim),
-                     lambda i, j, t: (qrow(i, t), qblk(j, t), 0)),
-        pl.BlockSpec((1, 1, block_q),
-                     lambda i, j, t: (qrow(i, t), 0, qblk(j, t))),
-        pl.BlockSpec((1, 1, block_q),
-                     lambda i, j, t: (qrow(i, t), 0, qblk(j, t))),
+        pl.BlockSpec((1, block_q, d), q_blk),
+        pl.BlockSpec((1, block_k, d), kv_blk),
+        pl.BlockSpec((1, block_k, dv_dim), kv_blk),
+        pl.BlockSpec((1, block_q, dv_dim), q_blk),
+        pl.BlockSpec((1, 1, block_q), q_vec),
+        pl.BlockSpec((1, 1, block_q), q_vec),
     ]
     dkv_ops = [q, k, v, g, lse, delta]
     if has_bias:
-        dkv_specs.append(
-            pl.BlockSpec((1, 1, block_k), lambda i, j, t: (i, 0, j)))
+        dkv_specs.append(pl.BlockSpec((1, 1, block_k), k_vec))
         dkv_ops.append(bias_flat)
     dk, dv, dbias = _pcall(
         _maybe_bias(functools.partial(
-            _dkv_kernel, scale=scale, causal=causal, blocks_q=blocks_q,
-            block_q=block_q, block_k=block_k, causal_offset=s_k - s_q,
-            has_bias=has_bias, window=window, steps_q=steps_q, group=group),
-            has_bias, n_in=6),
-        interpret, "zoo_flash_dkv",
-        grid=(bn // group, blocks_k, group * steps_q),
-        in_specs=dkv_specs,
+            _dkv_kernel, sched=sched_k, **static), has_bias, n_in=9),
+        "zoo_flash_dkv", sched_k, bn // group, dkv_specs,
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda i, j, t: (i, j, 0)),
-            pl.BlockSpec((1, block_k, dv_dim), lambda i, j, t: (i, j, 0)),
-            pl.BlockSpec((1, 1, block_k), lambda i, j, t: (i, 0, j)),
+            pl.BlockSpec((1, block_k, d), kv_blk),
+            pl.BlockSpec((1, block_k, dv_dim), kv_blk),
+            pl.BlockSpec((1, 1, block_k), k_vec),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bn // group, s_k, d), k.dtype),
@@ -597,7 +646,7 @@ def _flash_backward(q, k, v, bias_flat, out, lse, g, scale: float,
             pltpu.VMEM((block_k, dv_dim), jnp.float32),
             pltpu.VMEM((1, block_k), jnp.float32),
         ],
-    )(*dkv_ops)
+        operands=dkv_ops)
     return dq, dk, dv, (dbias if has_bias else None)
 
 
@@ -666,6 +715,13 @@ def _validate(q, k, scale, block_q: int, block_k: int, causal=True,
         raise NotImplementedError(f"seq lens must tile ({block_q},{block_k})")
     if q.shape[-1] > 256:
         raise NotImplementedError("head_dim > 256")
+    live = _live_blocks(causal, window, s_q, s_k, block_q, block_k)
+    steps = int(live.sum())
+    if steps > _MAX_STEPS and not live.all():
+        raise NotImplementedError(
+            f"{steps} live ({block_q},{block_k}) blocks: the schedule's "
+            f"tables would not fit SMEM (at most {_MAX_STEPS}); larger "
+            f"blocks would")
     return scale
 
 
@@ -679,12 +735,12 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     key-value head h // (heads / key-value heads). bias additive,
     broadcastable to (batch, heads, 1, s_k) (padding-mask layout).
     ``window`` (with ``causal``): a query sees only the ``window`` newest of
-    its causal keys, itself included; blocks wholly outside are neither
-    computed nor fetched, and the kernels' innermost axis walks only the
-    blocks a window can touch. Raises NotImplementedError for unsupported
-    shapes/bias so the dispatcher in ops.attention falls back to the XLA
-    reference implementation. ``block_q``/``block_k`` override the
-    seq-aware default tile sizes per call."""
+    its causal keys, itself included. The kernels' grids hold the live
+    blocks only (``_schedule``): a block wholly above the diagonal or
+    outside the window is no grid step. Raises NotImplementedError for
+    unsupported shapes/bias so the dispatcher in ops.attention falls back
+    to the XLA reference implementation. ``block_q``/``block_k`` override
+    the seq-aware default tile sizes per call."""
     block_q, block_k = _resolve_blocks(block_q, block_k,
                                        q.shape[2], k.shape[2])
     scale = _validate(q, k, scale, block_q, block_k, causal, window, bias)
@@ -721,7 +777,7 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
     delta term)."""
     block_q, block_k = _resolve_blocks(block_q, block_k,
                                        q.shape[2], k.shape[2])
-    scale = _validate(q, k, scale, block_q, block_k)
+    scale = _validate(q, k, scale, block_q, block_k, causal)
     b, n, s_q, d = q.shape
     s_k = k.shape[2]
     bn = b * n

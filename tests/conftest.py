@@ -107,3 +107,15 @@ def max_ulp(a, b):
     ia = np.asarray(a, np.float32).view(np.int32).astype(np.int64)
     ib = np.asarray(b, np.float32).view(np.int32).astype(np.int64)
     return int(np.max(np.abs(ia - ib)))
+
+
+def inner_jaxprs(eqn):
+    """The jaxprs an equation holds (a kernel's body aside)."""
+    from jax._src import core
+
+    if eqn.primitive.name == "pallas_call":
+        return
+    for v in eqn.params.values():
+        for u in (v if isinstance(v, (tuple, list)) else (v,)):
+            if isinstance(u, (core.ClosedJaxpr, core.Jaxpr)):
+                yield getattr(u, "jaxpr", u)

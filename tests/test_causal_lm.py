@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from benchmark.reference import optim, trinity as ref
+from conftest import inner_jaxprs
 
 CFG = {
     "hidden_size": 64, "head_dim": 16, "num_attention_heads": 4,
@@ -208,18 +209,6 @@ def test_window_and_grouped_heads_in_the_flash_kernels(window, heads, blocks):
         _close(a, b, 2e-5)
 
 
-def _inner_jaxprs(eqn):
-    """The jaxprs an equation holds (a kernel's body aside)."""
-    from jax._src import core
-
-    if eqn.primitive.name == "pallas_call":
-        return
-    for v in eqn.params.values():
-        for u in (v if isinstance(v, (tuple, list)) else (v,)):
-            if isinstance(u, (core.ClosedJaxpr, core.Jaxpr)):
-                yield getattr(u, "jaxpr", u)
-
-
 def _kernel_calls(jaxpr, counts=None):
     """Every `pallas_call` of a jaxpr by its kernel's name, nested ones too."""
     counts = {} if counts is None else counts
@@ -227,7 +216,7 @@ def _kernel_calls(jaxpr, counts=None):
         if eqn.primitive.name == "pallas_call":
             name = eqn.params["name"]
             counts[name] = counts.get(name, 0) + 1
-        for inner in _inner_jaxprs(eqn):
+        for inner in inner_jaxprs(eqn):
             _kernel_calls(inner, counts)
     return counts
 
@@ -510,7 +499,7 @@ def test_rows_past_the_held_total_are_never_read(chunks_of_64, monkeypatch):
 def _has_cond(jaxpr) -> bool:
     """A `cond` among the layer's own operations (a kernel's body aside)."""
     return any(e.primitive.name == "cond"
-               or any(map(_has_cond, _inner_jaxprs(e))) for e in jaxpr.eqns)
+               or any(map(_has_cond, inner_jaxprs(e))) for e in jaxpr.eqns)
 
 
 @pytest.mark.parametrize("held,n_experts,k,tokens,compacted", [

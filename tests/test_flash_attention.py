@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import inner_jaxprs
 
 from analytics_zoo_tpu.ops.attention import (_reference_attention,
                                              scaled_dot_product_attention)
@@ -36,27 +37,37 @@ def _padding_bias(key, s_k=S):
     return (1.0 - mask[:, None, None, :]) * -1e9
 
 
-def _check_fwd_and_grads(q, k, v, bias, causal, called=lambda f: f):
+def _check_fwd_and_grads(q, k, v, bias, causal, called=lambda f: f,
+                         window=None):
     scale = D ** -0.5
 
     def flash(*arrays, bias=None):
         # `called` sees arrays only: the mask flag and the scale stay static
         return called(lambda *a, bias: flash_attention(
-            *a, bias=bias, causal=causal, scale=scale))(*arrays, bias=bias)
+            *a, bias=bias, causal=causal, scale=scale,
+            window=window))(*arrays, bias=bias)
 
+    def reference(q_, k_, v_, b_):
+        return _reference_attention(q_, k_, v_, b_, causal, scale,
+                                    window=window)
+
+    # a causal query before the first key (s_q > s_k) sees none: the kernels
+    # give it 0, the reference the mean of v; nobody reads either
+    s_q, s_k = q.shape[2], k.shape[2]
+    seen = (jnp.arange(s_q) >= (s_q - s_k if causal else 0))[:, None]
     out_f = flash(q, k, v, bias=bias)
-    out_r = _reference_attention(q, k, v, bias, causal, scale)
-    np.testing.assert_allclose(out_f, out_r, **TOL)
+    out_r = reference(q, k, v, bias)
+    np.testing.assert_allclose(out_f * seen, out_r * seen, **TOL)
 
-    g = jax.random.normal(jax.random.PRNGKey(9), out_r.shape, jnp.float32)
+    g = jax.random.normal(jax.random.PRNGKey(9), out_r.shape,
+                          jnp.float32) * seen
 
     if bias is None:
         def loss_f(q_, k_, v_):
             return jnp.vdot(flash(q_, k_, v_), g)
 
         def loss_r(q_, k_, v_):
-            return jnp.vdot(_reference_attention(q_, k_, v_, None, causal,
-                                                 scale), g)
+            return jnp.vdot(reference(q_, k_, v_, None), g)
         grads_f = jax.grad(loss_f, argnums=(0, 1, 2))(q, k, v)
         grads_r = jax.grad(loss_r, argnums=(0, 1, 2))(q, k, v)
     else:
@@ -64,8 +75,7 @@ def _check_fwd_and_grads(q, k, v, bias, causal, called=lambda f: f):
             return jnp.vdot(flash(q_, k_, v_, bias=b_), g)
 
         def loss_r(q_, k_, v_, b_):
-            return jnp.vdot(_reference_attention(q_, k_, v_, b_, causal,
-                                                 scale), g)
+            return jnp.vdot(reference(q_, k_, v_, b_), g)
         grads_f = jax.grad(loss_f, argnums=(0, 1, 2, 3))(q, k, v, bias)
         grads_r = jax.grad(loss_r, argnums=(0, 1, 2, 3))(q, k, v, bias)
 
@@ -331,50 +341,185 @@ def test_auto_dispatch_regime_guard(monkeypatch):
     assert att._auto_use_flash(arr(bf16, 2176), arr(bf16, 2176))
 
 
-def test_stream_clamps():
-    """The causal DMA clamps must keep every live step's index unchanged
-    and pin dead steps inside the live range (so the pipeline revisits a
-    fetched block instead of copying dead ones)."""
-    from analytics_zoo_tpu.ops.flash_attention import (_causal_block_live,
-                                                       _stream_clamps)
-
-    bq = bk = 128
-    for s_q, s_k in ((512, 512), (384, 640), (640, 640)):
-        off = s_k - s_q
-        nq, nk = s_q // bq, s_k // bk
-        ks, qs = _stream_clamps(True, bq, bk, off, nq, nk)
-        for j in range(nq):
-            for t in range(nk):
-                c = int(ks(j, t))
-                assert 0 <= c < nk
-                if _causal_block_live(j, t, bq, bk, off):
-                    assert c == t, (s_q, s_k, j, t)  # live: untouched
-                else:
-                    # dead: clamped to the row's last live block
-                    assert _causal_block_live(j, c, bq, bk, off)
-        for j in range(nk):
-            for t in range(nq):
-                c = int(qs(j, t))
-                assert 0 <= c < nq
-                if _causal_block_live(t, j, bq, bk, off):
-                    assert c == t
-                else:
-                    assert _causal_block_live(c, j, bq, bk, off)
-    # non-causal: identity
-    ks, qs = _stream_clamps(False, bq, bk, 0, 4, 4)
-    assert ks(2, 3) == 3 and qs(1, 2) == 2
+def _brute_force_live(s_q, s_k, block_q, block_k, window):
+    """Every (query, key) pair of the causal mask, then by block: live where
+    any pair is seen."""
+    q_pos = np.arange(s_q)[:, None] + s_k - s_q
+    k_pos = np.arange(s_k)[None, :]
+    seen = q_pos >= k_pos
+    if window is not None:
+        seen &= q_pos - k_pos < window
+    return seen.reshape(s_q // block_q, block_q,
+                        s_k // block_k, block_k).any((1, 3))
 
 
-def test_flash_cross_lengths_causal_multiblock():
-    # several blocks on BOTH axes with s_q != s_k: exercises the clamp
-    # ranges end-to-end through fwd and both backward kernels
-    q, k, v = _qkv(jax.random.PRNGKey(7), s_q=384, s_k=640)
-    _check_fwd_and_grads(q, k, v, None, causal=True)
+@pytest.mark.parametrize("lens,blocks,window", [
+    ((512, 512), (128, 128), None),
+    ((512, 512), (128, 256), 130),
+    ((512, 512), (256, 128), 1),
+    ((512, 512), (128, 128), 2048),
+    ((384, 640), (128, 128), None),
+    ((384, 640), (128, 128), 200),
+    ((384, 640), (128, 128), 256),
+    ((640, 640), (128, 128), None),
+    ((640, 640), (128, 128), 130),
+    ((640, 640), (128, 128), 1),
+    ((1024, 1024), (128, 256), None),
+    ((1024, 1024), (256, 128), 200),
+    ((1024, 1024), (128, 256), 256),
+    ((1024, 1024), (256, 128), 2048),
+    ((640, 384), (128, 128), None),     # queries before the first key
+    ((640, 384), (128, 128), 130),
+])
+def test_the_block_schedule_against_a_brute_force_mask(lens, blocks, window):
+    """The schedule is the one place that knows which blocks are live: they
+    are the brute-force mask's, every one is a step exactly once (in dk/dv
+    once for each head of a group, heads innermost), the streamed index
+    ascends within a resident block, the first / last flags bracket it, and
+    a resident block with no live partner keeps one step that is not live."""
+    from analytics_zoo_tpu.ops import flash_attention as fa
+
+    (s_q, s_k), (block_q, block_k) = lens, blocks
+    want = _brute_force_live(s_q, s_k, block_q, block_k, window)
+    np.testing.assert_array_equal(
+        fa._live_blocks(True, window, s_q, s_k, block_q, block_k), want)
+    for resident, heads in (("q", 1), ("k", 1), ("k", 3)):
+        sched = fa._schedule(True, window, s_q, s_k, block_q, block_k,
+                             resident=resident, heads=heads)
+        assert all(t.dtype == np.int32 for t in sched.tables)
+        assert not sched.direct
+        assert (sched.blocks, sched.steps) == (1, sched.flags.size)
+        steps = iter([(j, t) for j in range(sched.blocks)
+                      for t in range(sched.steps * heads)])
+        for r, row in enumerate(want if resident == "q" else want.T):
+            live = np.flatnonzero(row).tolist()
+            partners = [(c, h) for c in live or [0] for h in range(heads)]
+            for n, (c, h) in enumerate(partners):
+                j, t = next(steps)
+                word = int(sched.flags[j * sched.steps + sched.step(t)])
+                assert sched.resident_block(j, t, sched.resident) == r
+                assert sched.streamed_block(t, sched.streamed) == c
+                assert sched.head(t) == h
+                assert bool(word & fa._LIVE) == bool(live)
+                # first / last on the block's entries: the kernels narrow
+                # them to a group's first / last head
+                assert bool(word & fa._FIRST) == (n < heads)
+                assert bool(word & fa._LAST) == (n >= len(partners) - heads)
+        assert next(steps, None) is None
+
+
+@pytest.mark.parametrize("causal,lens,grid", [
+    (False, (384, 512, 128, 256), (3, 2)),  # BERT's path, ring attention's
+    (True, (128, 512, 128, 128), (1, 4)),   # the newest queries of a prefix
+    (True, (384, 512, 128, 256), (1, 5)),   # rows of 1, 2, 2 live blocks
+])
+def test_a_schedule_with_no_dead_block_is_walked_directly(causal, lens, grid):
+    """Where every block is live (not causal: the whole rectangle,
+    row-major) the grid's axes are the resident and the streamed blocks
+    themselves and no table is read; where one is dead the live ones lie end
+    to end on the inner axis."""
+    from analytics_zoo_tpu.ops import flash_attention as fa
+
+    sched = fa._schedule(causal, None, *lens)
+    assert (sched.blocks, sched.steps) == grid
+    assert sched.direct == (lens[:2] != (384, 512) or not causal)
+    if sched.direct:
+        assert [(sched.resident_block(j, t, None),
+                 sched.streamed_block(t, None))
+                for j in range(grid[0]) for t in range(grid[1])] == [
+            (j, t) for j in range(grid[0]) for t in range(grid[1])]
+        assert all(t.size == 1 for t in sched.tables)
+
+
+def _pallas_calls(jaxpr, found=None):
+    """{kernel name: [grid, ...]} of every `pallas_call` in a jaxpr."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.setdefault(eqn.params["name"], []).append(
+                tuple(eqn.params["grid_mapping"].grid))
+        for inner in inner_jaxprs(eqn):
+            _pallas_calls(inner, found)
+    return found
+
+
+def _traced_grad(shape, kv_heads, window, monkeypatch, causal=True, **blocks):
+    """The jaxpr of the kernels' forward and backward at ``shape`` (traced
+    from shapes: nothing runs)."""
+    monkeypatch.delenv("AZOO_FLASH_BLOCK_Q", raising=False)
+    monkeypatch.delenv("AZOO_FLASH_BLOCK_K", raising=False)
+    b, n, s, d = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, kv_heads, s, d), jnp.bfloat16)
+
+    def loss(q_, k_, v_):
+        return jnp.sum(flash_attention(q_, k_, v_, causal=causal,
+                                       window=window,
+                                       **blocks).astype(jnp.float32))
+
+    return jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv).jaxpr
+
+
+@pytest.mark.parametrize("shape,kv_heads,causal,window,walk", [
+    ((1, 32, 32768, 64), 8, True, None, (1, 2080)),  # lfm2-24b-a2b.fit-seq32k
+    ((2, 32, 8192, 128), 4, True, None, (1, 136)),   # trinity-mini.fit-seq8k
+    ((2, 32, 8192, 128), 4, True, 2048, (1, 70)),    # ... its window layers
+    ((8, 12, 2048, 64), 12, False, None, (4, 4)),    # BERT-base widths
+])
+def test_the_kernels_grids_hold_the_live_blocks_only(shape, kv_heads, causal,
+                                                     window, walk,
+                                                     monkeypatch):
+    """At the two decoder cells' shapes a grid is rows x the live 512 x 512
+    blocks of a head on one axis: a dead block is no grid step. dk/dv's rows
+    are the key-value heads, each step once for every query head of the
+    group. Not causal the grid is the rectangle it was."""
+    b, n = shape[:2]
+    blocks, steps = walk
+    grids = _pallas_calls(_traced_grad(shape, kv_heads, window, monkeypatch,
+                                       causal=causal))
+    assert grids == {
+        "zoo_flash_fwd": [(b * n, blocks, steps)],
+        "zoo_flash_dq": [(b * n, blocks, steps)],
+        "zoo_flash_dkv": [(b * kv_heads, blocks, n // kv_heads * steps)]}
+
+
+@pytest.mark.parametrize("s,causal,fits", [
+    (32768, True, True),      # 32 896 steps: blocks pinned to 128 at LFM2's
+    (65536, True, False),     # 131 328
+    (65536, False, True),     # not causal: walked directly, no table
+])
+def test_a_schedule_too_long_for_smem_is_refused(s, causal, fits):
+    """The schedule's three int32 tables are prefetched into SMEM, 1 MiB on
+    a v5e: a shape whose tables would not fit there is outside the kernels'
+    support (larger blocks bring it back)."""
+    q = jax.ShapeDtypeStruct((1, 1, s, 64), jnp.bfloat16)
+
+    def build():
+        return jax.eval_shape(lambda q_: flash_attention(
+            q_, q_, q_, causal=causal, block_q=128, block_k=128), q)
+
+    if fits:
+        assert build().shape == q.shape
+    else:
+        with pytest.raises(NotImplementedError, match="SMEM"):
+            build()
+
+
+@pytest.mark.parametrize("s_q,s_k,window", [
+    (384, 640, None),
+    (640, 384, None),   # query blocks before the first key: steps not live
+    (384, 640, 130),    # key blocks older than every window: the same, dk/dv
+])
+def test_flash_cross_lengths_causal_multiblock(s_q, s_k, window):
+    # several blocks on BOTH axes with s_q != s_k: exercises the schedule
+    # end-to-end through fwd and both backward kernels
+    q, k, v = _qkv(jax.random.PRNGKey(7), s_q=s_q, s_k=s_k)
+    _check_fwd_and_grads(q, k, v, None, causal=True, window=window)
 
 
 def test_flash_bias_causal_grad():
     # padding-mask bias UNDER the causal mask: the bias BlockSpec streams
-    # through the same clamped index maps as K/V in all three kernels
+    # through the same scheduled index maps as K/V in all three kernels
     q, k, v = _qkv(jax.random.PRNGKey(8), s_q=256, s_k=384)
     bias = jnp.where(
         jax.random.bernoulli(jax.random.PRNGKey(9), 0.8, (B, N, 1, 384)),
