@@ -1278,8 +1278,10 @@ def lm_train_metrics() -> Dict[str, Any]:
     and those whose held assignments went through in the one compacted pass,
     as the step itself reported), ``conv_token_layers`` (counter
     ``zoo_lm_conv_token_layers_total``: tokens times short-convolution
-    layers of training steps, the mixer's work as it ran). One call per
-    model — the model holds the children."""
+    layers of training steps, the mixer's work as it ran),
+    ``latent_token_layers`` (counter ``zoo_lm_latent_token_layers_total``:
+    the same for latent-attention layers). One call per model — the model
+    holds the children."""
     reg = get_registry()
     assignments = reg.counter(
         "zoo_moe_assignments_total",
@@ -1310,6 +1312,10 @@ def lm_train_metrics() -> Dict[str, Any]:
             "zoo_lm_conv_token_layers_total",
             "Tokens times gated short-convolution layers a language model's "
             "train steps computed.").labels(),
+        "latent_token_layers": reg.counter(
+            "zoo_lm_latent_token_layers_total",
+            "Tokens times latent-attention layers a language model's train "
+            "steps computed.").labels(),
     }
 
 

@@ -1,15 +1,17 @@
 """Decoder block library: the parts of today's open decoder-only language
 models, as layers.
 
-``RMSNorm``; ``rotary_embedding`` (a function: it has no weights); two token
+``RMSNorm``; ``rotary_embedding`` (a function: it has no weights); three token
 mixers, ``GroupedQueryAttention`` (key-value heads fewer than query heads,
 optional per-head RMS norm of q and k, optional sigmoid output gate, causal, a
-sliding window or full, rotary positions or none) and ``GatedShortConv`` (a
-gated causal convolution over a few neighbouring tokens, no attention at
-all); ``SwiGLU``; and ``DecoderBlock``, which wires one mixer and either a
-dense ``SwiGLU`` or a ``SparseMoE`` (keras/layers/moe.py) under one of two
-norm layouts, four norms a layer (``"sandwich"``) or the two pre-norms alone
-(``"pre"``):
+sliding window or full, rotary positions or none), ``LatentAttention`` (keys
+and values of every head from one low-rank latent a token, one rotary key
+shared by all heads, queries and keys wider than values) and
+``GatedShortConv`` (a gated causal convolution over a few neighbouring tokens,
+no attention at all); ``SwiGLU``; and ``DecoderBlock``, which wires one mixer
+and either a dense ``SwiGLU`` or a ``SparseMoE`` (keras/layers/moe.py) under
+one of two norm layouts, four norms a layer (``"sandwich"``) or the two
+pre-norms alone (``"pre"``):
 
     h += post_attn_norm(mixer(in_norm(h)))      |  h += mixer(in_norm(h))
     h += post_mlp_norm(mlp(pre_mlp_norm(h)))    |  h += mlp(pre_mlp_norm(h))
@@ -43,20 +45,39 @@ def rms_norm(x, gain, eps: float):
     return (y * gain.astype(jnp.float32)).astype(x.dtype)
 
 
-def rotary_embedding(x, theta: float = 10000.0, positions=None):
-    """Rotary position embedding (Su et al. 2021) in the half-rotated form:
-    x (..., seq, head_dim); position t of a row is t unless ``positions``
-    (seq,) says otherwise. Angles in float32, the result in x's dtype."""
+def rotary_embedding(x, theta: float = 10000.0, positions=None,
+                     interleaved: bool = False):
+    """Rotary position embedding (Su et al. 2021): x (..., seq, head_dim);
+    position t of a row is t unless ``positions`` (seq,) says otherwise.
+    Frequency i turns one pair of x's entries by ``t / theta^(2i / head_dim)``:
+    in the half-rotated form the pair ``(x[i], x[i + head_dim / 2])``,
+    ``interleaved`` the neighbours ``(x[2i], x[2i + 1])``. Angles in float32,
+    the result in x's dtype."""
     s, hd = x.shape[-2], x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
     pos = (jnp.arange(s, dtype=jnp.float32) if positions is None
            else positions.astype(jnp.float32))
     ang = pos[:, None] * inv[None]
-    ang = jnp.concatenate([ang, ang], axis=-1)
     xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., : hd // 2], xf[..., hd // 2:]
-    rotated = jnp.concatenate([-x2, x1], axis=-1)
+    if interleaved:
+        ang = jnp.repeat(ang, 2, axis=-1)
+        pairs = xf.reshape(*xf.shape[:-1], hd // 2, 2)
+        rotated = jnp.stack([-pairs[..., 1], pairs[..., 0]],
+                            axis=-1).reshape(xf.shape)
+    else:
+        ang = jnp.concatenate([ang, ang], axis=-1)
+        x1, x2 = xf[..., : hd // 2], xf[..., hd // 2:]
+        rotated = jnp.concatenate([-x2, x1], axis=-1)
     return (xf * jnp.cos(ang) + rotated * jnp.sin(ang)).astype(x.dtype)
+
+
+def _flash_residuals() -> Tuple[str, ...]:
+    """The names an attention mixer's half keeps under a block's checkpoint.
+    Imported here like every use of the kernels' module: it brings Pallas, a
+    second of import that a model on the XLA path skips."""
+    from analytics_zoo_tpu.ops.flash_attention import FLASH_RESIDUALS
+
+    return FLASH_RESIDUALS
 
 
 def _cast(params, dtype):
@@ -133,11 +154,7 @@ class GroupedQueryAttention(KerasLayer):
         """What a block's rematerialised mixer half keeps of this mixer: what
         only the flash kernel can make, its output and log-sum-exp (on the
         XLA path, short rows, the names are nowhere and nothing is kept)."""
-        # imported here like every use of the kernels' module: it brings
-        # Pallas, a second of import that a model on the XLA path skips
-        from analytics_zoo_tpu.ops.flash_attention import FLASH_RESIDUALS
-
-        return FLASH_RESIDUALS
+        return _flash_residuals()
 
     def build(self, input_shape: Shape):
         d, init = input_shape[-1], DECODER_INIT
@@ -173,6 +190,90 @@ class GroupedQueryAttention(KerasLayer):
         if self.gated:
             o = o * jax.nn.sigmoid(parts[3].astype(jnp.float32)).astype(o.dtype)
         return o @ params["w_out"]
+
+
+class LatentAttention(KerasLayer):
+    """Causal multi-head latent attention over (B, S, d) with no query
+    compression (DeepSeek-V2's MLA, ``q_lora_rank`` null): a token's keys and
+    values of all ``n_head`` heads come from one latent ``kv_rank`` wide, and
+    the key's rotary part is ONE head ``qk_rope_dim`` wide that every query
+    head shares.
+
+        q  = u W_q                    (d -> H x (nope + rope))    [q_nope | q_rope] a head
+        a  = u W_kv_a                 (d -> kv_rank + rope)       [c | k_rope]
+        kv = rms(c; kv_norm) W_kv_b   (kv_rank -> H x (nope + v)) [k_nope | v] a head
+        q_h = [q_nope_h | rot(q_rope_h)],  k_h = [k_nope_h | rot(k_rope)]
+        o_h = softmax(q_h k_h^T / sqrt(nope + rope) + causal) v_h
+        y  = [o_1 .. o_H] W_out       (H x v -> d)
+
+    ``rot`` is the rotary embedding over neighbouring pairs. Queries and keys are ``qk_nope_dim +
+    qk_rope_dim`` wide, values ``v_dim``: the flash kernels take the two
+    widths apart. The shared key is broadcast to the heads before the kernel,
+    so its gradient is the sum over heads. No biases. Everything between the
+    block's norm and the kernel's operands runs under the scope
+    ``attn.latent``, the kernel call under ``attn.full`` (it is full causal
+    attention), ``W_out`` under neither."""
+
+    block_key = "attn"
+
+    def __init__(self, n_head: int, qk_nope_dim: int, qk_rope_dim: int,
+                 v_dim: int, kv_rank: int, rope_theta: float = 10000.0,
+                 epsilon: float = 1e-6, input_shape=None, name=None):
+        super().__init__(input_shape, name or unique_name("latent_attn"))
+        if qk_rope_dim % 2:
+            raise ValueError(f"rotary pairs over {qk_rope_dim} entries")
+        self.n_head, self.v_dim, self.kv_rank = n_head, v_dim, kv_rank
+        self.qk_nope_dim, self.qk_rope_dim = qk_nope_dim, qk_rope_dim
+        self.rope_theta, self.epsilon = rope_theta, epsilon
+
+    @property
+    def qk_dim(self) -> int:
+        """A head's width in queries and keys: the part with no position
+        beside the rotary part."""
+        return self.qk_nope_dim + self.qk_rope_dim
+
+    def kept_residuals(self) -> Tuple[str, ...]:
+        """As ``GroupedQueryAttention``: the flash kernel's output and
+        log-sum-exp; the projections, the latent norm, rotary and the
+        broadcast are recomputed."""
+        return _flash_residuals()
+
+    def build(self, input_shape: Shape):
+        d, init, h = input_shape[-1], DECODER_INIT, self.n_head
+        self.add_weight("w_q", (d, h * self.qk_dim), init)
+        self.add_weight("w_kv_a", (d, self.kv_rank + self.qk_rope_dim), init)
+        self.add_weight("kv_norm", (self.kv_rank,), "ones")
+        self.add_weight("w_kv_b",
+                        (self.kv_rank, h * (self.qk_nope_dim + self.v_dim)),
+                        init)
+        self.add_weight("w_out", (h * self.v_dim, d), init)
+
+    def call(self, params, x, **kw):
+        b, s, _ = x.shape
+        h, nope = self.n_head, self.qk_nope_dim
+
+        def heads(t):
+            return t.reshape(b, s, h, -1).transpose(0, 2, 1, 3)
+
+        def rot(t):
+            return rotary_embedding(t, self.rope_theta, interleaved=True)
+
+        with jax.named_scope("attn.latent"):
+            q = heads(x @ params["w_q"])
+            c, k_rope = jnp.split(x @ params["w_kv_a"], [self.kv_rank],
+                                  axis=-1)
+            kv = heads(rms_norm(c, params["kv_norm"], self.epsilon)
+                       @ params["w_kv_b"])
+            k_nope, v = kv[..., :nope], kv[..., nope:]
+            q = jnp.concatenate([q[..., :nope], rot(q[..., nope:])], axis=-1)
+            k_rope = jnp.broadcast_to(rot(k_rope)[:, None],
+                                      (b, h, s, self.qk_rope_dim))
+            k = jnp.concatenate([k_nope, k_rope], axis=-1)
+        with jax.named_scope("attn.full"):
+            o = scaled_dot_product_attention(q, k, v, causal=True,
+                                             scale=self.qk_dim ** -0.5)
+        return o.transpose(0, 2, 1, 3).reshape(b, s, h * self.v_dim) @ params[
+            "w_out"]
 
 
 class GatedShortConv(KerasLayer):
@@ -215,7 +316,8 @@ class GatedShortConv(KerasLayer):
 
 class DecoderBlock(KerasLayer):
     """One decoder layer (see the module docstring). ``attn``: the token
-    mixer, a ``GroupedQueryAttention`` or a ``GatedShortConv``; ``mlp``: a
+    mixer, a ``GroupedQueryAttention``, a ``LatentAttention`` or a
+    ``GatedShortConv``; ``mlp``: a
     built-or-unbuilt ``SwiGLU`` or ``SparseMoE``. ``norms``: ``"sandwich"``,
     a norm before and after each half, or ``"pre"``, the two before alone.
     ``dtype``: the compute type its weights and input are cast to inside the
@@ -245,7 +347,8 @@ class DecoderBlock(KerasLayer):
         self.mixer, self.mlp, self.norms = attn, mlp, norms
         # the attention mixer under the name it has always had; None on a
         # layer that has none
-        self.attn = attn if isinstance(attn, GroupedQueryAttention) else None
+        self.attn = (attn if isinstance(
+            attn, (GroupedQueryAttention, LatentAttention)) else None)
         self.epsilon, self.remat = epsilon, remat
         self.dtype = None if dtype is None else jnp.dtype(dtype)
         self.has_state = bool(getattr(mlp, "has_state", False))

@@ -1,9 +1,9 @@
 """Causal decoder-only language model over the decoder block library
 (keras/layers/decoder.py, keras/layers/moe.py): token embedding, a stack of
 ``DecoderBlock``s whose token mixer (``sliding_attention`` /
-``full_attention`` / ``conv``) and feed-forward kind (leading dense layers,
-then expert layers) come from the configuration, a final RMS norm and a
-head, its own or the embedding's transpose.
+``full_attention`` / ``latent_attention`` / ``conv``) and feed-forward kind
+(leading dense layers, then expert layers) come from the configuration, a
+final RMS norm and a head, its own or the embedding's transpose.
 ``apply`` ends in logits over the vocabulary held here, in the compute type;
 train it with ``compile(optimizer, loss="token_crossentropy_from_logits")``
 and ``Estimator.train`` / ``fit`` like any other ``KerasNet``.
@@ -26,17 +26,36 @@ from analytics_zoo_tpu.keras.engine.base import unique_name
 from analytics_zoo_tpu.keras.engine.topology import KerasNet
 from analytics_zoo_tpu.keras.layers.core import Dense
 from analytics_zoo_tpu.keras.layers.decoder import (
-    DecoderBlock, GatedShortConv, GroupedQueryAttention, RMSNorm, SwiGLU,
-    rms_norm,
+    DecoderBlock, GatedShortConv, GroupedQueryAttention, LatentAttention,
+    RMSNorm, SwiGLU, rms_norm,
 )
 from analytics_zoo_tpu.keras.layers.embeddings import Embedding
 from analytics_zoo_tpu.keras.layers.moe import DECODER_INIT, SparseMoE
+
+
+def _experts(cfg: Dict, held_key: str) -> Dict:
+    """The experts of one chip's share: ``held_key`` counts those held here,
+    numbered from ``experts_held_offset``, of the ``router_num_experts`` the
+    router scores (where the file states no such width, all are held)."""
+    held = cfg[held_key]
+    return dict(n_experts=cfg.get("router_num_experts", held),
+                experts_held=(cfg.get("experts_held_offset", 0), held))
+
+
+def _listed_layers(cfg: Dict) -> Dict:
+    """What ``afmoe`` and ``lfm2_moe`` name alike: a mixer a layer, the
+    leading dense layers, grouped key-value heads, the experts held."""
+    return dict(layer_types=cfg["layer_types"],
+                num_dense_layers=cfg["num_dense_layers"],
+                n_kv_head=cfg["num_key_value_heads"],
+                **_experts(cfg, "num_experts"))
 
 
 def _afmoe(cfg: Dict) -> Dict:
     """Trinity: four norms a layer, a gated attention whose full layers
     carry no position, one shared expert, a muP embedding, its own head."""
     return dict(
+        **_listed_layers(cfg),
         head_dim=cfg["head_dim"], n_shared=cfg["num_shared_experts"],
         sliding_window=cfg["sliding_window"], rope_theta=cfg["rope_theta"],
         epsilon=cfg["rms_norm_eps"],
@@ -53,6 +72,7 @@ def _lfm2_moe(cfg: Dict) -> Dict:
     where the file does not say). ``bias_rate`` is the training
     framework's, not the model file's: 0 leaves the bias at rest."""
     return dict(
+        **_listed_layers(cfg),
         head_dim=cfg["hidden_size"] // cfg["num_attention_heads"],
         n_shared=0, rope_theta=cfg["rope_parameters"]["rope_theta"],
         rope_full_layers=True, gated_attention=False,
@@ -65,18 +85,60 @@ def _lfm2_moe(cfg: Dict) -> Dict:
         tie_embeddings=cfg.get("tie_embeddings", True))
 
 
+# what `_deepseek_v3` does not build, by key: the only value it takes
+_DEEPSEEK_V3_ONLY = {"q_lora_rank": None, "n_group": 1, "topk_group": 1,
+                     "rope_scaling": None, "rope_interleave": True,
+                     "scoring_func": "sigmoid"}
+
+
+def _deepseek_v3(cfg: Dict) -> Dict:
+    """DeepSeek-V3's layout without query compression (Kanana-2): every layer
+    mixes by latent attention (no ``layer_types``: ``num_hidden_layers`` of
+    them), the two pre-norms alone, ``first_k_dense_replace`` leading dense
+    layers, then sigmoid top-k expert layers with a selection bias
+    (``noaux_tc``, one group) and ``n_shared_experts`` shared experts;
+    ``n_routed_experts`` counts the experts held here. ``bias_rate`` is the
+    training framework's, as for LFM2. Raises for what is not built: a
+    compressed query, grouped routing, scaled or half-rotated rotary,
+    softmax scores."""
+    for key, only in _DEEPSEEK_V3_ONLY.items():
+        if cfg.get(key, only) != only:
+            raise NotImplementedError(
+                f"deepseek_v3 with {key}={cfg[key]!r}: only {only!r} is built")
+    return dict(
+        layer_types=["latent_attention"] * cfg["num_hidden_layers"],
+        num_dense_layers=cfg["first_k_dense_replace"],
+        n_kv_head=cfg["num_key_value_heads"],
+        head_dim=cfg["qk_rope_head_dim"],
+        qk_nope_dim=cfg["qk_nope_head_dim"],
+        qk_rope_dim=cfg["qk_rope_head_dim"],
+        v_dim=cfg["v_head_dim"], kv_rank=cfg["kv_lora_rank"],
+        rope_theta=cfg["rope_theta"], epsilon=cfg["rms_norm_eps"],
+        **_experts(cfg, "n_routed_experts"),
+        n_shared=cfg["n_shared_experts"], norms="pre", embed_scale=1.0,
+        route_norm=cfg["norm_topk_prob"],
+        route_scale=cfg["routed_scaling_factor"], route_eps=1e-20,
+        bias_rate=cfg.get("bias_rate", 0.001),
+        tie_embeddings=cfg.get("tie_word_embeddings", False))
+
+
 # a published config's `model_type` -> the constructor's arguments that its
 # own key names give
-_FAMILIES = {"afmoe": _afmoe, "lfm2_moe": _lfm2_moe}
+_FAMILIES = {"afmoe": _afmoe, "lfm2_moe": _lfm2_moe,
+             "deepseek_v3": _deepseek_v3}
 
 
 class CausalLM(KerasNet):
     """See the module docstring. ``layer_types``: a layer's token mixer, one
     of ``"sliding_attention"`` (rotary positions, window ``sliding_window``),
     ``"full_attention"`` (causal; rotary positions where ``rope_full_layers``,
-    else no positional encoding) or ``"conv"`` (a ``GatedShortConv`` over
-    ``conv_kernel`` tokens); the first ``num_dense_layers`` layers get a
-    dense ``SwiGLU`` of ``dense_width``, the others a ``SparseMoE``.
+    else no positional encoding), ``"latent_attention"`` (a
+    ``LatentAttention`` of ``qk_nope_dim``, ``qk_rope_dim``, ``v_dim`` and
+    ``kv_rank``, rotary over neighbouring pairs;
+    ``n_kv_head`` and ``head_dim`` say nothing of it) or ``"conv"`` (a
+    ``GatedShortConv`` over ``conv_kernel`` tokens); the first
+    ``num_dense_layers`` layers get a dense ``SwiGLU`` of ``dense_width``,
+    the others a ``SparseMoE``.
     ``gated_attention``: the attention's sigmoid output gate. ``norms``: a
     block's norm layout (``DecoderBlock``). ``embed_scale``: the embedding's
     multiplier (sqrt(hidden) with muP). ``tie_embeddings``: the head is the
@@ -87,7 +149,8 @@ class CausalLM(KerasNet):
     them out with the loss and gives them back to ``record_train_stats`` at
     the drain."""
 
-    MIXERS = ("sliding_attention", "full_attention", "conv")
+    MIXERS = ("sliding_attention", "full_attention", "latent_attention",
+              "conv")
 
     def __init__(self, vocab_size: int, hidden_size: int,
                  layer_types: Sequence[str], n_head: int, n_kv_head: int,
@@ -100,7 +163,9 @@ class CausalLM(KerasNet):
                  bias_rate: float = 0.001, route_eps: float = 1e-20,
                  rope_full_layers: bool = False, gated_attention: bool = True,
                  conv_kernel: int = 3, norms: str = "sandwich",
-                 tie_embeddings: bool = False, seq_len: Optional[int] = None,
+                 tie_embeddings: bool = False, qk_nope_dim: int = 0,
+                 qk_rope_dim: int = 0, v_dim: int = 0, kv_rank: int = 0,
+                 seq_len: Optional[int] = None,
                  dtype: Optional[str] = "bfloat16", remat: bool = True,
                  name: Optional[str] = None):
         super().__init__(name or unique_name("causal_lm"))
@@ -121,6 +186,10 @@ class CausalLM(KerasNet):
             if kind == "conv":
                 mixer = GatedShortConv(conv_kernel,
                                        name=f"{self.name}_l{i}_conv")
+            elif kind == "latent_attention":
+                mixer = LatentAttention(
+                    n_head, qk_nope_dim, qk_rope_dim, v_dim, kv_rank,
+                    rope_theta, epsilon, name=f"{self.name}_l{i}_attn")
             else:
                 mixer = GroupedQueryAttention(
                     n_head, n_kv_head, head_dim,
@@ -140,6 +209,8 @@ class CausalLM(KerasNet):
             block.ensure_built((None, seq_len, hidden_size))
             self.blocks.append(block)
         self.conv_layers = sum(kind == "conv" for kind in layer_types)
+        self.latent_layers = sum(kind == "latent_attention"
+                                 for kind in layer_types)
         self.final_norm = RMSNorm(epsilon, name=self.name + "_final_norm")
         self.final_norm.ensure_built((None, seq_len, hidden_size))
         self.head = None
@@ -154,23 +225,19 @@ class CausalLM(KerasNet):
     @classmethod
     def from_config(cls, cfg: Dict, **kw) -> "CausalLM":
         """From a published config, by its ``model_type`` (``afmoe`` where it
-        states none): each family's own key names, plus ``router_num_experts``
-        (the router's width) where ``num_experts`` counts only the experts
-        held, from ``experts_held_offset``."""
+        states none): the keys every family names alike here, each family's
+        own in its function above, plus ``router_num_experts`` (the router's
+        width) where the family's count of experts (``num_experts``,
+        ``n_routed_experts``) counts only the experts held, from
+        ``experts_held_offset``."""
         kind = cfg.get("model_type", "afmoe")
         if kind not in _FAMILIES:
             raise ValueError(f"unknown model_type {kind!r}; known: "
                              f"{sorted(_FAMILIES)}")
-        held = cfg["num_experts"]
         shared = dict(
             vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
-            layer_types=cfg["layer_types"],
             n_head=cfg["num_attention_heads"],
-            n_kv_head=cfg["num_key_value_heads"],
             dense_width=cfg["intermediate_size"],
-            num_dense_layers=cfg["num_dense_layers"],
-            n_experts=cfg.get("router_num_experts", held),
-            experts_held=(cfg.get("experts_held_offset", 0), held),
             expert_width=cfg["moe_intermediate_size"],
             top_k=cfg["num_experts_per_tok"])
         return cls(**shared, **_FAMILIES[kind](cfg), **kw)
@@ -241,6 +308,9 @@ class CausalLM(KerasNet):
         obs["tokens"].inc(float(stats["tokens"]))
         # the step's tokens went through every short convolution the model has
         obs["conv_token_layers"].inc(float(stats["tokens"]) * self.conv_layers)
+        # and through every latent-attention layer
+        obs["latent_token_layers"].inc(
+            float(stats["tokens"]) * self.latent_layers)
         counts = stats.get("expert_tokens")
         if counts is None:
             return

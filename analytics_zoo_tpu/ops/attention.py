@@ -1,9 +1,10 @@
 """Attention op: single entry point the layer library calls.
 
 Dispatches between the Pallas flash-attention kernel (ops/flash_attention.py)
-and a fused-by-XLA jnp path. Both take (B, N, S, D) q and (B, N_kv, S, D)
-k/v (N_kv = N, or fewer key-value heads that divide N: grouped-query
-attention), an additive bias/mask, a causal flag and, with it, a sliding
+and a fused-by-XLA jnp path. Both take (B, N, S, D) q, (B, N_kv, S, D) k and
+(B, N_kv, S, Dv) v (N_kv = N, or fewer key-value heads that divide N:
+grouped-query attention; Dv = D, or the values' own width, which is then the
+output's: latent attention), an additive bias/mask, a causal flag and, with it, a sliding
 ``window`` (query i sees key j iff 0 <= i - j < window; the kernels skip
 the blocks wholly outside it). The default routes by the size of the S^2
 logits tensor (see _flash_bytes_threshold): XLA at product shapes, the
@@ -128,10 +129,11 @@ def scaled_dot_product_attention(q, k, v, bias: Optional[jax.Array] = None,
                                  dropout_rng: Optional[jax.Array] = None,
                                  use_flash: Optional[bool] = None,
                                  window: Optional[int] = None) -> jax.Array:
-    """q: (batch, heads, seq, head_dim); k/v the same, or with fewer
+    """q: (batch, heads, seq, head_dim); k the same, or with fewer
     key-value heads that divide the query heads (grouped-query attention:
-    query head h reads key-value head h // (heads / key-value heads)).
-    bias: additive, broadcastable to (batch, heads, q_len, k_len) — use
+    query head h reads key-value head h // (heads / key-value heads)); v as
+    k but for its last dim, the values' own width: the output is (batch,
+    heads, seq, v's width). bias: additive, broadcastable to (batch, heads, q_len, k_len) — use
     large negatives for padding masks. ``window`` (needs ``causal``): query
     i sees key j iff 0 <= i - j < window — the sliding-window mask; on the
     kernel path blocks wholly outside the window are skipped, not masked.

@@ -699,10 +699,14 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, window, res, cts):
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def _validate(q, k, scale, block_q: int, block_k: int, causal=True,
+def _validate(q, k, v, scale, block_q: int, block_k: int, causal=True,
               window=None, bias=None):
     """Shared support-envelope check for both public entry points; returns
     the resolved scale."""
+    if q.shape[-1] != k.shape[-1] or k.shape[:-1] != v.shape[:-1]:
+        raise ValueError(
+            f"q {q.shape} and k {k.shape} contract over one width, and k and "
+            f"v {v.shape} differ in their last dim at most")
     if scale is None:
         scale = q.shape[-1] ** -0.5
     s_q, s_k = q.shape[2], k.shape[2]
@@ -713,8 +717,9 @@ def _validate(q, k, scale, block_q: int, block_k: int, causal=True,
         raise NotImplementedError("bias with grouped key-value heads")
     if s_q % block_q or s_k % block_k:
         raise NotImplementedError(f"seq lens must tile ({block_q},{block_k})")
-    if q.shape[-1] > 256:
-        raise NotImplementedError("head_dim > 256")
+    if max(q.shape[-1], v.shape[-1]) > 256:
+        raise NotImplementedError(
+            f"head widths {q.shape[-1]} (q, k) and {v.shape[-1]} (v): > 256")
     live = _live_blocks(causal, window, s_q, s_k, block_q, block_k)
     steps = int(live.sum())
     if steps > _MAX_STEPS and not live.all():
@@ -730,9 +735,12 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     window: Optional[int] = None):
-    """Pallas path. q: (batch, heads, seq, head_dim); k/v the same, or with
+    """Pallas path. q: (batch, heads, seq, head_dim); k the same, or with
     fewer (key-value) heads that divide the query heads: query head h reads
-    key-value head h // (heads / key-value heads). bias additive,
+    key-value head h // (heads / key-value heads); v as k but for its last
+    dim, the values' own width, which is the output's (latent attention: q
+    and k 192 wide, v and the output 128; dq and dk come back as wide as q,
+    dv as wide as v; neither width over 256). bias additive,
     broadcastable to (batch, heads, 1, s_k) (padding-mask layout).
     ``window`` (with ``causal``): a query sees only the ``window`` newest of
     its causal keys, itself included. The kernels' grids hold the live
@@ -743,7 +751,7 @@ def flash_attention(q, k, v, bias: Optional[jax.Array] = None,
     the seq-aware default tile sizes per call."""
     block_q, block_k = _resolve_blocks(block_q, block_k,
                                        q.shape[2], k.shape[2])
-    scale = _validate(q, k, scale, block_q, block_k, causal, window, bias)
+    scale = _validate(q, k, v, scale, block_q, block_k, causal, window, bias)
     window = None if window is None else int(window)
     b, n, s_q, d = q.shape
     s_k, n_kv = k.shape[2], k.shape[1]
@@ -777,7 +785,7 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
     delta term)."""
     block_q, block_k = _resolve_blocks(block_q, block_k,
                                        q.shape[2], k.shape[2])
-    scale = _validate(q, k, scale, block_q, block_k, causal)
+    scale = _validate(q, k, v, scale, block_q, block_k, causal)
     b, n, s_q, d = q.shape
     s_k = k.shape[2]
     bn = b * n

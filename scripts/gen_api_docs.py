@@ -48,8 +48,11 @@ PAGES = {
     "keras-layers-attention": (
         "Keras layers — attention and transformers",
         "TransformerLayer/BERT blocks, sequence- and pipeline-parallel "
-        "attention (ref APIGuide/PipelineAPI/keras-api transformer rows).",
-        ["analytics_zoo_tpu.keras.layers.attention"]),
+        "attention (ref APIGuide/PipelineAPI/keras-api transformer rows); "
+        "the decoder block library (grouped-query and latent attention, "
+        "gated short convolution, rotary embedding, SwiGLU, DecoderBlock).",
+        ["analytics_zoo_tpu.keras.layers.attention",
+         "analytics_zoo_tpu.keras.layers.decoder"]),
     "keras-layers-extras": (
         "Keras layers — wrappers and extras",
         "TimeDistributed/Bidirectional, merges, noise, masking and the "

@@ -530,29 +530,36 @@ def test_a_compacted_pass_exists_only_where_it_is_smaller(held, n_experts, k,
         assert rows == 32768
 
 
-# the two families' expert layers: the reference, its configuration at the toy
-# widths with all 8 experts, the layer's own arguments, and whether a shared
-# expert stands beside the routed ones
+# the three families' expert layers: the reference, its configuration at the
+# toy widths, the key that counts the experts held, the layer's own
+# arguments, and whether shared experts stand beside the routed ones
 _LFM2_MOE = dict(CFG, norm_topk_prob=True, routed_scaling_factor=1)
+_DEEPSEEK_V3 = dict(_LFM2_MOE, routed_scaling_factor=2.448, n_shared_experts=2,
+                    num_hidden_layers=3, first_k_dense_replace=1,
+                    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                    kv_lora_rank=32)
 _EXPERT_LAYERS = {
-    "afmoe": ("benchmark.reference.trinity", CFG,
+    "afmoe": ("benchmark.reference.trinity", CFG, "num_experts",
               dict(route_scale=2.826), True),
-    "lfm2_moe": ("benchmark.reference.lfm2", _LFM2_MOE,
+    "lfm2_moe": ("benchmark.reference.lfm2", _LFM2_MOE, "num_experts",
                  dict(route_eps=1e-6), False),
+    "deepseek_v3": ("benchmark.reference.kanana2", _DEEPSEEK_V3,
+                    "n_routed_experts", dict(route_scale=2.448), True),
 }
 
 
 @pytest.mark.parametrize("family", sorted(_EXPERT_LAYERS))
 def test_the_eight_shares_add_up_to_the_uncut_expert_layer(family):
-    """The routed parts of all shares, plus the shared expert (where the
-    family has one) counted once, equal the uncut reference's expert layer."""
+    """The routed parts of all shares, plus the shared experts (where the
+    family has them; two of them are one SwiGLU twice as wide) counted once,
+    equal the uncut reference's expert layer."""
     import importlib
 
     from analytics_zoo_tpu.keras.layers import SparseMoE
 
-    module, cfg, layer_args, has_shared = _EXPERT_LAYERS[family]
+    module, cfg, held_key, layer_args, has_shared = _EXPERT_LAYERS[family]
     ref_ = importlib.import_module(module)
-    whole = dict(cfg, num_experts=8, experts_held_offset=0)
+    whole = dict(cfg, **{held_key: 8}, experts_held_offset=0)
     w = ref_.init_weights(whole, jax.random.PRNGKey(5))["layers"][1]
     m = jax.random.normal(jax.random.PRNGKey(6), (64, 64))
     bias = 0.01 * jax.random.normal(jax.random.PRNGKey(7), (8,))
@@ -580,7 +587,7 @@ def test_the_eight_shares_add_up_to_the_uncut_expert_layer(family):
             # the reference, given the same share, computes the same part
             part, _ = ref_.expert_layer(
                 dict(w, experts=held), m, bias,
-                dict(whole, num_experts=2, experts_held_offset=offset))
+                dict(whole, **{held_key: 2}, experts_held_offset=offset))
             _close(y, part - shared(), 1e-5)
             total = total + y
         total = total + shared()
