@@ -1187,6 +1187,19 @@ def checkpoint_sweep_counters() -> Dict[str, Counter]:
     return _sweep_children
 
 
+def flash_backward_built() -> MetricFamily:
+    """``zoo_flash_backward_built_total{kernels="one"|"two"}``: builds of
+    the flash attention's backward (``ops/flash_attention.py``), counted
+    when it is traced, by how many Pallas kernels it was built with: one
+    (dq beside dk/dv, where a query head's dq fits the VMEM budget) or two
+    (dq's kernel and dk/dv's). A build, not a step: a device trace with no
+    ``zoo_flash_dq`` operation says the same at run time."""
+    return get_registry().counter(
+        "zoo_flash_backward_built_total",
+        "Builds of the flash attention's backward, by how many Pallas "
+        "kernels it runs (one/two).", labels=("kernels",))
+
+
 def distributed_metrics() -> Dict[str, Any]:
     """The multi-host training metric children in the global registry
     (:mod:`analytics_zoo_tpu.ft.distributed` + ``train_distributed``):

@@ -4,9 +4,14 @@ The hot op of TransformerLayer/BERT (ref TransformerLayer.scala:50,
 BERT.scala:60). The forward kernel streams K/V blocks through VMEM against a
 resident Q block, maintaining running max/denominator — O(S) memory instead
 of the O(S²) logits tensor (HBM-bandwidth-bound otherwise). The backward is
-the standard tiled dq / dk-dv split (two kernels, each re-computing the
-probability tile from the saved per-row logsumexp), so *training* gets the
-memory and bandwidth win too — no O(S²) recompute fallback.
+one kernel that re-computes each live block's probability tile from the
+saved per-row logsumexp once and takes dq, dk and dv from it: a K/V block
+stays in VMEM and its live query blocks stream past, dk/dv gather in block
+scratch and dq in a slab of the query head's whole row. A row whose slab
+would not fit the VMEM budget (``_ONE_KERNEL_VMEM``) runs the standard tiled
+dq / dk-dv split instead (two kernels, each re-computing the tile). Either
+way *training* gets the memory and bandwidth win too — no O(S²) recompute
+fallback.
 
 Which (query block, key block) pairs a kernel visits is a block schedule,
 computed from the shapes at trace time (``_schedule``): a block no query of
@@ -160,6 +165,25 @@ _FIRST, _LAST, _LIVE = 1, 2, 4
 # prefetched into SMEM, 1 MiB on a v5e, and this leaves a quarter of it.
 _MAX_STEPS = 1 << 16
 
+# The most VMEM the one-kernel backward may give a query head's whole dq: its
+# f32 slab and the two buffers of its output block, lane-padded
+# (``_dq_slab_bytes``). 32 of a v5e's 128 MiB stay for the block-sized
+# operands and the score tile's temporaries. A longer row (over 98 304 bf16
+# queries at widths to 128, 49 152 at 192: ring attention's longest shards)
+# runs the two kernels, dq's and dk/dv's, each of which needs only blocks.
+_ONE_KERNEL_VMEM = 96 << 20
+
+# What the one-kernel backward asks of VMEM beside the slab: Mosaic's default
+# scoped limit on a v5e, which the two kernels' blocks compile within.
+_BLOCK_VMEM = 16 << 20
+
+
+def _dq_slab_bytes(s_q: int, d: int, dtype) -> int:
+    """VMEM for a row's dq in the one-kernel backward: an f32 ``(s_q, d)``
+    slab and two ``dtype`` buffers of the output, ``d`` padded to lanes."""
+    lanes = -(-d // 128) * 128
+    return s_q * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
+
 
 def _live_blocks(causal: bool, window, s_q: int, s_k: int, block_q: int,
                  block_k: int) -> np.ndarray:
@@ -236,6 +260,14 @@ class _Schedule(NamedTuple):
         return (self.resident_block(j, t, resident_ref),
                 self.streamed_block(t, streamed_ref), first, last, live)
 
+    def row_edges(self):
+        """(first, last): whether the grid step at hand is its row's first
+        or last, the whole walk of a row between them."""
+        j, t = pl.program_id(1), pl.program_id(2)
+        return (jnp.logical_and(j == 0, t == 0),
+                jnp.logical_and(j == self.blocks - 1,
+                                t == self.steps * self.heads - 1))
+
     def when_live(self, live, compute) -> None:
         """The step's block work: on a live step only, and with no branch
         where every step is one."""
@@ -274,13 +306,15 @@ def _schedule(causal: bool, window, s_q: int, s_k: int, block_q: int,
     return _Schedule(res, strm, flags, 1, flags.size, heads, False)
 
 
-def _index_maps(sched: _Schedule, resident: str, group: int):
+def _index_maps(sched: _Schedule, resident: str, group: int,
+                rows: str = "q"):
     """The index maps of a kernel's operands over its grid (row, j, t) and
     the schedule's tables: a q block ``(1, block_q, d)``, a per-query vector
-    ``(1, 1, block_q)``, a K/V block and a per-key vector. ``resident="q"``:
-    a row is a query head, and reads key-value row ``row // group``.
-    ``resident="k"``: a row is a key-value head, and the step names the
-    query head of its group."""
+    ``(1, 1, block_q)``, a K/V block and a per-key vector. ``resident``:
+    which block stays in VMEM across the inner axis. ``rows="q"``: a row is
+    a query head, and reads key-value row ``row // group``. ``rows="kv"``
+    (the two-kernel dk/dv): a row is a key-value head, and the step names
+    the query head of its group."""
 
     def resident_blk(j, t, tables):
         return sched.resident_block(j, t, tables[0])
@@ -294,10 +328,10 @@ def _index_maps(sched: _Schedule, resident: str, group: int):
         q_of, k_of = streamed_blk, resident_blk
 
     def q_row(i, t):
-        return i if resident == "q" else i * group + sched.head(t)
+        return i if rows == "q" else i * group + sched.head(t)
 
     def kv_row(i):
-        return i // group if resident == "q" else i
+        return i // group if rows == "q" else i
 
     def q_blk(i, j, t, *tables):
         return q_row(i, t), q_of(j, t, tables), 0
@@ -344,19 +378,26 @@ def _masked(s, qi, ki, block_q: int, block_k: int, causal_offset: int,
 
 
 def _pcall(kernel, name: str, sched: _Schedule, rows: int, in_specs,
-           out_specs, out_shape, scratch_shapes, operands):
-    """Shared pallas_call plumbing for all three kernels: a grid of ``rows``
+           out_specs, out_shape, scratch_shapes, operands,
+           row_vmem: Optional[int] = None):
+    """Shared pallas_call plumbing for all the kernels: a grid of ``rows``
     by the schedule's two axes (the innermost sequential, carrying the
     accumulator scratch), the schedule's tables prefetched into SMEM for the
     index maps and the kernel's ``pl.when``s, the interpret flag. ``name``
     is the kernel's stable name in a device trace (``zoo_flash_fwd`` /
     ``zoo_flash_dq`` / ``zoo_flash_dkv``): the benchmark's readers find the
-    kernels by it."""
+    kernels by it. ``row_vmem``: the bytes of a scratch that a whole row
+    carries (the one-kernel backward's dq), so that both inner axes are
+    sequential and VMEM holds it beside the blocks."""
     interpret = _interpret()
     kw = {}
     if not interpret:
         kw["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+            dimension_semantics=(
+                "parallel", "parallel" if row_vmem is None else "arbitrary",
+                "arbitrary"),
+            vmem_limit_bytes=(None if row_vmem is None
+                              else row_vmem + _BLOCK_VMEM))
     return pl.pallas_call(
         kernel, interpret=interpret, name=name, out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -470,17 +511,26 @@ def _flash_forward(q, k, v, bias_flat, scale: float, causal: bool,
 
 
 # ---------------------------------------------------------------------------
-# Backward: dq kernel (a q block resident, its live K/V blocks streamed),
-# dk/dv/dbias kernel (a K/V block resident, its live q blocks streamed, each
-# once for every query head of the group). Each grid is (rows, the schedule's
-# two axes): the live blocks only. Both re-materialize the probability tile
-# from the saved logsumexp, accumulating in an f32 VMEM scratch from a
-# resident block's first step and flushing on its last. A whole-row design
-# (K/V as full (1, s, d) blocks with an in-kernel fori over pl.ds slices)
-# unrolls into a Mosaic module whose size grows with the sequence; this
-# blocked-grid form keeps the module size independent of the sequence length
-# (the schedule's tables grow with the live blocks of one head, 2 080 int32
-# words three times at 32 768 keys) and lets the pallas pipeline stream K/V
+# Backward. One kernel where a row's dq fits VMEM (``_ONE_KERNEL_VMEM``): a row
+# is a query head, a K/V block stays resident while its live q blocks stream
+# past (the schedule's ``resident="k"`` walk), and each live block's scores,
+# probabilities and dS are made once for all three products; dk/dv gather in
+# block scratch, dq in an f32 slab of the row's whole ``(s_q, d)``, zeroed at
+# the row's first step and written once at its last. Key blocks ascend, so
+# dq sums in the dq kernel's order. With grouped key-value heads each query
+# head's dk/dv leave the kernel in f32 and XLA sums the group.
+# Otherwise two kernels: dq (a q block resident, its live K/V blocks
+# streamed) and dk/dv/dbias (a K/V block resident, its live q blocks
+# streamed, each once for every query head of the group). Each grid is
+# (rows, the schedule's two axes): the live blocks only. All re-materialize
+# the probability tile from the saved logsumexp, accumulating in f32 VMEM
+# scratch from a resident block's first step and flushing on its last. A
+# whole-row design (K/V as full (1, s, d) blocks with an in-kernel fori over
+# pl.ds slices) unrolls into a Mosaic module whose size grows with the
+# sequence; this blocked-grid form keeps the module size independent of the
+# sequence length (the schedule's tables grow with the live blocks of one
+# head, 2 080 int32 words three times at 32 768 keys; the dq slab is zeroed
+# and written in a loop of blocks) and lets the pallas pipeline stream the
 # blocks instead of holding whole rows in VMEM.
 # ---------------------------------------------------------------------------
 
@@ -521,16 +571,43 @@ def _dq_kernel(resident_ref, streamed_ref, flags_ref, q_ref, k_ref, v_ref,
         dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
+def _each_block(n: int, size: int, body) -> None:
+    """``body(rows)`` for the ``n`` row slices of ``size`` of a slab, as a
+    loop the Mosaic module holds once (a whole-slab operation unrolls)."""
+    def step(b, carry):
+        body(pl.ds(pl.multiple_of(b * size, size), size))
+        return carry
+
+    jax.lax.fori_loop(0, n, step, 0)
+
+
 def _dkv_kernel(resident_ref, streamed_ref, flags_ref, q_ref, k_ref, v_ref,
                 do_ref, lse_ref, delta_ref, bias_ref, dk_ref, dv_ref, db_ref,
-                dk_acc, dv_acc, db_acc, *, scale: float, causal: bool,
-                sched: _Schedule, block_q: int, block_k: int,
-                causal_offset: int, has_bias: bool, window):
-    # the steps of a K/V block walk its live q blocks, each for every query
-    # head of the group in turn: one K/V block gathers dk/dv from all of them
+                *refs, scale: float, causal: bool, sched: _Schedule,
+                block_q: int, block_k: int, causal_offset: int,
+                has_bias: bool, window, with_dq: bool):
+    # the steps of a K/V block walk its live q blocks (in the two-kernel form
+    # each for every query head of the group in turn): one K/V block gathers
+    # dk/dv from all of them. ``with_dq``: the one-kernel backward, whose
+    # refs go on with dq's output and its slab
+    if with_dq:
+        dq_ref, dk_acc, dv_acc, db_acc, dq_acc = refs
+    else:
+        dk_acc, dv_acc, db_acc = refs
     ki, qi, first, last, live = sched.this_step(resident_ref, streamed_ref,
                                                flags_ref)
     cdt = _compute_dtype(q_ref)
+
+    if with_dq:
+        row_first, row_last = sched.row_edges()
+        n_q = dq_acc.shape[0] // block_q
+
+        @pl.when(row_first)
+        def _zero_dq():
+            def zero(rows):
+                dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]),
+                                            jnp.float32)
+            _each_block(n_q, block_q, zero)
 
     @pl.when(first)
     def _init():
@@ -558,6 +635,9 @@ def _dkv_kernel(resident_ref, streamed_ref, flags_ref, q_ref, k_ref, v_ref,
         dk_acc[...] += _mm_tn(ds, q, cdt)             # scale applied at flush
         if has_bias:
             db_acc[...] += jnp.sum(ds, axis=0)[None, :]
+        if with_dq:
+            rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+            dq_acc[rows, :] += _mm(ds, k, cdt)
 
     sched.when_live(live, compute)
 
@@ -568,85 +648,121 @@ def _dkv_kernel(resident_ref, streamed_ref, flags_ref, q_ref, k_ref, v_ref,
         db_ref[0, 0] = db_acc[0] if has_bias else jnp.zeros(
             (block_k,), jnp.float32)
 
+    if with_dq:
+        @pl.when(row_last)
+        def _flush_dq():
+            def flush(rows):
+                dq_ref[0, rows, :] = (dq_acc[rows, :] * scale).astype(
+                    dq_ref.dtype)
+            _each_block(n_q, block_q, flush)
+
 
 def _flash_backward(q, k, v, bias_flat, out, lse, g, scale: float,
                     causal: bool, block_q: int, block_k: int, g_lse=None,
                     window=None):
+    from analytics_zoo_tpu.common.observability import flash_backward_built
+
     bn, s_q, d = q.shape
     s_k = k.shape[1]
     dv_dim = v.shape[-1]
     group = bn // k.shape[0]
     has_bias = bias_flat is not None
     shapes = (causal, window, s_q, s_k, block_q, block_k)
-    sched_q = _schedule(*shapes)
-    sched_k = _schedule(*shapes, resident="k", heads=group)
     static = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
                   causal_offset=s_k - s_q, has_bias=has_bias, window=window)
+    slab = _dq_slab_bytes(s_q, d, q.dtype)
+    one = slab <= _ONE_KERNEL_VMEM
+    flash_backward_built().labels(kernels="one" if one else "two").inc()
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, None, :]  # (bn, 1, s_q)
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32)
 
+    def inputs(q_blk, q_vec, kv_blk, k_vec):
+        """q, k, v, dO, lse, delta (and the bias) as the kernels read them."""
+        specs = [
+            pl.BlockSpec((1, block_q, d), q_blk),
+            pl.BlockSpec((1, block_k, d), kv_blk),
+            pl.BlockSpec((1, block_k, dv_dim), kv_blk),
+            pl.BlockSpec((1, block_q, dv_dim), q_blk),
+            pl.BlockSpec((1, 1, block_q), q_vec),
+            pl.BlockSpec((1, 1, block_q), q_vec),
+        ]
+        ops = [q, k, v, g, lse, delta]
+        if has_bias:
+            specs.append(pl.BlockSpec((1, 1, block_k), k_vec))
+            ops.append(bias_flat)
+        return specs, ops
+
+    def dkv_outputs(kv_blk, k_vec, rows, dtype_k, dtype_v):
+        return ([pl.BlockSpec((1, block_k, d), kv_blk),
+                 pl.BlockSpec((1, block_k, dv_dim), kv_blk),
+                 pl.BlockSpec((1, 1, block_k), k_vec)],
+                [jax.ShapeDtypeStruct((rows, s_k, d), dtype_k),
+                 jax.ShapeDtypeStruct((rows, s_k, dv_dim), dtype_v),
+                 jax.ShapeDtypeStruct((rows, 1, s_k), jnp.float32)])
+
+    dkv_scratch = [pltpu.VMEM((block_k, d), jnp.float32),
+                   pltpu.VMEM((block_k, dv_dim), jnp.float32),
+                   pltpu.VMEM((1, block_k), jnp.float32)]
+
+    if one:
+        # a row is a query head: its K/V blocks resident in turn, its live q
+        # blocks streamed; a query head's dk/dv (f32 where a group sums them)
+        # and its whole dq
+        sched = _schedule(*shapes, resident="k")
+        specs, ops = inputs(*_index_maps(sched, "k", group))
+        _, _, out_blk, out_vec = _index_maps(sched, "k", 1)
+        out_specs, out_shape = dkv_outputs(
+            out_blk, out_vec, bn,
+            *((jnp.float32,) * 2 if group > 1 else (k.dtype, v.dtype)))
+        dk, dv, dbias, dq = _pcall(
+            _maybe_bias(functools.partial(
+                _dkv_kernel, sched=sched, with_dq=True, **static),
+                has_bias, n_in=9),
+            "zoo_flash_dkv", sched, bn, specs,
+            out_specs=out_specs + [
+                pl.BlockSpec((1, s_q, d), lambda i, j, t, *tables: (i, 0, 0))],
+            out_shape=out_shape + [
+                jax.ShapeDtypeStruct((bn, s_q, d), q.dtype)],
+            scratch_shapes=dkv_scratch + [pltpu.VMEM((s_q, d), jnp.float32)],
+            operands=ops, row_vmem=slab)
+        if group > 1:
+            def summed(x, like):  # a group's query heads, neighbouring rows
+                return x.reshape(bn // group, group, s_k, -1).sum(1).astype(
+                    like.dtype)
+            dk, dv = summed(dk, k), summed(dv, v)
+        return dq, dk, dv, (dbias if has_bias else None)
+
     # dq: q/do/lse/delta resident across a q block's steps, its live K/V
     # blocks streamed one a step by the pipeline
-    q_blk, q_vec, kv_blk, k_vec = _index_maps(sched_q, "q", group)
-    dq_specs = [
-        pl.BlockSpec((1, block_q, d), q_blk),
-        pl.BlockSpec((1, block_k, d), kv_blk),
-        pl.BlockSpec((1, block_k, dv_dim), kv_blk),
-        pl.BlockSpec((1, block_q, dv_dim), q_blk),
-        pl.BlockSpec((1, 1, block_q), q_vec),
-        pl.BlockSpec((1, 1, block_q), q_vec),
-    ]
-    dq_ops = [q, k, v, g, lse, delta]
-    if has_bias:
-        dq_specs.append(pl.BlockSpec((1, 1, block_k), k_vec))
-        dq_ops.append(bias_flat)
+    sched_q = _schedule(*shapes)
+    q_blk, _, _, _ = maps = _index_maps(sched_q, "q", group)
+    specs, ops = inputs(*maps)
     dq = _pcall(
         _maybe_bias(functools.partial(
             _dq_kernel, sched=sched_q, **static), has_bias, n_in=9),
-        "zoo_flash_dq", sched_q, bn, dq_specs,
+        "zoo_flash_dq", sched_q, bn, specs,
         out_specs=pl.BlockSpec((1, block_q, d), q_blk),
         out_shape=jax.ShapeDtypeStruct((bn, s_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        operands=dq_ops)
+        operands=ops)
 
     # dk/dv/dbias: a row is a key-value head; K/V resident across a K block's
     # steps, Q/dO/lse/delta of each of the group's query heads streamed
-    q_blk, q_vec, kv_blk, k_vec = _index_maps(sched_k, "k", group)
-    dkv_specs = [
-        pl.BlockSpec((1, block_q, d), q_blk),
-        pl.BlockSpec((1, block_k, d), kv_blk),
-        pl.BlockSpec((1, block_k, dv_dim), kv_blk),
-        pl.BlockSpec((1, block_q, dv_dim), q_blk),
-        pl.BlockSpec((1, 1, block_q), q_vec),
-        pl.BlockSpec((1, 1, block_q), q_vec),
-    ]
-    dkv_ops = [q, k, v, g, lse, delta]
-    if has_bias:
-        dkv_specs.append(pl.BlockSpec((1, 1, block_k), k_vec))
-        dkv_ops.append(bias_flat)
+    sched_k = _schedule(*shapes, resident="k", heads=group)
+    _, _, kv_blk, k_vec = maps = _index_maps(sched_k, "k", group, rows="kv")
+    specs, ops = inputs(*maps)
+    out_specs, out_shape = dkv_outputs(kv_blk, k_vec, bn // group, k.dtype,
+                                       v.dtype)
     dk, dv, dbias = _pcall(
         _maybe_bias(functools.partial(
-            _dkv_kernel, sched=sched_k, **static), has_bias, n_in=9),
-        "zoo_flash_dkv", sched_k, bn // group, dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), kv_blk),
-            pl.BlockSpec((1, block_k, dv_dim), kv_blk),
-            pl.BlockSpec((1, 1, block_k), k_vec),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bn // group, s_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bn // group, s_k, dv_dim), v.dtype),
-            jax.ShapeDtypeStruct((bn // group, 1, s_k), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, dv_dim), jnp.float32),
-            pltpu.VMEM((1, block_k), jnp.float32),
-        ],
-        operands=dkv_ops)
+            _dkv_kernel, sched=sched_k, with_dq=False, **static),
+            has_bias, n_in=9),
+        "zoo_flash_dkv", sched_k, bn // group, specs,
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=dkv_scratch, operands=ops)
     return dq, dk, dv, (dbias if has_bias else None)
 
 

@@ -264,7 +264,7 @@ def test_a_block_runs_the_flash_forward_once(kind, forwards, monkeypatch):
     log-sum-exp, so the backward pass holds no second forward kernel; a bare
     checkpoint holds two."""
     assert _block_grads(kind, 128, monkeypatch, count=True) == {
-        "zoo_flash_fwd": forwards, "zoo_flash_dq": 1, "zoo_flash_dkv": 1}
+        "zoo_flash_fwd": forwards, "zoo_flash_dkv": 1}
 
 
 @pytest.mark.parametrize("window", [128, None])

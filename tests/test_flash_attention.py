@@ -443,44 +443,104 @@ def _pallas_calls(jaxpr, found=None):
     return found
 
 
-def _traced_grad(shape, kv_heads, window, monkeypatch, causal=True, **blocks):
+def _traced_grad(shape, kv_heads, window, monkeypatch, causal=True,
+                 v_width=None, **blocks):
     """The jaxpr of the kernels' forward and backward at ``shape`` (traced
-    from shapes: nothing runs)."""
+    from shapes: nothing runs); values ``v_width`` wide if given."""
     monkeypatch.delenv("AZOO_FLASH_BLOCK_Q", raising=False)
     monkeypatch.delenv("AZOO_FLASH_BLOCK_K", raising=False)
     b, n, s, d = shape
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
-    kv = jax.ShapeDtypeStruct((b, kv_heads, s, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((b, kv_heads, s, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((b, kv_heads, s, v_width or d), jnp.bfloat16)
 
     def loss(q_, k_, v_):
         return jnp.sum(flash_attention(q_, k_, v_, causal=causal,
                                        window=window,
                                        **blocks).astype(jnp.float32))
 
-    return jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv).jaxpr
+    return jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr
 
 
-@pytest.mark.parametrize("shape,kv_heads,causal,window,walk", [
-    ((1, 32, 32768, 64), 8, True, None, (1, 2080)),  # lfm2-24b-a2b.fit-seq32k
-    ((2, 32, 8192, 128), 4, True, None, (1, 136)),   # trinity-mini.fit-seq8k
-    ((2, 32, 8192, 128), 4, True, 2048, (1, 70)),    # ... its window layers
-    ((8, 12, 2048, 64), 12, False, None, (4, 4)),    # BERT-base widths
+_KANANA = ((1, 32, 16384, 192), 32, True, None, (1, 528), 128)
+
+
+@pytest.mark.parametrize("shape,kv_heads,causal,window,walk,v_width", [
+    ((1, 32, 32768, 64), 8, True, None, (1, 2080), None),  # lfm2 fit-seq32k
+    ((2, 32, 8192, 128), 4, True, None, (1, 136), None),   # trinity fit-seq8k
+    ((2, 32, 8192, 128), 4, True, 2048, (1, 70), None),    # its window layers
+    _KANANA,                                               # kanana fit-seq16k
+    ((8, 12, 2048, 64), 12, False, None, (4, 4), None),    # BERT-base widths
 ])
 def test_the_kernels_grids_hold_the_live_blocks_only(shape, kv_heads, causal,
-                                                     window, walk,
+                                                     window, walk, v_width,
                                                      monkeypatch):
-    """At the two decoder cells' shapes a grid is rows x the live 512 x 512
-    blocks of a head on one axis: a dead block is no grid step. dk/dv's rows
-    are the key-value heads, each step once for every query head of the
-    group. Not causal the grid is the rectangle it was."""
+    """At the decoder cells' shapes a grid is rows x the live 512 x 512
+    blocks of a head on one axis: a dead block is no grid step. The backward
+    is one kernel, still named ``zoo_flash_dkv``, whose rows are the query
+    heads and which walks the same live blocks: no ``zoo_flash_dq``. Not
+    causal the grid is the rectangle it was."""
     b, n = shape[:2]
     blocks, steps = walk
     grids = _pallas_calls(_traced_grad(shape, kv_heads, window, monkeypatch,
-                                       causal=causal))
+                                       causal=causal, v_width=v_width))
+    assert grids == {
+        "zoo_flash_fwd": [(b * n, blocks, steps)],
+        "zoo_flash_dkv": [(b * n, blocks, steps)]}
+
+
+@pytest.mark.parametrize("shape,kv_heads,causal,window,walk,v_width", [
+    ((1, 32, 32768, 64), 8, True, None, (1, 2080), None),
+    _KANANA,
+])
+def test_over_the_vmem_budget_the_backward_is_two_kernels(
+        shape, kv_heads, causal, window, walk, v_width, monkeypatch):
+    """A row whose dq slab does not fit ``_ONE_KERNEL_VMEM`` keeps the two
+    kernels and their grids: dq's rows the query heads, dk/dv's the
+    key-value heads, each step once for every query head of the group."""
+    from analytics_zoo_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_ONE_KERNEL_VMEM", 0)
+    b, n = shape[:2]
+    blocks, steps = walk
+    grids = _pallas_calls(_traced_grad(shape, kv_heads, window, monkeypatch,
+                                       causal=causal, v_width=v_width))
     assert grids == {
         "zoo_flash_fwd": [(b * n, blocks, steps)],
         "zoo_flash_dq": [(b * n, blocks, steps)],
         "zoo_flash_dkv": [(b * kv_heads, blocks, n // kv_heads * steps)]}
+
+
+def test_the_dq_slab_of_the_cells_fits_the_budget():
+    """The slab and its output's two buffers, lane-padded: 33.6 MB at
+    kanana's and LFM2's rows, under the 96 MiB budget; some 98 k queries at
+    width 128 are not."""
+    from analytics_zoo_tpu.ops import flash_attention as fa
+
+    assert fa._dq_slab_bytes(16384, 192, jnp.bfloat16) == 16384 * 256 * 8
+    assert fa._dq_slab_bytes(32768, 64, jnp.bfloat16) == 32768 * 128 * 8
+    assert fa._dq_slab_bytes(8192, 128, jnp.float32) == 8192 * 128 * 12
+    assert fa._dq_slab_bytes(98304, 128, jnp.bfloat16) <= fa._ONE_KERNEL_VMEM
+    assert fa._dq_slab_bytes(98816, 128, jnp.bfloat16) > fa._ONE_KERNEL_VMEM
+
+
+def test_the_backward_builds_are_counted_by_kernels(monkeypatch):
+    """``zoo_flash_backward_built_total{kernels}``: one build a trace of the
+    backward, under the path it took."""
+    from analytics_zoo_tpu.common.observability import flash_backward_built
+    from analytics_zoo_tpu.ops import flash_attention as fa
+
+    def built():
+        fam = flash_backward_built()
+        return {k: fam.labels(kernels=k).value for k in ("one", "two")}
+
+    shape = (1, 2, 1024, 64)
+    before = built()
+    _traced_grad(shape, 2, None, monkeypatch)
+    assert built() == {"one": before["one"] + 1, "two": before["two"]}
+    monkeypatch.setattr(fa, "_ONE_KERNEL_VMEM", 0)
+    _traced_grad(shape, 2, None, monkeypatch)
+    assert built() == {"one": before["one"] + 1, "two": before["two"] + 1}
 
 
 @pytest.mark.parametrize("s,causal,fits", [
@@ -525,3 +585,94 @@ def test_flash_bias_causal_grad():
         jax.random.bernoulli(jax.random.PRNGKey(9), 0.8, (B, N, 1, 384)),
         0.0, -1e9).astype(jnp.float32)
     _check_fwd_and_grads(q, k, v, bias, causal=True)
+
+
+def _two_kernel_builds():
+    from analytics_zoo_tpu.common.observability import flash_backward_built
+
+    return flash_backward_built().labels(kernels="two").value
+
+
+@pytest.mark.parametrize("case", ["causal", "window", "bias", "bias_causal",
+                                  "g_lse"])
+def test_the_two_kernel_backward_against_the_reference(case, monkeypatch):
+    """With the VMEM budget at nought every backward runs dq's kernel and
+    dk/dv's: the fallback keeps the coverage the one kernel has."""
+    from analytics_zoo_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_ONE_KERNEL_VMEM", 0)
+    before = _two_kernel_builds()
+    if case == "causal":
+        _check_fwd_and_grads(*_qkv(jax.random.PRNGKey(0)), None, causal=True)
+    elif case == "window":
+        q, k, v = _qkv(jax.random.PRNGKey(7), s_q=384, s_k=640)
+        _check_fwd_and_grads(q, k, v, None, causal=True, window=130)
+    elif case == "bias":
+        _check_fwd_and_grads(*_qkv(jax.random.PRNGKey(1)),
+                             _padding_bias(jax.random.PRNGKey(2)),
+                             causal=False)
+    elif case == "bias_causal":
+        q, k, v = _qkv(jax.random.PRNGKey(8), s_q=256, s_k=384)
+        bias = jnp.where(
+            jax.random.bernoulli(jax.random.PRNGKey(9), 0.8, (B, N, 1, 384)),
+            0.0, -1e9).astype(jnp.float32)
+        _check_fwd_and_grads(q, k, v, bias, causal=True)
+    else:
+        q, k, v = _qkv(jax.random.PRNGKey(0))
+        got = _out_lse_and_grads(
+            lambda *a: fa.flash_attention_with_lse(*a, causal=True), q, k, v)
+        want = _out_lse_and_grads(
+            lambda *a: _reference_with_lse(*a, True), q, k, v)
+        for a, b, what in zip(got, want, "out lse dq dk dv".split()):
+            np.testing.assert_allclose(a, b, err_msg=what, **TOL)
+    assert _two_kernel_builds() > before
+
+
+@pytest.mark.parametrize("kv_heads,window", [(N, None), (N, 130), (1, None)])
+def test_the_one_and_the_two_kernel_backward_agree(kv_heads, window,
+                                                   monkeypatch):
+    """dq sums over the same key blocks in the same order either way: equal
+    to the last bit. dk / dv too where no group sums; where one does, the
+    one kernel's f32 partials are summed by XLA, within f32 rounding."""
+    from analytics_zoo_tpu.ops import flash_attention as fa
+
+    q, k, v = _qkv(jax.random.PRNGKey(21), s_q=384, s_k=640)
+    k, v = k[:, :kv_heads], v[:, :kv_heads]
+    g = jax.random.normal(jax.random.PRNGKey(22), q.shape, jnp.float32)
+
+    def grads():
+        return jax.grad(lambda *a: jnp.vdot(flash_attention(
+            *a, causal=True, window=window), g), argnums=(0, 1, 2))(q, k, v)
+
+    one = grads()
+    monkeypatch.setattr(fa, "_ONE_KERNEL_VMEM", 0)
+    two = grads()
+    np.testing.assert_array_equal(np.asarray(one[0]), np.asarray(two[0]))
+    for a, b, what in zip(one[1:], two[1:], ("dk", "dv")):
+        if kv_heads == N:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        else:
+            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6,
+                                       err_msg=what)
+
+
+def test_the_one_kernel_backward_at_kanana_widths():
+    """Queries and keys 192 wide beside values 128 wide, 512 x 512 blocks
+    (a two-by-two causal walk): the dq slab is 192 wide."""
+    kq, kk, kv, kg = jax.random.split(jax.random.PRNGKey(23), 4)
+    q = jax.random.normal(kq, (1, 4, 1024, 192), jnp.float32)
+    k = jax.random.normal(kk, (1, 4, 1024, 192), jnp.float32)
+    v = jax.random.normal(kv, (1, 4, 1024, 128), jnp.float32)
+    g = jax.random.normal(kg, (1, 4, 1024, 128), jnp.float32)
+    scale = 192 ** -0.5
+
+    def loss(attn):
+        return lambda *a: jnp.vdot(attn(*a), g)
+
+    got = jax.value_and_grad(loss(lambda *a: flash_attention(
+        *a, causal=True, scale=scale)), argnums=(0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(loss(lambda *a: _reference_attention(
+        *a, None, True, scale)), argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-4)
+    for a, b, what in zip(got[1], want[1], ("dq", "dk", "dv")):
+        np.testing.assert_allclose(a, b, err_msg=what, **TOL)
