@@ -128,7 +128,11 @@ class GroupedQueryAttention(KerasLayer):
     attention, else query i sees keys i - window < j <= i. ``rope_theta``:
     None for no positional encoding, else rotary embedding on q and k. One
     fused input kernel ``w_in`` (d, (2 n_head [gated] or n_head + 2 n_kv_head)
-    * head_dim) laid out q | k | v | g."""
+    * head_dim) laid out q | k | v | g. Scopes, none inside another: the
+    input projection and the split into heads under ``attn.proj_in``, the
+    head norms and rotary under ``attn.qk_rotary``, the kernel call under
+    ``attn.window`` or ``attn.full``, the heads merged, the gate and ``w_out``
+    under ``attn.proj_out``."""
 
     block_key = "attn"      # a mixer's place in a block's parameters
 
@@ -167,29 +171,33 @@ class GroupedQueryAttention(KerasLayer):
     def call(self, params, x, **kw):
         b, s, _ = x.shape
         hd = self.head_dim
-        parts = jnp.split(x @ params["w_in"],
-                          list(itertools.accumulate(self._splits()))[:-1],
-                          axis=-1)
 
         def heads(t, n):
             return t.reshape(b, s, n, hd).transpose(0, 2, 1, 3)
 
-        q, k = heads(parts[0], self.n_head), heads(parts[1], self.n_kv_head)
-        v = heads(parts[2], self.n_kv_head)
-        if self.qk_norm:
-            q = rms_norm(q, params["q_norm"], self.epsilon)
-            k = rms_norm(k, params["k_norm"], self.epsilon)
-        if self.rope_theta is not None:
-            q = rotary_embedding(q, self.rope_theta)
-            k = rotary_embedding(k, self.rope_theta)
+        with jax.named_scope("attn.proj_in"):
+            parts = jnp.split(x @ params["w_in"],
+                              list(itertools.accumulate(self._splits()))[:-1],
+                              axis=-1)
+            q, k = heads(parts[0], self.n_head), heads(parts[1], self.n_kv_head)
+            v = heads(parts[2], self.n_kv_head)
+        with jax.named_scope("attn.qk_rotary"):
+            if self.qk_norm:
+                q = rms_norm(q, params["q_norm"], self.epsilon)
+                k = rms_norm(k, params["k_norm"], self.epsilon)
+            if self.rope_theta is not None:
+                q = rotary_embedding(q, self.rope_theta)
+                k = rotary_embedding(k, self.rope_theta)
         with jax.named_scope("attn.full" if self.window is None
                              else "attn.window"):
             o = scaled_dot_product_attention(q, k, v, causal=True,
                                              window=self.window)
-        o = o.transpose(0, 2, 1, 3).reshape(b, s, self.n_head * hd)
-        if self.gated:
-            o = o * jax.nn.sigmoid(parts[3].astype(jnp.float32)).astype(o.dtype)
-        return o @ params["w_out"]
+        with jax.named_scope("attn.proj_out"):
+            o = o.transpose(0, 2, 1, 3).reshape(b, s, self.n_head * hd)
+            if self.gated:
+                o = o * jax.nn.sigmoid(parts[3].astype(jnp.float32)).astype(
+                    o.dtype)
+            return o @ params["w_out"]
 
 
 class LatentAttention(KerasLayer):
@@ -212,7 +220,7 @@ class LatentAttention(KerasLayer):
     so its gradient is the sum over heads. No biases. Everything between the
     block's norm and the kernel's operands runs under the scope
     ``attn.latent``, the kernel call under ``attn.full`` (it is full causal
-    attention), ``W_out`` under neither."""
+    attention), the heads merged and ``W_out`` under ``attn.proj_out``."""
 
     block_key = "attn"
 
@@ -272,8 +280,9 @@ class LatentAttention(KerasLayer):
         with jax.named_scope("attn.full"):
             o = scaled_dot_product_attention(q, k, v, causal=True,
                                              scale=self.qk_dim ** -0.5)
-        return o.transpose(0, 2, 1, 3).reshape(b, s, h * self.v_dim) @ params[
-            "w_out"]
+        with jax.named_scope("attn.proj_out"):
+            return o.transpose(0, 2, 1, 3).reshape(
+                b, s, h * self.v_dim) @ params["w_out"]
 
 
 class GatedShortConv(KerasLayer):
@@ -331,7 +340,11 @@ class DecoderBlock(KerasLayer):
     nest: ``{"attn" | "conv": ..., "mlp": ..., "<norm>": {"gain": ...}}``
     (the mixer under its ``block_key``). A block with an expert layer carries
     that layer's state (the router's selection bias and the step's tokens an
-    expert) and returns it updated when training."""
+    expert) and returns it updated when training. Scopes: the casts of a
+    half's weights and of the block's input under ``block.cast``, the norms
+    and the two residual adds under ``block.norm``, a dense ``SwiGLU`` under
+    ``mlp.dense``; the mixer and the expert layer under their own, never
+    inside these (their backward and rematerialised passes keep the name)."""
 
     NORMS = ("in_norm", "post_attn_norm", "pre_mlp_norm", "post_mlp_norm")
     LAYOUTS = {"sandwich": NORMS, "pre": ("in_norm", "pre_mlp_norm")}
@@ -382,19 +395,26 @@ class DecoderBlock(KerasLayer):
             return rms_norm(y, p[name]["gain"], eps) if name in p else y
 
         def mixer_half(p, h):
-            p = _cast(p, self.dtype)
-            a = self.mixer.call(p[mixer_key],
-                                rms_norm(h, p["in_norm"]["gain"], eps))
-            return h + after(p, "post_attn_norm", a)
+            with jax.named_scope("block.cast"):
+                p = _cast(p, self.dtype)
+            with jax.named_scope("block.norm"):
+                n = rms_norm(h, p["in_norm"]["gain"], eps)
+            a = self.mixer.call(p[mixer_key], n)
+            with jax.named_scope("block.norm"):
+                return h + after(p, "post_attn_norm", a)
 
         def mlp_half(p, h, st):
-            p = _cast(p, self.dtype)
-            m = rms_norm(h, p["pre_mlp_norm"]["gain"], eps)
+            with jax.named_scope("block.cast"):
+                p = _cast(p, self.dtype)
+            with jax.named_scope("block.norm"):
+                m = rms_norm(h, p["pre_mlp_norm"]["gain"], eps)
             if self.has_state:
                 y, st = self.mlp.call(p["mlp"], m, state=st, training=training)
             else:
-                y = self.mlp.call(p["mlp"], m)
-            return h + after(p, "post_mlp_norm", y), st
+                with jax.named_scope("mlp.dense"):
+                    y = self.mlp.call(p["mlp"], m)
+            with jax.named_scope("block.norm"):
+                return h + after(p, "post_mlp_norm", y), st
 
         # What the mixer's half keeps is in the class's docstring. The
         # other half keeps nothing: its forward pass, the expert layer's
@@ -408,7 +428,8 @@ class DecoderBlock(KerasLayer):
                 if kept else None)
             mlp_half = jax.checkpoint(mlp_half)
         if self.dtype is not None:
-            x = x.astype(self.dtype)
+            with jax.named_scope("block.cast"):
+                x = x.astype(self.dtype)
         mixer_keys = (mixer_key, "in_norm", "post_attn_norm")
         h = mixer_half({k: params[k] for k in mixer_keys if k in params}, x)
         h, new_state = mlp_half(

@@ -257,7 +257,8 @@ class CausalLM(KerasNet):
         """x: token ids (rows, tokens) -> (logits (rows, tokens, vocabulary)
         in the compute type, the new state)."""
         ids = x[0] if isinstance(x, (list, tuple)) else x
-        h = self.embed.call(params[self.embed.name], ids) * self.embed_scale
+        with jax.named_scope("lm.embed"):
+            h = self.embed.call(params[self.embed.name], ids) * self.embed_scale
         new_state = dict(state)
         for block in self.blocks:
             if block.has_state:
@@ -266,12 +267,14 @@ class CausalLM(KerasNet):
                     training=training)
             else:
                 h = block.call(params[block.name], h)
-        h = rms_norm(h, params[self.final_norm.name]["gain"], self.epsilon)
-        if self.head is None:      # tied: the embedding's transpose
-            kernel = params[self.embed.name]["embeddings"].T
-        else:
-            kernel = params[self.head.name]["kernel"]
-        logits = h @ (kernel if self.dtype is None else kernel.astype(self.dtype))
+        with jax.named_scope("lm.head"):
+            h = rms_norm(h, params[self.final_norm.name]["gain"], self.epsilon)
+            if self.head is None:      # tied: the embedding's transpose
+                kernel = params[self.embed.name]["embeddings"].T
+            else:
+                kernel = params[self.head.name]["kernel"]
+            logits = h @ (kernel if self.dtype is None
+                          else kernel.astype(self.dtype))
         if training:
             new_state[self.name] = {
                 "tokens": jnp.asarray(ids.size, jnp.float32)}

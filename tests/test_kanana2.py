@@ -215,10 +215,13 @@ def test_the_projections_and_the_kernel_run_under_scopes_of_their_own():
     names = set(re.findall(r'"(jit\([^"]*)"', text))
     latent = {n for n in names if "attn.latent" in n}
     full = {n for n in names if "attn.full" in n}
-    assert latent and full and not latent & full
+    out = {n for n in names if "attn.proj_out" in n}
+    assert latent and full and out
+    assert not latent & full and not latent & out and not full & out
     assert any("dot_general" in n for n in latent)
-    # the output projection is under neither
-    assert any("dot_general" in n for n in names - latent - full)
+    # the heads merged and the output projection under a scope of their own
+    assert any("dot_general" in n for n in out)
+    assert not any("dot_general" in n for n in names - latent - full - out)
 
 
 def test_a_latent_half_keeps_the_flash_residuals_and_is_the_blocks_attn():
