@@ -1200,6 +1200,17 @@ def flash_backward_built() -> MetricFamily:
         "kernels it runs (one/two).", labels=("kernels",))
 
 
+def qk_rotary_built() -> MetricFamily:
+    """``zoo_qk_rotary_built_total{path="kernel"|"xla"}``: builds of the
+    per-head norm and rotary of queries or keys (``ops/qk_rotary.py``
+    ``norm_rotary``), one a tensor each time it is traced, by the path it
+    took: the fused Pallas kernels or XLA's two functions."""
+    return get_registry().counter(
+        "zoo_qk_rotary_built_total",
+        "Builds of the per-head q/k norm and rotary, by path (kernel/xla).",
+        labels=("path",))
+
+
 def distributed_metrics() -> Dict[str, Any]:
     """The multi-host training metric children in the global registry
     (:mod:`analytics_zoo_tpu.ft.distributed` + ``train_distributed``):

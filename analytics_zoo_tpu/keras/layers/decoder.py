@@ -128,9 +128,12 @@ class GroupedQueryAttention(KerasLayer):
     attention, else query i sees keys i - window < j <= i. ``rope_theta``:
     None for no positional encoding, else rotary embedding on q and k. One
     fused input kernel ``w_in`` (d, (2 n_head [gated] or n_head + 2 n_kv_head)
-    * head_dim) laid out q | k | v | g. Scopes, none inside another: the
-    input projection and the split into heads under ``attn.proj_in``, the
-    head norms and rotary under ``attn.qk_rotary``, the kernel call under
+    * head_dim) laid out q | k | v | g. With ``qk_norm`` the head norm and
+    rotary of q and of k are one op, ``ops.qk_rotary.norm_rotary`` (fused
+    kernels on the chip), which also moves them into heads. Scopes, none
+    inside another: the input projection and the split under
+    ``attn.proj_in``, the head norms and rotary under ``attn.qk_rotary``,
+    the kernel call under
     ``attn.window`` or ``attn.full``, the heads merged, the gate and ``w_out``
     under ``attn.proj_out``."""
 
@@ -172,20 +175,30 @@ class GroupedQueryAttention(KerasLayer):
         b, s, _ = x.shape
         hd = self.head_dim
 
+        def split(t, n):
+            return t.reshape(b, s, n, hd)
+
         def heads(t, n):
-            return t.reshape(b, s, n, hd).transpose(0, 2, 1, 3)
+            return split(t, n).transpose(0, 2, 1, 3)
 
         with jax.named_scope("attn.proj_in"):
             parts = jnp.split(x @ params["w_in"],
                               list(itertools.accumulate(self._splits()))[:-1],
                               axis=-1)
-            q, k = heads(parts[0], self.n_head), heads(parts[1], self.n_kv_head)
+            # the head norms take q and k as the projection lays them out
+            qk = split if self.qk_norm else heads
+            q, k = qk(parts[0], self.n_head), qk(parts[1], self.n_kv_head)
             v = heads(parts[2], self.n_kv_head)
         with jax.named_scope("attn.qk_rotary"):
             if self.qk_norm:
-                q = rms_norm(q, params["q_norm"], self.epsilon)
-                k = rms_norm(k, params["k_norm"], self.epsilon)
-            if self.rope_theta is not None:
+                # imported here like the kernels' module (_flash_residuals)
+                from analytics_zoo_tpu.ops.qk_rotary import norm_rotary
+
+                q = norm_rotary(q, params["q_norm"], self.epsilon,
+                                self.rope_theta)
+                k = norm_rotary(k, params["k_norm"], self.epsilon,
+                                self.rope_theta)
+            elif self.rope_theta is not None:
                 q = rotary_embedding(q, self.rope_theta)
                 k = rotary_embedding(k, self.rope_theta)
         with jax.named_scope("attn.full" if self.window is None

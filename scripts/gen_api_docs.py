@@ -331,11 +331,13 @@ PAGES = {
          "analytics_zoo_tpu.parallel.pipeline",
          "analytics_zoo_tpu.parallel.moe"]),
     "ops": (
-        "Ops — attention, flash kernels, bbox",
+        "Ops — attention, flash kernels, q/k norm and rotary, bbox",
         "The hot-op layer: dispatchered attention, the Pallas flash "
-        "kernels, padded NMS (SURVEY §2.3).",
+        "kernels, the fused q/k head norm and rotary, padded NMS "
+        "(SURVEY §2.3).",
         ["analytics_zoo_tpu.ops.attention",
          "analytics_zoo_tpu.ops.flash_attention",
+         "analytics_zoo_tpu.ops.qk_rotary",
          "analytics_zoo_tpu.ops.bbox"]),
 }
 

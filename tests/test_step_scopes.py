@@ -153,3 +153,28 @@ def test_the_scopes_are_metadata_only(step):
     _, compiled, bare = step
     assert compiled != bare                 # the eight are in its metadata
     assert program_text(compiled) == program_text(bare)
+
+
+# Where the fused q / k norm and rotary kernels (``ops/qk_rotary.py``) run:
+# on the chip at head widths 64 and 128; here in interpret mode, with the
+# dispatcher told it is on the chip, at a width and a length they take.
+KERNEL_SHAPES = {
+    "afmoe": dict(head_dim=64, seq_len=512),
+    "lfm2_moe": dict(hidden_size=128, num_attention_heads=2,
+                     num_key_value_heads=2, seq_len=512),
+}
+
+
+@pytest.mark.parametrize("family", sorted(KERNEL_SHAPES))
+def test_the_fused_q_k_kernels_run_under_the_rotary_scope(family,
+                                                          monkeypatch):
+    from analytics_zoo_tpu.ops import qk_rotary
+
+    monkeypatch.setattr(qk_rotary, "_on_chip", lambda: True)
+    lowered = _lowered(dict(FAMILIES[family], **KERNEL_SHAPES[family]))
+    names = [full for _, full in _full_names(
+        lowered.as_text("hlo", debug_info=True)) if "zoo_qk_rotary" in full]
+    for kernel in ("zoo_qk_rotary_fwd", "zoo_qk_rotary_bwd"):
+        assert any(kernel in full for full in names), kernel
+    outside = [full for full in names if _named(full) != ["attn.qk_rotary"]]
+    assert not outside, outside[:10]
