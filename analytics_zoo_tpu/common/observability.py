@@ -1304,8 +1304,9 @@ def lm_train_metrics() -> Dict[str, Any]:
     ``zoo_lm_conv_token_layers_total``: tokens times short-convolution
     layers of training steps, the mixer's work as it ran),
     ``latent_token_layers`` (counter ``zoo_lm_latent_token_layers_total``:
-    the same for latent-attention layers). One call per model — the model
-    holds the children."""
+    the same for latent-attention layers), ``ssm_token_layers`` (counter
+    ``zoo_lm_ssm_token_layers_total``: the same for Mamba-2 layers). One
+    call per model — the model holds the children."""
     reg = get_registry()
     assignments = reg.counter(
         "zoo_moe_assignments_total",
@@ -1340,6 +1341,10 @@ def lm_train_metrics() -> Dict[str, Any]:
             "zoo_lm_latent_token_layers_total",
             "Tokens times latent-attention layers a language model's train "
             "steps computed.").labels(),
+        "ssm_token_layers": reg.counter(
+            "zoo_lm_ssm_token_layers_total",
+            "Tokens times Mamba-2 state-space layers a language model's "
+            "train steps computed.").labels(),
     }
 
 

@@ -49,7 +49,7 @@ from analytics_zoo_tpu.keras.layers.attention import (
 from analytics_zoo_tpu.keras.layers.moe import MoE, SparseMoE
 from analytics_zoo_tpu.keras.layers.decoder import (
     RMSNorm, SwiGLU, GroupedQueryAttention, LatentAttention, GatedShortConv,
-    DecoderBlock, rotary_embedding,
+    Mamba2Mixer, DecoderBlock, rotary_embedding,
 )
 from analytics_zoo_tpu.keras.engine.topology import Input, InputLayer
 

@@ -1,17 +1,19 @@
 """Decoder block library: the parts of today's open decoder-only language
 models, as layers.
 
-``RMSNorm``; ``rotary_embedding`` (a function: it has no weights); three token
+``RMSNorm``; ``rotary_embedding`` (a function: it has no weights); four token
 mixers, ``GroupedQueryAttention`` (key-value heads fewer than query heads,
 optional per-head RMS norm of q and k, optional sigmoid output gate, causal, a
 sliding window or full, rotary positions or none), ``LatentAttention`` (keys
 and values of every head from one low-rank latent a token, one rotary key
-shared by all heads, queries and keys wider than values) and
-``GatedShortConv`` (a gated causal convolution over a few neighbouring tokens,
-no attention at all); ``SwiGLU``; and ``DecoderBlock``, which wires one mixer
-and either a dense ``SwiGLU`` or a ``SparseMoE`` (keras/layers/moe.py) under
-one of two norm layouts, four norms a layer (``"sandwich"``) or the two
-pre-norms alone (``"pre"``):
+shared by all heads, queries and keys wider than values), ``GatedShortConv``
+(a gated causal convolution over a few neighbouring tokens, no attention at
+all) and ``Mamba2Mixer`` (a Mamba-2 state-space layer, its scan in the chunked
+form of ``ops/ssd.py``); ``SwiGLU``; and ``DecoderBlock``, which wires one
+mixer and either a dense ``SwiGLU`` or a ``SparseMoE`` (keras/layers/moe.py)
+under one of two norm layouts, four norms a layer (``"sandwich"``) or the two
+pre-norms alone (``"pre"``), or one of the two parts alone behind its
+pre-norm (the Nemotron-H layers):
 
     h += post_attn_norm(mixer(in_norm(h)))      |  h += mixer(in_norm(h))
     h += post_mlp_norm(mlp(pre_mlp_norm(h)))    |  h += mlp(pre_mlp_norm(h))
@@ -36,6 +38,7 @@ import jax.numpy as jnp
 from analytics_zoo_tpu.keras.engine.base import KerasLayer, Shape, unique_name
 from analytics_zoo_tpu.keras.layers.moe import DECODER_INIT, SparseMoE
 from analytics_zoo_tpu.ops.attention import scaled_dot_product_attention
+from analytics_zoo_tpu.ops.ssd import chunked_scan
 
 
 def rms_norm(x, gain, eps: float):
@@ -336,11 +339,142 @@ class GatedShortConv(KerasLayer):
             return (c * conv) @ params["w_out"]
 
 
+def _uniform_init(bound: float):
+    def init(key, shape, dtype=jnp.float32):
+        return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+    return init
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """log(1 .. H): head j decays at rate j + 1."""
+    return jnp.log(jnp.arange(1, shape[0] + 1, dtype=dtype))
+
+
+def _dt_bias_init(lo: float, hi: float, floor: float):
+    """The inverse softplus of dt drawn log-uniform in [lo, hi], floored."""
+    def init(key, shape, dtype=jnp.float32):
+        dt = jnp.exp(jax.random.uniform(key, shape, dtype) * (
+            jnp.log(hi) - jnp.log(lo)) + jnp.log(lo))
+        dt = jnp.maximum(dt, floor)
+        return dt + jnp.log(-jnp.expm1(-dt))
+
+    return init
+
+
+class Mamba2Mixer(KerasLayer):
+    """The Mamba-2 token mixer over (B, S, d) (Dao & Gu 2024), as the
+    Nemotron-H layers hold it, no biases but the convolution's:
+
+        [z | xBC | dl] = W_in u        (d -> H P + (H P + 2 G N) + H)
+        xBC <- silu(causal depthwise conv over ``conv_kernel`` tokens + b)
+        [x | B | C] = xBC              (H heads of P; G groups of N)
+        dt = softplus(dl + dt_bias);   A = -exp(A_log)
+        y = chunked_scan(x, dt, A, B, C, D)        (ops/ssd.py)
+        out = W_out rms_G(y * silu(z); norm)       (the gate, then a norm over
+                                                    G groups of H P / G)
+
+    Head j reads group ``j // (H / G)`` of B and C and keeps a P x N state.
+    ``dt``, the decays and the states are float32, and so are ``a_log``,
+    ``dt_bias`` and ``d_skip`` whatever the block's compute type
+    (``float32_params``). Initial values are the family's: ``a_log`` =
+    log(1 .. H), ``d_skip`` = 1, ``dt_bias`` the inverse softplus of dt
+    log-uniform in ``time_step`` = (min, max) floored at its third entry,
+    the convolution's taps and bias uniform in +-1 / sqrt(kernel), the
+    matrices normal(0.02), ``w_out`` times ``out_scale``. A row must be a
+    whole number of ``chunk`` tokens. Scopes, none inside another:
+    ``ssm.proj_in`` (the input projection, the convolution, its silu and
+    dt), ``ssm.scan`` (the scan with the D skip), ``ssm.proj_out`` (the
+    gate, the grouped norm and ``w_out``)."""
+
+    block_key = "mamba"
+    float32_params = ("a_log", "dt_bias", "d_skip")
+
+    def __init__(self, n_heads: int, head_dim: int, n_groups: int,
+                 state_dim: int, conv_kernel: int = 4, chunk: int = 128,
+                 time_step=(0.001, 0.1, 1e-4), out_scale: float = 1.0,
+                 epsilon: float = 1e-5, input_shape=None, name=None):
+        super().__init__(input_shape, name or unique_name("mamba2"))
+        if n_heads % n_groups:
+            raise ValueError(f"{n_heads} heads do not share {n_groups} "
+                             f"groups evenly")
+        self.n_heads, self.head_dim = int(n_heads), int(head_dim)
+        self.n_groups, self.state_dim = int(n_groups), int(state_dim)
+        self.conv_kernel, self.chunk = int(conv_kernel), int(chunk)
+        self.time_step = tuple(float(t) for t in time_step)
+        self.out_scale, self.epsilon = float(out_scale), epsilon
+
+    @property
+    def inner(self) -> int:
+        """The heads' width together, H x P: of x, z and the output's input."""
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """The channels the convolution runs over: x, B and C."""
+        return self.inner + 2 * self.n_groups * self.state_dim
+
+    def kept_residuals(self) -> Tuple[str, ...]:
+        """Nothing: the whole half is recomputed, the scan included."""
+        return ()
+
+    def build(self, input_shape: Shape):
+        d, h = input_shape[-1], self.n_heads
+        bound = self.conv_kernel ** -0.5
+        self.add_weight("w_in", (d, self.inner + self.conv_dim + h),
+                        DECODER_INIT)
+        self.add_weight("conv_taps", (self.conv_dim, self.conv_kernel),
+                        _uniform_init(bound))
+        self.add_weight("conv_bias", (self.conv_dim,), _uniform_init(bound))
+        self.add_weight("dt_bias", (h,), _dt_bias_init(*self.time_step))
+        self.add_weight("a_log", (h,), _a_log_init)
+        self.add_weight("d_skip", (h,), "ones")
+        self.add_weight("norm", (self.inner,), "ones")
+        scale = self.out_scale
+
+        def out_init(key, shape, dtype=jnp.float32):
+            return DECODER_INIT(key, shape, dtype) * scale
+
+        self.add_weight("w_out", (self.inner, d), out_init)
+
+    def call(self, params, x, **kw):
+        b, s, _ = x.shape
+        h, p, g, n = self.n_heads, self.head_dim, self.n_groups, self.state_dim
+        f32 = jnp.float32
+        with jax.named_scope("ssm.proj_in"):
+            z, xbc, dl = jnp.split(x @ params["w_in"],
+                                   [self.inner, self.inner + self.conv_dim],
+                                   axis=-1)
+            padded = jnp.pad(xbc, ((0, 0), (self.conv_kernel - 1, 0), (0, 0)))
+            taps = params["conv_taps"].astype(f32)
+            conv = sum(taps[:, j] * padded[:, j:j + s].astype(f32)
+                       for j in range(self.conv_kernel))
+            xbc = jax.nn.silu(conv + params["conv_bias"].astype(f32)).astype(
+                x.dtype)
+            xs, bs, cs = jnp.split(xbc, [self.inner, self.inner + g * n],
+                                   axis=-1)
+            dt = jax.nn.softplus(dl.astype(f32) + params["dt_bias"])
+        with jax.named_scope("ssm.scan"):
+            y = chunked_scan(xs.reshape(b, s, h, p), dt,
+                             -jnp.exp(params["a_log"]),
+                             bs.reshape(b, s, g, n), cs.reshape(b, s, g, n),
+                             params["d_skip"], self.chunk)
+        with jax.named_scope("ssm.proj_out"):
+            gated = (y.reshape(b, s, self.inner).astype(f32)
+                     * jax.nn.silu(z.astype(f32)))
+            y = rms_norm(gated.reshape(b, s, g, -1),
+                         params["norm"].reshape(g, -1), self.epsilon)
+            return y.reshape(b, s, self.inner).astype(x.dtype) @ params["w_out"]
+
+
 class DecoderBlock(KerasLayer):
     """One decoder layer (see the module docstring). ``attn``: the token
-    mixer, a ``GroupedQueryAttention``, a ``LatentAttention`` or a
-    ``GatedShortConv``; ``mlp``: a
-    built-or-unbuilt ``SwiGLU`` or ``SparseMoE``. ``norms``: ``"sandwich"``,
+    mixer, a ``GroupedQueryAttention``, a ``LatentAttention``, a
+    ``GatedShortConv`` or a ``Mamba2Mixer``; ``mlp``: a
+    built-or-unbuilt ``SwiGLU`` or ``SparseMoE``. Either may be None, not
+    both: the block is then the other half alone with that half's norms
+    (``h += mixer(in_norm(h))`` or ``h += mlp(pre_mlp_norm(h))`` under
+    ``"pre"``). ``norms``: ``"sandwich"``,
     a norm before and after each half, or ``"pre"``, the two before alone.
     ``dtype``: the compute type its weights and input are cast to inside the
     block. ``remat``: rematerialise each half in the backward pass (a layer's
@@ -361,6 +495,7 @@ class DecoderBlock(KerasLayer):
 
     NORMS = ("in_norm", "post_attn_norm", "pre_mlp_norm", "post_mlp_norm")
     LAYOUTS = {"sandwich": NORMS, "pre": ("in_norm", "pre_mlp_norm")}
+    MIXER_NORMS = ("in_norm", "post_attn_norm")
 
     def __init__(self, attn: KerasLayer, mlp: KerasLayer,
                  epsilon: float = 1e-5, dtype: Optional[str] = "bfloat16",
@@ -370,6 +505,8 @@ class DecoderBlock(KerasLayer):
         if norms not in self.LAYOUTS:
             raise ValueError(f"unknown norm layout {norms!r}; known: "
                              f"{sorted(self.LAYOUTS)}")
+        if attn is None and mlp is None:
+            raise ValueError("a block with neither a mixer nor a feed-forward")
         self.mixer, self.mlp, self.norms = attn, mlp, norms
         # the attention mixer under the name it has always had; None on a
         # layer that has none
@@ -380,16 +517,23 @@ class DecoderBlock(KerasLayer):
         self.has_state = bool(getattr(mlp, "has_state", False))
 
     def build(self, input_shape: Shape):
-        self.mixer.ensure_built(input_shape)
-        self.mlp.ensure_built(input_shape)
+        names = self.LAYOUTS[self.norms]
+        for part, norms in ((self.mixer, self.MIXER_NORMS),
+                            (self.mlp, ("pre_mlp_norm", "post_mlp_norm"))):
+            if part is None:
+                names = [n for n in names if n not in norms]
+            else:
+                part.ensure_built(input_shape)
         self._norms = {n: RMSNorm(self.epsilon, name=f"{self.name}_{n}")
-                       for n in self.LAYOUTS[self.norms]}
+                       for n in names}
         for norm in self._norms.values():
             norm.ensure_built(input_shape)
 
     def _parts(self):
-        return {self.mixer.block_key: self.mixer, "mlp": self.mlp,
-                **self._norms}
+        parts = {} if self.mixer is None else {self.mixer.block_key: self.mixer}
+        if self.mlp is not None:
+            parts["mlp"] = self.mlp
+        return {**parts, **self._norms}
 
     def init_params(self, rng):
         return {key: part.init_params(jax.random.fold_in(rng, i))
@@ -402,14 +546,20 @@ class DecoderBlock(KerasLayer):
         return self.mlp.init_state() if self.has_state else {}
 
     def call(self, params, x, state=None, training=False, **kw):
-        eps, mixer_key = self.epsilon, self.mixer.block_key
+        eps = self.epsilon
+        mixer_key = None if self.mixer is None else self.mixer.block_key
 
         def after(p, name, y):
             return rms_norm(y, p[name]["gain"], eps) if name in p else y
 
         def mixer_half(p, h):
+            wide = getattr(self.mixer, "float32_params", ())
+            raw = p[mixer_key]
             with jax.named_scope("block.cast"):
                 p = _cast(p, self.dtype)
+            if wide:          # the mixer's float32 weights, left as they are
+                p[mixer_key] = dict(p[mixer_key],
+                                    **{k: raw[k] for k in wide})
             with jax.named_scope("block.norm"):
                 n = rms_norm(h, p["in_norm"]["gain"], eps)
             a = self.mixer.call(p[mixer_key], n)
@@ -434,18 +584,22 @@ class DecoderBlock(KerasLayer):
         # compacted pass included, runs twice (overflow chunks, rematerialised
         # one by one inside, three times).
         if self.remat:
-            kept = self.mixer.kept_residuals()
-            mixer_half = jax.checkpoint(
-                mixer_half,
-                policy=jax.checkpoint_policies.save_only_these_names(*kept)
-                if kept else None)
+            if self.mixer is not None:
+                kept = self.mixer.kept_residuals()
+                mixer_half = jax.checkpoint(
+                    mixer_half,
+                    policy=jax.checkpoint_policies.save_only_these_names(*kept)
+                    if kept else None)
             mlp_half = jax.checkpoint(mlp_half)
         if self.dtype is not None:
             with jax.named_scope("block.cast"):
                 x = x.astype(self.dtype)
-        mixer_keys = (mixer_key, "in_norm", "post_attn_norm")
-        h = mixer_half({k: params[k] for k in mixer_keys if k in params}, x)
-        h, new_state = mlp_half(
-            {k: v for k, v in params.items() if k not in mixer_keys}, h,
-            state if self.has_state else None)
+        mixer_keys = (mixer_key,) + self.MIXER_NORMS
+        h, new_state = x, None
+        if self.mixer is not None:
+            h = mixer_half({k: params[k] for k in mixer_keys if k in params}, h)
+        if self.mlp is not None:
+            h, new_state = mlp_half(
+                {k: v for k, v in params.items() if k not in mixer_keys}, h,
+                state if self.has_state else None)
         return (h, new_state) if self.has_state else h

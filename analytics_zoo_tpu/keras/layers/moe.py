@@ -93,13 +93,18 @@ class SparseMoE(KerasLayer):
     """Sigmoid top-k expert layer that drops nothing, with shared experts and
     a selection bias, over the experts this chip holds.
 
-    ``y = SwiGLU_shared(x) + sum over a token's k picks e of w_e SwiGLU_e(x)``:
+    ``y = FF_shared(x) + sum over a token's k picks e of w_e FF_e(x)``, each
+    ``FF`` a SwiGLU (``activation="swiglu"``: ``W_down(silu(W_gate x) * W_up
+    x)``, gate | up in one kernel) or a squared-ReLU feed-forward
+    (``"relu2"``: ``W_down relu(W_up x)^2``, an up kernel alone):
     ``s = sigmoid(W_r x)`` in float32 over all ``n_experts``; the ``top_k``
     largest of ``s + b`` are picked (``b``, the selection bias, is state, not
     a weight: it steers the pick and gets no gradient); ``w = s[picked]``,
     normalised to sum 1 (``route_norm``: over the sum plus ``route_eps``) and
     scaled by ``route_scale``. ``n_shared=0``: no shared expert, everything
-    the layer gives a token comes from the experts held.
+    the layer gives a token comes from the experts held; else one shared
+    feed-forward ``shared_width`` wide (``width * n_shared`` where not
+    given).
 
     ``experts_held = (offset, count)``: of the ``n_experts`` the router
     scores, this layer holds weights for ``count``, numbered from ``offset``
@@ -126,8 +131,14 @@ class SparseMoE(KerasLayer):
                  experts_held=None, n_shared: int = 1,
                  route_norm: bool = True, route_scale: float = 1.0,
                  bias_rate: float = 0.001, route_eps: float = 1e-20,
+                 activation: str = "swiglu", shared_width=None,
                  input_shape=None, name=None):
+        from analytics_zoo_tpu.parallel.moe import ACTIVATIONS
+
         super().__init__(input_shape, name or unique_name("sparse_moe"))
+        if activation not in ACTIVATIONS:
+            raise ValueError(f"unknown expert activation {activation!r}; "
+                             f"known: {sorted(ACTIVATIONS)}")
         self.n_experts, self.width, self.top_k = int(n_experts), int(width), int(top_k)
         offset, count = experts_held or (0, self.n_experts)
         if not (0 <= offset and count >= 1 and offset + count <= self.n_experts):
@@ -135,28 +146,36 @@ class SparseMoE(KerasLayer):
                              f"of the {self.n_experts} experts")
         self.experts_held = (int(offset), int(count))
         self.n_shared = int(n_shared)
+        self.shared_width = int(shared_width or self.width * self.n_shared)
+        self.activation = activation
         self.route_norm, self.route_scale = route_norm, float(route_scale)
         self.bias_rate, self.route_eps = float(bias_rate), float(route_eps)
 
     def build(self, input_shape: Shape):
         d, init = input_shape[-1], DECODER_INIT
         count = self.experts_held[1]
+        # the first kernel: gate | up side by side, or up alone
+        first, wide = (("w_gate_up", 2) if self.activation == "swiglu"
+                       else ("w_up", 1))
         self.add_weight("router", (d, self.n_experts), init)
         if self.n_shared:
-            self.add_weight("shared_w_gate_up",
-                            (d, 2 * self.width * self.n_shared), init)
-            self.add_weight("shared_w_down",
-                            (self.width * self.n_shared, d), init)
-        self.add_weight("experts_w_gate_up", (count, d, 2 * self.width), init)
+            self.add_weight("shared_" + first, (d, wide * self.shared_width),
+                            init)
+            self.add_weight("shared_w_down", (self.shared_width, d), init)
+        self.add_weight("experts_" + first, (count, d, wide * self.width),
+                        init)
         self.add_weight("experts_w_down", (count, self.width, d), init)
         self.add_state("select_bias", (self.n_experts,), "zeros")
         self.add_state("expert_tokens", (self.n_experts,), "zeros")
         self.add_state("compact", (), "zeros")
 
     def call(self, params, x, state=None, training=False, **kw):
-        from analytics_zoo_tpu.parallel.moe import held_experts_ffn, route_topk
+        from analytics_zoo_tpu.parallel.moe import (
+            ACTIVATIONS, held_experts_ffn, route_topk)
 
         state = state or self.init_state()
+        act = ACTIVATIONS[self.activation]
+        first = "w_gate_up" if self.activation == "swiglu" else "w_up"
         shape = x.shape
         flat = x.reshape(-1, shape[-1])
         with jax.named_scope("moe.route"):
@@ -165,14 +184,13 @@ class SparseMoE(KerasLayer):
                 self.route_norm, self.route_scale, self.route_eps)
         with jax.named_scope("moe.experts"):
             y, compact = held_experts_ffn(
-                flat, picked, weights, params["experts_w_gate_up"],
+                flat, picked, weights, params["experts_" + first],
                 params["experts_w_down"], self.n_experts,
-                self.experts_held[0])
+                self.experts_held[0], self.activation)
         if self.n_shared:
             with jax.named_scope("moe.shared"):
-                gate, up = jnp.split(flat @ params["shared_w_gate_up"], 2,
-                                     axis=-1)
-                y = y + (jax.nn.silu(gate) * up) @ params["shared_w_down"]
+                y = y + act(flat @ params["shared_" + first]) @ params[
+                    "shared_w_down"]
         if training:
             bias = state["select_bias"] + self.bias_rate * jnp.sign(
                 jnp.mean(counts) - counts)

@@ -1,9 +1,11 @@
 """Causal decoder-only language model over the decoder block library
 (keras/layers/decoder.py, keras/layers/moe.py): token embedding, a stack of
 ``DecoderBlock``s whose token mixer (``sliding_attention`` /
-``full_attention`` / ``latent_attention`` / ``conv``) and feed-forward kind
-(leading dense layers, then expert layers) come from the configuration, a
-final RMS norm and a head, its own or the embedding's transpose.
+``full_attention`` / ``latent_attention`` / ``conv`` / ``mamba``) and
+feed-forward kind (leading dense layers, then expert layers) come from the
+configuration, or, a layer one part alone, the mixer or the expert layer
+(``experts``), a final RMS norm and a head, its own or the embedding's
+transpose.
 ``apply`` ends in logits over the vocabulary held here, in the compute type;
 train it with ``compile(optimizer, loss="token_crossentropy_from_logits")``
 and ``Estimator.train`` / ``fit`` like any other ``KerasNet``.
@@ -27,7 +29,7 @@ from analytics_zoo_tpu.keras.engine.topology import KerasNet
 from analytics_zoo_tpu.keras.layers.core import Dense
 from analytics_zoo_tpu.keras.layers.decoder import (
     DecoderBlock, GatedShortConv, GroupedQueryAttention, LatentAttention,
-    RMSNorm, SwiGLU, rms_norm,
+    Mamba2Mixer, RMSNorm, SwiGLU, rms_norm,
 )
 from analytics_zoo_tpu.keras.layers.embeddings import Embedding
 from analytics_zoo_tpu.keras.layers.moe import DECODER_INIT, SparseMoE
@@ -122,10 +124,79 @@ def _deepseek_v3(cfg: Dict) -> Dict:
         tie_embeddings=cfg.get("tie_word_embeddings", False))
 
 
+# what `_nemotron_h` does not build, by key: the only value it takes
+_NEMOTRON_H_ONLY = {"mamba_proj_bias": False, "mlp_bias": False,
+                    "attention_bias": False, "use_bias": False,
+                    "use_conv_bias": True, "residual_in_fp32": False,
+                    "n_group": 1, "topk_group": 1, "mlp_hidden_act": "relu2",
+                    "mamba_hidden_act": "silu", "sliding_window": None}
+# a letter of `hybrid_override_pattern` -> the layer kind; `-` (a dense
+# feed-forward alone) is not built
+_NEMOTRON_H_LETTERS = {"M": "mamba", "*": "full_attention", "E": "experts"}
+
+
+def _nemotron_h(cfg: Dict) -> Dict:
+    """Nemotron-H: each layer ONE part behind one pre-norm, by the letters
+    of ``hybrid_override_pattern`` (``M`` a Mamba-2 mixer, ``*`` grouped-query
+    attention with no position, no q/k norm and no gate, ``E`` an expert
+    layer of squared-ReLU experts beside one shared expert
+    ``moe_shared_expert_intermediate_size`` wide, sigmoid top-k with a
+    selection bias); ``n_routed_experts`` counts the experts held here.
+    ``rope_theta`` and ``partial_rotary_factor`` are in the file and unused:
+    the family's attention carries no position. The Mamba output projection
+    starts scaled by 1 / sqrt(``rescale_prenorm_residual_layers``) (the
+    published depth where the file is cut; ``num_hidden_layers`` where
+    not said) under ``rescale_prenorm_residual``. ``bias_rate`` is the
+    training framework's, as for LFM2. Raises for what is not built: a
+    dense layer (``-``), a bias anywhere but the convolution's, a clamped
+    time step, float32 residuals, grouped routing, another activation."""
+    for key, only in _NEMOTRON_H_ONLY.items():
+        if cfg.get(key, only) != only:
+            raise NotImplementedError(
+                f"nemotron_h with {key}={cfg[key]!r}: only {only!r} is built")
+    lo, hi = cfg.get("time_step_limit", (0.0, None))
+    if lo != 0 or hi not in (None, float("inf")):
+        raise NotImplementedError(
+            f"nemotron_h with time_step_limit={cfg['time_step_limit']!r}: "
+            f"only [0, null] (no clamp) is built")
+    pattern = cfg["hybrid_override_pattern"]
+    unknown = sorted(set(pattern) - set(_NEMOTRON_H_LETTERS))
+    if unknown:
+        raise NotImplementedError(
+            f"nemotron_h layers {unknown} in the pattern: only "
+            f"{sorted(_NEMOTRON_H_LETTERS)} are built")
+    if len(pattern) != cfg["num_hidden_layers"]:
+        raise ValueError(f"a pattern of {len(pattern)} layers for "
+                         f"num_hidden_layers={cfg['num_hidden_layers']}")
+    layers = cfg.get("rescale_prenorm_residual_layers",
+                     cfg["num_hidden_layers"])
+    return dict(
+        layer_types=[_NEMOTRON_H_LETTERS[c] for c in pattern],
+        one_part=True, n_kv_head=cfg["num_key_value_heads"],
+        head_dim=cfg["head_dim"], rope_full_layers=False,
+        gated_attention=False, qk_norm=False, norms="pre",
+        epsilon=cfg["layer_norm_epsilon"], embed_scale=1.0,
+        mamba_heads=cfg["mamba_num_heads"],
+        mamba_head_dim=cfg["mamba_head_dim"], ssm_groups=cfg["n_groups"],
+        ssm_state=cfg["ssm_state_size"], conv_kernel=cfg["conv_kernel"],
+        ssm_chunk=cfg["chunk_size"],
+        time_step=(cfg["time_step_min"], cfg["time_step_max"],
+                   cfg["time_step_floor"]),
+        ssm_out_scale=(layers ** -0.5 if cfg["rescale_prenorm_residual"]
+                       else 1.0),
+        **_experts(cfg, "n_routed_experts"), expert_act="relu2",
+        n_shared=cfg["n_shared_experts"],
+        shared_width=cfg["moe_shared_expert_intermediate_size"],
+        route_norm=cfg["norm_topk_prob"],
+        route_scale=cfg["routed_scaling_factor"], route_eps=1e-20,
+        bias_rate=cfg.get("bias_rate", 0.001),
+        tie_embeddings=cfg.get("tie_word_embeddings", False))
+
+
 # a published config's `model_type` -> the constructor's arguments that its
 # own key names give
 _FAMILIES = {"afmoe": _afmoe, "lfm2_moe": _lfm2_moe,
-             "deepseek_v3": _deepseek_v3}
+             "deepseek_v3": _deepseek_v3, "nemotron_h": _nemotron_h}
 
 
 class CausalLM(KerasNet):
@@ -135,11 +206,18 @@ class CausalLM(KerasNet):
     else no positional encoding), ``"latent_attention"`` (a
     ``LatentAttention`` of ``qk_nope_dim``, ``qk_rope_dim``, ``v_dim`` and
     ``kv_rank``, rotary over neighbouring pairs;
-    ``n_kv_head`` and ``head_dim`` say nothing of it) or ``"conv"`` (a
-    ``GatedShortConv`` over ``conv_kernel`` tokens); the first
+    ``n_kv_head`` and ``head_dim`` say nothing of it), ``"conv"`` (a
+    ``GatedShortConv`` over ``conv_kernel`` tokens) or ``"mamba"`` (a
+    ``Mamba2Mixer`` of ``mamba_heads`` heads of ``mamba_head_dim``,
+    ``ssm_groups`` groups of B and C ``ssm_state`` wide, a convolution over
+    ``conv_kernel`` tokens, scanned in chunks of ``ssm_chunk``); the first
     ``num_dense_layers`` layers get a dense ``SwiGLU`` of ``dense_width``,
-    the others a ``SparseMoE``.
-    ``gated_attention``: the attention's sigmoid output gate. ``norms``: a
+    the others a ``SparseMoE`` of ``expert_act`` experts (``"swiglu"`` or
+    ``"relu2"``) beside a shared one ``shared_width`` wide (``expert_width *
+    n_shared`` where not given). ``one_part``: each layer is one part alone,
+    the kind's mixer, or, of kind ``"experts"``, the ``SparseMoE``.
+    ``gated_attention``: the attention's sigmoid output gate; ``qk_norm``:
+    its per-head RMS norm of q and k. ``norms``: a
     block's norm layout (``DecoderBlock``). ``embed_scale``: the embedding's
     multiplier (sqrt(hidden) with muP). ``tie_embeddings``: the head is the
     embedding's transpose, one leaf of the parameters whose gradient is the
@@ -150,7 +228,8 @@ class CausalLM(KerasNet):
     the drain."""
 
     MIXERS = ("sliding_attention", "full_attention", "latent_attention",
-              "conv")
+              "conv", "mamba")
+    EXPERTS_ALONE = "experts"
 
     def __init__(self, vocab_size: int, hidden_size: int,
                  layer_types: Sequence[str], n_head: int, n_kv_head: int,
@@ -165,6 +244,12 @@ class CausalLM(KerasNet):
                  conv_kernel: int = 3, norms: str = "sandwich",
                  tie_embeddings: bool = False, qk_nope_dim: int = 0,
                  qk_rope_dim: int = 0, v_dim: int = 0, kv_rank: int = 0,
+                 qk_norm: bool = True, one_part: bool = False,
+                 mamba_heads: int = 0, mamba_head_dim: int = 0,
+                 ssm_groups: int = 1, ssm_state: int = 0, ssm_chunk: int = 128,
+                 time_step: Tuple[float, float, float] = (0.001, 0.1, 1e-4),
+                 ssm_out_scale: float = 1.0, expert_act: str = "swiglu",
+                 shared_width: Optional[int] = None,
                  seq_len: Optional[int] = None,
                  dtype: Optional[str] = "bfloat16", remat: bool = True,
                  name: Optional[str] = None):
@@ -178,12 +263,20 @@ class CausalLM(KerasNet):
                                name=self.name + "_embed")
         self.embed.ensure_built((None, seq_len))
         self.blocks = []
+        kinds = self.MIXERS + ((self.EXPERTS_ALONE,) if one_part else ())
         for i, kind in enumerate(layer_types):
-            if kind not in self.MIXERS:
+            if kind not in kinds:
                 raise ValueError(f"layer {i}: unknown kind {kind!r}; known: "
-                                 f"{list(self.MIXERS)}")
+                                 f"{list(kinds)}")
             sliding = kind == "sliding_attention"
-            if kind == "conv":
+            if kind == self.EXPERTS_ALONE:
+                mixer = None
+            elif kind == "mamba":
+                mixer = Mamba2Mixer(
+                    mamba_heads, mamba_head_dim, ssm_groups, ssm_state,
+                    conv_kernel, ssm_chunk, time_step, ssm_out_scale, epsilon,
+                    name=f"{self.name}_l{i}_mamba")
+            elif kind == "conv":
                 mixer = GatedShortConv(conv_kernel,
                                        name=f"{self.name}_l{i}_conv")
             elif kind == "latent_attention":
@@ -196,14 +289,17 @@ class CausalLM(KerasNet):
                     window=sliding_window if sliding else None,
                     rope_theta=(rope_theta if sliding or rope_full_layers
                                 else None),
-                    gated=gated_attention, epsilon=epsilon,
+                    qk_norm=qk_norm, gated=gated_attention, epsilon=epsilon,
                     name=f"{self.name}_l{i}_attn")
-            if i < num_dense_layers:
+            if one_part and mixer is not None:
+                mlp = None
+            elif i < num_dense_layers:
                 mlp = SwiGLU(dense_width, name=f"{self.name}_l{i}_mlp")
             else:
                 mlp = SparseMoE(n_experts, expert_width, top_k, experts_held,
                                 n_shared, route_norm, route_scale, bias_rate,
-                                route_eps, name=f"{self.name}_l{i}_moe")
+                                route_eps, expert_act, shared_width,
+                                name=f"{self.name}_l{i}_moe")
             block = DecoderBlock(mixer, mlp, epsilon, dtype, remat, norms,
                                  name=f"{self.name}_l{i}")
             block.ensure_built((None, seq_len, hidden_size))
@@ -211,6 +307,7 @@ class CausalLM(KerasNet):
         self.conv_layers = sum(kind == "conv" for kind in layer_types)
         self.latent_layers = sum(kind == "latent_attention"
                                  for kind in layer_types)
+        self.ssm_layers = sum(kind == "mamba" for kind in layer_types)
         self.final_norm = RMSNorm(epsilon, name=self.name + "_final_norm")
         self.final_norm.ensure_built((None, seq_len, hidden_size))
         self.head = None
@@ -314,6 +411,8 @@ class CausalLM(KerasNet):
         # and through every latent-attention layer
         obs["latent_token_layers"].inc(
             float(stats["tokens"]) * self.latent_layers)
+        # and through every Mamba-2 layer
+        obs["ssm_token_layers"].inc(float(stats["tokens"]) * self.ssm_layers)
         counts = stats.get("expert_tokens")
         if counts is None:
             return

@@ -212,7 +212,7 @@ def _gmm(lhs, rhs, sizes, offset):
 
 
 def _held_chunk(x, picked, weights, w_gate_up, w_down, n_experts: int,
-                offset: int):
+                offset: int, activation: str = "swiglu"):
     """One chunk of tokens through the experts held: x (c, d), picked and
     weights (c, k). Returns (c, d) in x's dtype."""
     c, k = picked.shape
@@ -223,15 +223,14 @@ def _held_chunk(x, picked, weights, w_gate_up, w_down, n_experts: int,
     sizes = jnp.sum(jax.nn.one_hot(flat, n_experts, dtype=jnp.int32), axis=0)
     xs = _rows_to_sorted(x, order, inv, k)
     gate_up = _gmm(xs, w_gate_up, sizes, offset)
-    gate, up = jnp.split(gate_up, 2, axis=-1)
-    ys = _gmm(jax.nn.silu(gate) * up, w_down, sizes, offset)
+    ys = _gmm(ACTIVATIONS[activation](gate_up), w_down, sizes, offset)
     y = _rows_from_sorted(ys, order, inv).reshape(c, k, -1)
     return jnp.sum(y.astype(jnp.float32) * weights[..., None],
                    axis=1).astype(x.dtype)
 
 
 def _held_chunks(x, picked, weights, w_gate_up, w_down, n_experts: int,
-                 offset: int):
+                 offset: int, activation: str = "swiglu"):
     """All the tokens through :func:`_held_chunk`, ``_CHUNK_TOKENS`` at a
     time, each chunk rematerialised in the backward pass: buffers of
     chunk * k rows whatever the routing."""
@@ -240,7 +239,7 @@ def _held_chunks(x, picked, weights, w_gate_up, w_down, n_experts: int,
     if t % chunk:
         raise ValueError(f"{t} tokens do not divide into chunks of {chunk}")
     body = jax.checkpoint(lambda xc, pc, wc, a, b: _held_chunk(
-        xc, pc, wc, a, b, n_experts, offset))
+        xc, pc, wc, a, b, n_experts, offset, activation))
     if t == chunk:
         return body(x, picked, weights, w_gate_up, w_down)
     n = t // chunk
@@ -313,6 +312,16 @@ def _swiglu(gate_up):
     return jax.nn.silu(gate) * up
 
 
+def _relu2(up):
+    """(r, h) -> relu(up)^2 (r, h): the squared-ReLU expert, no gate."""
+    return jnp.square(jax.nn.relu(up))
+
+
+# an expert's activation by name, over what its first product gives: gate |
+# up side by side (2h wide) for "swiglu", up alone (h wide) for "relu2"
+ACTIVATIONS = {"swiglu": _swiglu, "relu2": _relu2}
+
+
 def _sum_of_held(rows, slot, held, weights=None):
     """(t, d): a token's float32 sum, over those of its picks that are
     ``held`` (t, k), of ``rows[slot]``, each times its weight where given.
@@ -332,7 +341,8 @@ def _sum_of_held(rows, slot, held, weights=None):
     return jnp.concatenate(out, axis=1).astype(rows.dtype)
 
 
-def _held_compact(x, weights, w_gate_up, w_down, key, sizes, rows: int):
+def _held_compact(x, weights, w_gate_up, w_down, key, sizes, rows: int,
+                  activation: str = "swiglu"):
     """Every token through the experts held in one pass over ``rows`` rows.
     ``key`` (t*k,): an assignment's expert among the ``count`` held, or
     ``count``; ``sizes`` (count,): the held experts' rows, ``rows`` or fewer
@@ -349,12 +359,13 @@ def _held_compact(x, weights, w_gate_up, w_down, key, sizes, rows: int):
     slot = jnp.where(held, slot.reshape(-1, k), 0)
     xs = x[token]
     gate_up = _held_gmm(xs, w_gate_up, sizes)
-    ys = _held_gmm(_swiglu(gate_up), w_down, sizes)
+    ys = _held_gmm(ACTIVATIONS[activation](gate_up), w_down, sizes)
     y = _sum_of_held(ys, slot, held, weights)
     return y, (token, source, slot, xs, gate_up, ys)
 
 
-def _held_compact_bwd(kept, weights, w_gate_up, w_down, key, sizes, g):
+def _held_compact_bwd(kept, weights, w_gate_up, w_down, key, sizes, g,
+                      activation: str = "swiglu"):
     """The gradients of :func:`_held_compact` to x, weights and both expert
     tensors, from ``g`` (t, d): gathers of r rows and the grouped products'
     own transposes; the sum of a token's rows is float32 as forward."""
@@ -368,34 +379,35 @@ def _held_compact_bwd(kept, weights, w_gate_up, w_down, key, sizes, g):
     d_weights = jnp.zeros(key.shape, jnp.float32).at[source].set(
         d_row, unique_indices=True).reshape(slot.shape)
     d_weights = jnp.where(held, d_weights, 0).astype(weights.dtype)
-    hidden, swiglu_vjp = jax.vjp(_swiglu, gate_up)
+    hidden, act_vjp = jax.vjp(ACTIVATIONS[activation], gate_up)
     d_down = _held_tgmm(hidden, d_ys, sizes)
-    (d_gate_up,) = swiglu_vjp(_held_gmm(d_ys, w_down, sizes, True))
+    (d_gate_up,) = act_vjp(_held_gmm(d_ys, w_down, sizes, True))
     d_gate_up_w = _held_tgmm(xs, d_gate_up, sizes)
     d_xs = _held_gmm(d_gate_up, w_gate_up, sizes, True)
     return _sum_of_held(d_xs, slot, held), d_weights, d_gate_up_w, d_down
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
 def _held_ffn(x, picked, weights, w_gate_up, w_down, key, sizes, compact,
-              n_experts: int, offset: int):
+              n_experts: int, offset: int, activation: str):
     """One pass over the compacted rows where ``compact`` says they fit, the
     chunks of all t*k rows where not; the backward pass takes the same
     side."""
     return _held_ffn_fwd(x, picked, weights, w_gate_up, w_down, key, sizes,
-                         compact, n_experts, offset)[0]
+                         compact, n_experts, offset, activation)[0]
 
 
 def _held_ffn_fwd(x, picked, weights, w_gate_up, w_down, key, sizes, compact,
-                  n_experts, offset):
+                  n_experts, offset, activation):
     rows = _held_capacity(*picked.shape, sizes.shape[0], n_experts)
 
     def one_pass():
-        return _held_compact(x, weights, w_gate_up, w_down, key, sizes, rows)
+        return _held_compact(x, weights, w_gate_up, w_down, key, sizes, rows,
+                             activation)
 
     def chunks():
         y = _held_chunks(x, picked, weights, w_gate_up, w_down, n_experts,
-                         offset)
+                         offset, activation)
         kept = jax.eval_shape(one_pass)[1]
         return y, jax.tree_util.tree_map(
             lambda s: jnp.zeros(s.shape, s.dtype), kept)
@@ -405,20 +417,20 @@ def _held_ffn_fwd(x, picked, weights, w_gate_up, w_down, key, sizes, compact,
                compact)
 
 
-def _held_ffn_bwd(n_experts, offset, res, g):
+def _held_ffn_bwd(n_experts, offset, activation, res, g):
     kept, x, picked, weights, w_gate_up, w_down, key, sizes, compact = res
 
     def chunks():
         _, vjp = jax.vjp(
             lambda x_, w_, a, b: _held_chunks(x_, picked, w_, a, b, n_experts,
-                                              offset),
+                                              offset, activation),
             x, weights, w_gate_up, w_down)
         return vjp(g)
 
     d_x, d_weights, d_gate_up, d_down = jax.lax.cond(
         compact,
         lambda: _held_compact_bwd(kept, weights, w_gate_up, w_down, key,
-                                  sizes, g),
+                                  sizes, g, activation),
         chunks)
     return d_x, None, d_weights, d_gate_up, d_down, None, None, None
 
@@ -427,15 +439,18 @@ _held_ffn.defvjp(_held_ffn_fwd, _held_ffn_bwd)
 
 
 def held_experts_ffn(x, picked, weights, w_gate_up, w_down, n_experts: int,
-                     offset: int = 0):
-    """The held experts' part of a top-k SwiGLU expert layer, nothing
-    dropped: ``sum over the picks of a token that fall on a held expert of
-    weight * W_down_e(silu(W_gate_e x) * W_up_e x)``. ``x`` (T, d);
-    ``picked`` / ``weights`` (T, k) from :func:`route_topk` (expert numbers
-    over all ``n_experts``); ``w_gate_up`` (count, d, 2h) with gate and up
-    side by side, ``w_down`` (count, h, d): the experts numbered ``offset``
-    .. ``offset + count - 1``. On one chip no exchange is made: what the
-    absent experts would add is left out.
+                     offset: int = 0, activation: str = "swiglu"):
+    """The held experts' part of a top-k expert layer, nothing dropped:
+    ``sum over the picks of a token that fall on a held expert of weight *
+    W_down_e act(W_in_e x)``. ``x`` (T, d); ``picked`` / ``weights`` (T, k)
+    from :func:`route_topk` (expert numbers over all ``n_experts``);
+    ``w_gate_up`` the experts' first kernel, ``w_down`` (count, h, d): the
+    experts numbered ``offset`` .. ``offset + count - 1``. ``activation``
+    (``ACTIVATIONS``): ``"swiglu"``, ``silu(W_gate x) * W_up x`` from a
+    ``(count, d, 2h)`` kernel with gate and up side by side, or ``"relu2"``,
+    ``relu(W_up x)^2`` from an up kernel ``(count, d, h)`` alone; one path
+    for both, the activation its data. On one chip no exchange is made: what
+    the absent experts would add is left out.
 
     Returns ``(y, compact)``. The held assignments go through in one pass
     over a buffer of :func:`_held_capacity` rows, twice the held experts'
@@ -450,10 +465,10 @@ def held_experts_ffn(x, picked, weights, w_gate_up, w_down, n_experts: int,
     rows = _held_capacity(t, k, count, n_experts)
     if rows >= t * k:
         return (_held_chunks(x, picked, weights, w_gate_up, w_down, n_experts,
-                             offset), jnp.zeros((), jnp.bool_))
+                             offset, activation), jnp.zeros((), jnp.bool_))
     local = picked.reshape(-1) - offset
     key = jnp.where((local >= 0) & (local < count), local, count)
     sizes = jnp.sum(jax.nn.one_hot(key, count, dtype=jnp.int32), axis=0)
     compact = jnp.sum(sizes) <= rows
     return _held_ffn(x, picked, weights, w_gate_up, w_down, key, sizes,
-                     compact, n_experts, offset), compact
+                     compact, n_experts, offset, activation), compact

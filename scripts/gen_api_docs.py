@@ -50,7 +50,8 @@ PAGES = {
         "TransformerLayer/BERT blocks, sequence- and pipeline-parallel "
         "attention (ref APIGuide/PipelineAPI/keras-api transformer rows); "
         "the decoder block library (grouped-query and latent attention, "
-        "gated short convolution, rotary embedding, SwiGLU, DecoderBlock).",
+        "gated short convolution, the Mamba-2 mixer, rotary embedding, "
+        "SwiGLU, DecoderBlock).",
         ["analytics_zoo_tpu.keras.layers.attention",
          "analytics_zoo_tpu.keras.layers.decoder"]),
     "keras-layers-extras": (
@@ -331,13 +332,14 @@ PAGES = {
          "analytics_zoo_tpu.parallel.pipeline",
          "analytics_zoo_tpu.parallel.moe"]),
     "ops": (
-        "Ops — attention, flash kernels, q/k norm and rotary, bbox",
+        "Ops — attention, flash kernels, q/k norm and rotary, SSD scan, bbox",
         "The hot-op layer: dispatchered attention, the Pallas flash "
-        "kernels, the fused q/k head norm and rotary, padded NMS "
-        "(SURVEY §2.3).",
+        "kernels, the fused q/k head norm and rotary, the chunked "
+        "state-space scan, padded NMS (SURVEY §2.3).",
         ["analytics_zoo_tpu.ops.attention",
          "analytics_zoo_tpu.ops.flash_attention",
          "analytics_zoo_tpu.ops.qk_rotary",
+         "analytics_zoo_tpu.ops.ssd",
          "analytics_zoo_tpu.ops.bbox"]),
 }
 

@@ -99,6 +99,8 @@ SPECS = {
     "GroupedQueryAttention": (dict(n_head=4, n_kv_head=2, head_dim=4,
                                    window=3, rope_theta=10000.0), SEQ8),
     "GatedShortConv": (dict(kernel=3), SEQ8),
+    "Mamba2Mixer": (dict(n_heads=2, head_dim=4, n_groups=1, state_dim=4,
+                         chunk=3), SEQ8),
     "LatentAttention": (dict(n_head=2, qk_nope_dim=4, qk_rope_dim=2, v_dim=4,
                              kv_rank=4), SEQ8),
     "RMSNorm": (dict(), (8,)),

@@ -1,11 +1,13 @@
-"""Every operation of a decoder's train step under one named scope (PR 39):
-the nine that the benchmark's readers and drivers name and the eight beside
-them, over the three decoder families at the tiny sizes of their own tests,
-in bfloat16 with the blocks rematerialised, through the estimator's own step
-on the CPU. A scope is metadata: the compiled program is the same without
-the eight."""
+"""Every operation of a decoder's train step under one named scope: the nine
+that the benchmark's readers and drivers name, the eight beside them, and the
+Mamba-2 mixer's three, over the four decoder families at the tiny sizes of
+their own tests, in bfloat16 with the blocks rematerialised, through the
+estimator's own step on the CPU. A scope is metadata: the compiled program is
+the same without the eleven. And the SwiGLU families' steps compile to what
+they compiled to before the expert path took its activation as data."""
 
 import contextlib
+import hashlib
 import re
 
 import jax
@@ -15,13 +17,24 @@ import pytest
 from test_causal_lm import CFG as AFMOE
 from test_kanana2 import CFG as DEEPSEEK_V3
 from test_lfm2 import CFG as LFM2_MOE
+from test_nemotronh import CFG as NEMOTRON_H
 
 ACCEPTED = ("attn.window", "attn.full", "attn.latent", "conv.short",
             "moe.route", "moe.experts", "moe.shared", "lm.loss", "optimizer")
+SSM = ("ssm.proj_in", "ssm.scan", "ssm.proj_out")
 NEW = ("attn.proj_in", "attn.qk_rotary", "attn.proj_out", "block.norm",
-       "block.cast", "mlp.dense", "lm.embed", "lm.head")
+       "block.cast", "mlp.dense", "lm.embed", "lm.head") + SSM
 SCOPES = ACCEPTED + NEW
-FAMILIES = {"afmoe": AFMOE, "lfm2_moe": LFM2_MOE, "deepseek_v3": DEEPSEEK_V3}
+FAMILIES = {"afmoe": AFMOE, "lfm2_moe": LFM2_MOE, "deepseek_v3": DEEPSEEK_V3,
+            "nemotron_h": NEMOTRON_H}
+# `benchmark.scope_shares.program_text` of each SwiGLU family's compiled step
+# as it stood before `held_experts_ffn` took the expert's activation as data
+SWIGLU_STEPS = {
+    "afmoe": "55b955b12c99bc7b864dce76e62eb7f6edd4d954472fc8ff7d343b0ffeeee751",
+    "deepseek_v3":
+        "eb87b296fd436b0711a161896589c0433c411efcb8aa49522ad7c6823ab2c2d1",
+    "lfm2_moe":
+        "a368380daf70371d5e8416eb915d0df838688e60449b37492480b30197fc959a"}
 # the instructions a device's time goes to besides elementwise passes
 WORK = ("dot", "gather", "scatter", "convolution", "custom-call")
 NAMED_SCOPE = jax.named_scope
@@ -54,14 +67,14 @@ def _accepted_only(name):
 @pytest.fixture(scope="module", params=sorted(FAMILIES))
 def step(request):
     """The family's step: its un-optimized HLO with source information, its
-    compiled text, and the compiled text of the same step built with the
-    eight new scopes taken out."""
+    compiled text, the compiled text of the same step built with the
+    eleven scopes outside the benchmark's nine taken out, and the family."""
     lowered = _lowered(FAMILIES[request.param])
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(jax, "named_scope", _accepted_only)
         bare = _lowered(FAMILIES[request.param]).compile().as_text()
     return (lowered.as_text("hlo", debug_info=True),
-            lowered.compile().as_text(), bare)
+            lowered.compile().as_text(), bare, request.param)
 
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
@@ -119,13 +132,14 @@ def _named(full: str):
 
 
 def test_no_scope_name_holds_another_and_the_benchmark_names_the_nine():
-    from benchmark import fit_kanana2, fit_lfm2, trace_lm
+    from benchmark import fit_kanana2, fit_lfm2, fit_nemotronh, trace_lm
 
-    assert len(set(SCOPES)) == 17
+    assert len(set(SCOPES)) == 20
     for a in SCOPES:
         assert [b for b in SCOPES if a in b] == [a], a
     assert set(trace_lm.SCOPES + fit_lfm2.SCOPES + fit_kanana2.SCOPES) == set(
         ACCEPTED)
+    assert fit_nemotronh.SCOPES == SSM
 
 
 def test_every_product_look_up_and_kernel_is_under_one_scope(step):
@@ -140,19 +154,32 @@ def test_no_op_name_names_two_scopes(step):
     names = _full_names(step[0])
     both = {full for _, full in names if len(_named(full)) > 1}
     assert not both, sorted(both)[:10]
-    # and every one of the eight is in the step of some family (the dense
-    # feed-forward, rotary and the input projection are in all three)
+    # and every one of the eleven is in the step of some family (the dense
+    # feed-forward, rotary and the input projection are in the first three;
+    # the Mamba mixer's three scopes in nemotron_h's, which has no dense
+    # layer)
     found = {s for _, full in names for s in _named(full)}
-    assert {"block.norm", "block.cast", "mlp.dense", "lm.embed", "lm.head",
+    assert {"block.norm", "block.cast", "lm.embed", "lm.head",
             "attn.proj_out"} <= found
+    assert ("mlp.dense" in found) == (step[3] != "nemotron_h")
+    assert set(SSM) & found == (set(SSM) if step[3] == "nemotron_h"
+                                else set())
 
 
 def test_the_scopes_are_metadata_only(step):
     from benchmark.scope_shares import program_text
 
-    _, compiled, bare = step
-    assert compiled != bare                 # the eight are in its metadata
+    _, compiled, bare, _ = step
+    assert compiled != bare                 # the eleven are in its metadata
     assert program_text(compiled) == program_text(bare)
+
+
+@pytest.mark.parametrize("family", sorted(SWIGLU_STEPS))
+def test_the_swiglu_steps_compile_as_before_the_squared_relu_experts(family):
+    from benchmark.scope_shares import program_text
+
+    text = program_text(_lowered(FAMILIES[family]).compile().as_text())
+    assert hashlib.sha256(text.encode()).hexdigest() == SWIGLU_STEPS[family]
 
 
 # Where the fused q / k norm and rotary kernels (``ops/qk_rotary.py``) run:
