@@ -1211,6 +1211,17 @@ def qk_rotary_built() -> MetricFamily:
         labels=("path",))
 
 
+def ssd_built() -> MetricFamily:
+    """``zoo_ssd_built_total{path="kernel"|"xla"}``: builds of a Mamba-2
+    layer's chunked scan (``ops/ssd.py`` ``chunked_scan``), one a call each
+    time it is traced, by the path it took: the Pallas kernels
+    ``zoo_ssd_fwd`` / ``zoo_ssd_bwd`` or XLA's ``jax.numpy`` form."""
+    return get_registry().counter(
+        "zoo_ssd_built_total",
+        "Builds of the Mamba-2 chunked scan, by path (kernel/xla).",
+        labels=("path",))
+
+
 def distributed_metrics() -> Dict[str, Any]:
     """The multi-host training metric children in the global registry
     (:mod:`analytics_zoo_tpu.ft.distributed` + ``train_distributed``):

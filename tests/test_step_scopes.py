@@ -205,3 +205,28 @@ def test_the_fused_q_k_kernels_run_under_the_rotary_scope(family,
         assert any(kernel in full for full in names), kernel
     outside = [full for full in names if _named(full) != ["attn.qk_rotary"]]
     assert not outside, outside[:10]
+
+
+# Where the chunked scan's kernels (``ops/ssd.py``) run: on the chip at head
+# widths that divide a lane tile, B and C a multiple of 128 wide; here in
+# interpret mode, with the dispatcher told it is on the chip.
+SSD_SHAPE = dict(mamba_head_dim=64, ssm_state_size=128, chunk_size=64,
+                 seq_len=128)
+
+
+def test_the_scan_kernels_run_under_the_scan_scope(monkeypatch):
+    """Forward, the rematerialised forward and the backward: every
+    ``zoo_ssd_fwd`` / ``zoo_ssd_bwd`` of the nemotron_h step sits under
+    ``ssm.scan`` alone."""
+    from analytics_zoo_tpu.ops import ssd
+
+    monkeypatch.setattr(ssd, "_on_chip", lambda: True)
+    lowered = _lowered(dict(NEMOTRON_H, **SSD_SHAPE))
+    names = [full for _, full in _full_names(
+        lowered.as_text("hlo", debug_info=True)) if "zoo_ssd" in full]
+    for kernel in ("zoo_ssd_fwd", "zoo_ssd_bwd"):
+        assert any(kernel in full for full in names), kernel
+    assert any("rematted_computation" in full and "zoo_ssd_fwd" in full
+               for full in names)
+    outside = [full for full in names if _named(full) != ["ssm.scan"]]
+    assert not outside, outside[:10]
