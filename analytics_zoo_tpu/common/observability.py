@@ -1201,13 +1201,16 @@ def flash_backward_built() -> MetricFamily:
 
 
 def qk_rotary_built() -> MetricFamily:
-    """``zoo_qk_rotary_built_total{path="kernel"|"xla"}``: builds of the
-    per-head norm and rotary of queries or keys (``ops/qk_rotary.py``
-    ``norm_rotary``), one a tensor each time it is traced, by the path it
-    took: the fused Pallas kernels or XLA's two functions."""
+    """``zoo_qk_rotary_built_total{path="kernel"|"xla"|"kernel_scaled"|
+    "xla_scaled"}``: builds of the per-head norm and rotary of queries or
+    keys (``ops/qk_rotary.py`` ``norm_rotary``), one a tensor each time it
+    is traced, by the path it took: the fused Pallas kernels or XLA's two
+    functions, ``_scaled`` where the rotary spec scales its frequencies and
+    magnitude (YaRN: another table in the same kernels)."""
     return get_registry().counter(
         "zoo_qk_rotary_built_total",
-        "Builds of the per-head q/k norm and rotary, by path (kernel/xla).",
+        "Builds of the per-head q/k norm and rotary, by path (kernel/xla, "
+        "_scaled for YaRN tables).",
         labels=("path",))
 
 
@@ -1316,7 +1319,13 @@ def lm_train_metrics() -> Dict[str, Any]:
     layers of training steps, the mixer's work as it ran),
     ``latent_token_layers`` (counter ``zoo_lm_latent_token_layers_total``:
     the same for latent-attention layers), ``ssm_token_layers`` (counter
-    ``zoo_lm_ssm_token_layers_total``: the same for Mamba-2 layers). One
+    ``zoo_lm_ssm_token_layers_total``: the same for Mamba-2 layers),
+    ``window_pairs`` (counter ``zoo_lm_window_pairs_total``: the (query, key)
+    pairs inside the windows of sliding-window attention layers, summed over
+    those layers: a step's rows x sum over a row's queries i of min(i + 1,
+    window) x the window layers), ``balance`` (summary
+    ``zoo_moe_balance_loss``: the expert layers' balance term that the
+    step's loss added, a sample a step, from models that have one). One
     call per model — the model holds the children."""
     reg = get_registry()
     assignments = reg.counter(
@@ -1356,6 +1365,14 @@ def lm_train_metrics() -> Dict[str, Any]:
             "zoo_lm_ssm_token_layers_total",
             "Tokens times Mamba-2 state-space layers a language model's "
             "train steps computed.").labels(),
+        "window_pairs": reg.counter(
+            "zoo_lm_window_pairs_total",
+            "Query-key pairs inside the windows of sliding-window attention "
+            "layers that a language model's train steps computed.").labels(),
+        "balance": reg.summary(
+            "zoo_moe_balance_loss",
+            "The expert layers' balance term of the training loss, a sample "
+            "a step.").labels(),
     }
 
 

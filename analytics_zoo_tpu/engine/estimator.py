@@ -18,6 +18,10 @@ Model protocol (duck-typed; KerasNet and nnframes both implement it):
   init(rng) -> (params, model_state)
   apply(params, model_state, x, training, rng) -> (y, new_model_state)
   regularization(params) -> scalar
+  auxiliary_loss(model_state) -> scalar   (optional: a term of the training
+      loss that the forward pass computes, e.g. an expert layer's balance
+      term, from the state ``apply`` returned; the reported loss stays the
+      criterion's)
 """
 
 from __future__ import annotations
@@ -1042,6 +1046,7 @@ class Estimator:
         model = self.model
         cast = self._cast_for_compute
         ps_criterion = objectives_lib.get_per_sample(criterion)
+        aux_fn = getattr(model, "auxiliary_loss", None)
 
         def _reduce_rows(ps, mask):
             """Masked/unmasked mean of a per-sample loss vector, plus the
@@ -1079,6 +1084,10 @@ class Estimator:
                     count = jnp.asarray(
                         jax.tree_util.tree_leaves(y)[0].shape[0], jnp.float32)
             reg = model.regularization(params)
+            if aux_fn is not None:
+                # a term the forward pass computed (a balance term of the
+                # experts): in the gradient, not in the reported loss
+                reg = reg + aux_fn(new_state)
             return loss + reg, (new_state, loss, count)
 
         opt_shardings = None
@@ -1831,6 +1840,7 @@ class Estimator:
         cast = self._cast_for_compute
         ps_criterion = objectives_lib.get_per_sample(criterion)
         update_mask = self._update_mask(self.tstate.params)
+        aux_fn = getattr(model, "auxiliary_loss", None)
 
         def _reduce_rows(ps, mask):
             if mask is None:
@@ -1856,6 +1866,8 @@ class Estimator:
                         jax.tree_util.tree_leaves(y)[0].shape[0],
                         jnp.float32)
             reg = model.regularization(params)
+            if aux_fn is not None:
+                reg = reg + aux_fn(new_state)
             return loss + reg, (new_state, loss, count)
 
         def step(params, model_state, opt_state, xs, y, mask, rng):

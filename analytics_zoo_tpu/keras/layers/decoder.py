@@ -30,7 +30,7 @@ Pallas flash kernels past the size threshold on the chip, XLA elsewhere.
 from __future__ import annotations
 
 import itertools
-from typing import Optional, Tuple
+from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from analytics_zoo_tpu.keras.engine.base import KerasLayer, Shape, unique_name
 from analytics_zoo_tpu.keras.layers.moe import DECODER_INIT, SparseMoE
 from analytics_zoo_tpu.ops.attention import scaled_dot_product_attention
+from analytics_zoo_tpu.ops.rotary import Rotary, as_rotary, frequencies
 from analytics_zoo_tpu.ops.ssd import chunked_scan
 
 
@@ -48,16 +49,18 @@ def rms_norm(x, gain, eps: float):
     return (y * gain.astype(jnp.float32)).astype(x.dtype)
 
 
-def rotary_embedding(x, theta: float = 10000.0, positions=None,
+def rotary_embedding(x, theta=10000.0, positions=None,
                      interleaved: bool = False):
     """Rotary position embedding (Su et al. 2021): x (..., seq, head_dim);
     position t of a row is t unless ``positions`` (seq,) says otherwise.
-    Frequency i turns one pair of x's entries by ``t / theta^(2i / head_dim)``:
-    in the half-rotated form the pair ``(x[i], x[i + head_dim / 2])``,
-    ``interleaved`` the neighbours ``(x[2i], x[2i + 1])``. Angles in float32,
-    the result in x's dtype."""
+    ``theta``: a base, or an ``ops.rotary.Rotary`` spec (YaRN's scaled
+    frequencies and magnitude). Frequency i turns one pair of x's entries by
+    ``t * inv_i`` (``ops.rotary.frequencies``; ``inv_i = 1 / theta^(2i /
+    head_dim)`` unscaled): in the half-rotated form the pair ``(x[i], x[i +
+    head_dim / 2])``, ``interleaved`` the neighbours ``(x[2i], x[2i + 1])``.
+    Angles in float32, the result in x's dtype."""
     s, hd = x.shape[-2], x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    inv, magnitude = frequencies(as_rotary(theta), hd)
     pos = (jnp.arange(s, dtype=jnp.float32) if positions is None
            else positions.astype(jnp.float32))
     ang = pos[:, None] * inv[None]
@@ -71,7 +74,10 @@ def rotary_embedding(x, theta: float = 10000.0, positions=None,
         ang = jnp.concatenate([ang, ang], axis=-1)
         x1, x2 = xf[..., : hd // 2], xf[..., hd // 2:]
         rotated = jnp.concatenate([-x2, x1], axis=-1)
-    return (xf * jnp.cos(ang) + rotated * jnp.sin(ang)).astype(x.dtype)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if magnitude != 1.0:
+        cos, sin = cos * magnitude, sin * magnitude
+    return (xf * cos + rotated * sin).astype(x.dtype)
 
 
 def _flash_residuals() -> Tuple[str, ...]:
@@ -129,7 +135,9 @@ class GroupedQueryAttention(KerasLayer):
     over a head's width, learned gain. ``gated``: a fourth projection g, the
     output is ``W_o (attn * sigmoid(g))``. ``window``: None for full causal
     attention, else query i sees keys i - window < j <= i. ``rope_theta``:
-    None for no positional encoding, else rotary embedding on q and k. One
+    None for no positional encoding, else rotary embedding on q and k by a
+    base or by an ``ops.rotary.Rotary`` spec (YaRN's scaled frequencies and
+    magnitude among them). One
     fused input kernel ``w_in`` (d, (2 n_head [gated] or n_head + 2 n_kv_head)
     * head_dim) laid out q | k | v | g. With ``qk_norm`` the head norm and
     rotary of q and of k are one op, ``ops.qk_rotary.norm_rotary`` (fused
@@ -144,7 +152,8 @@ class GroupedQueryAttention(KerasLayer):
 
     def __init__(self, n_head: int, n_kv_head: int, head_dim: int,
                  window: Optional[int] = None,
-                 rope_theta: Optional[float] = None, qk_norm: bool = True,
+                 rope_theta: Union[None, float, Rotary] = None,
+                 qk_norm: bool = True,
                  gated: bool = True, epsilon: float = 1e-5,
                  input_shape=None, name=None):
         super().__init__(input_shape, name or unique_name("gqa"))

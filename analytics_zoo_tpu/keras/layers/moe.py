@@ -1,7 +1,7 @@
 """MoE as layers — expert feed-forwards in the standard layer library:
-``MoE`` (top-1, capacity, Switch) and ``SparseMoE`` (sigmoid top-k, nothing
-dropped, shared experts, a share of the experts held: today's sparse
-decoders).
+``MoE`` (top-1, capacity, Switch) and ``SparseMoE`` (sigmoid or softmax
+top-k, nothing dropped, shared experts, a share of the experts held, a
+balance term for the loss: today's sparse decoders).
 
 Wraps :mod:`analytics_zoo_tpu.parallel.moe` (top-1 dispatch/combine, the
 Mesh-TF/Switch formulation) as a KerasLayer with a residual connection, so
@@ -90,8 +90,8 @@ DECODER_INIT = normal_init(0.02)
 
 
 class SparseMoE(KerasLayer):
-    """Sigmoid top-k expert layer that drops nothing, with shared experts and
-    a selection bias, over the experts this chip holds.
+    """Top-k expert layer that drops nothing, with shared experts and a
+    selection bias or a balance term, over the experts this chip holds.
 
     ``y = FF_shared(x) + sum over a token's k picks e of w_e FF_e(x)``, each
     ``FF`` a SwiGLU (``activation="swiglu"``: ``W_down(silu(W_gate x) * W_up
@@ -101,10 +101,12 @@ class SparseMoE(KerasLayer):
     largest of ``s + b`` are picked (``b``, the selection bias, is state, not
     a weight: it steers the pick and gets no gradient); ``w = s[picked]``,
     normalised to sum 1 (``route_norm``: over the sum plus ``route_eps``) and
-    scaled by ``route_scale``. ``n_shared=0``: no shared expert, everything
-    the layer gives a token comes from the experts held; else one shared
-    feed-forward ``shared_width`` wide (``width * n_shared`` where not
-    given).
+    scaled by ``route_scale``. ``scores="softmax"``: ``s = softmax(W_r x)``
+    over all ``n_experts`` and the ``top_k`` most probable picked, ``b`` left
+    out (``parallel/moe.py`` ``route_topk``). ``n_shared=0``: no shared
+    expert, everything the layer gives a token comes from the experts held;
+    else one shared feed-forward ``shared_width`` wide (``width * n_shared``
+    where not given).
 
     ``experts_held = (offset, count)``: of the ``n_experts`` the router
     scores, this layer holds weights for ``count``, numbered from ``offset``
@@ -112,7 +114,10 @@ class SparseMoE(KerasLayer):
     them and computes the part of the sum its own experts give; on one chip
     no exchange is made and the absent experts' part is left out. Default:
     all of them. No capacity: shapes are fixed whatever the routing
-    (parallel/moe.py ``held_experts_ffn``).
+    (parallel/moe.py ``held_experts_ffn``): the held rows go through one
+    compacted pass where they fit its buffer (twice the uniform share), else
+    in chunks, and always in chunks where nothing steers the load
+    (``compact``).
 
     State, returned updated from a training call as batch-norm statistics
     are: ``select_bias`` (n_experts,), after a step
@@ -120,9 +125,18 @@ class SparseMoE(KerasLayer):
     step's tokens routed to expert e (the auxiliary-loss-free balancing of
     Wang et al. 2024); ``expert_tokens`` (n_experts,), that ``n`` itself, and
     ``compact`` (), 1 where the step's held assignments went through in one
-    compacted pass and 0 where they overflowed it or no such pass exists
-    (``held_experts_ffn``): ``Estimator.train`` hands both to the counters
+    compacted pass and 0 where they overflowed it, no such pass exists or
+    the layer takes none (``held_experts_ffn``): ``Estimator.train`` hands both to the counters
     with the loss.
+
+    ``balance_coef`` > 0 (softmax scores): a training call also leaves the
+    statistics of the balance term of Switch / Mixtral training in its state,
+    over all ``n_experts``: ``balance_f``, the tokens that picked each expert
+    over the tokens (``counts / T``, summing to ``top_k``), and
+    ``balance_p``, each expert's mean probability (which carries the
+    gradient), under the scope ``moe.balance``. The model pools them over
+    its expert layers into ``balance_coef * n_experts * sum(f * p)``
+    (``models/causal_lm.py`` ``CausalLM.auxiliary_loss``).
     """
 
     has_state = True
@@ -132,6 +146,7 @@ class SparseMoE(KerasLayer):
                  route_norm: bool = True, route_scale: float = 1.0,
                  bias_rate: float = 0.001, route_eps: float = 1e-20,
                  activation: str = "swiglu", shared_width=None,
+                 scores: str = "sigmoid", balance_coef: float = 0.0,
                  input_shape=None, name=None):
         from analytics_zoo_tpu.parallel.moe import ACTIVATIONS
 
@@ -150,6 +165,20 @@ class SparseMoE(KerasLayer):
         self.activation = activation
         self.route_norm, self.route_scale = route_norm, float(route_scale)
         self.bias_rate, self.route_eps = float(bias_rate), float(route_eps)
+        if balance_coef and scores != "softmax":
+            raise ValueError("a balance term over sigmoid scores: only the "
+                             "softmax router's is built")
+        self.scores, self.balance_coef = scores, float(balance_coef)
+
+    @property
+    def compact(self) -> bool:
+        """Whether the held rows may take the one compacted pass: where a
+        selection bias steers the load toward the uniform share. A softmax
+        router has none (its picks leave the bias out), and in training on
+        one chip its share on the experts held wanders back and forth across
+        the buffer's edge, each crossing costing the difference between the
+        two paths; the chunks' cost follows the load with no edge."""
+        return self.scores == "sigmoid" and self.bias_rate > 0
 
     def build(self, input_shape: Shape):
         d, init = input_shape[-1], DECODER_INIT
@@ -168,6 +197,9 @@ class SparseMoE(KerasLayer):
         self.add_state("select_bias", (self.n_experts,), "zeros")
         self.add_state("expert_tokens", (self.n_experts,), "zeros")
         self.add_state("compact", (), "zeros")
+        if self.balance_coef:
+            self.add_state("balance_f", (self.n_experts,), "zeros")
+            self.add_state("balance_p", (self.n_experts,), "zeros")
 
     def call(self, params, x, state=None, training=False, **kw):
         from analytics_zoo_tpu.parallel.moe import (
@@ -179,14 +211,15 @@ class SparseMoE(KerasLayer):
         shape = x.shape
         flat = x.reshape(-1, shape[-1])
         with jax.named_scope("moe.route"):
-            picked, weights, counts = route_topk(
+            routed = route_topk(
                 flat, params["router"], state["select_bias"], self.top_k,
-                self.route_norm, self.route_scale, self.route_eps)
+                self.route_norm, self.route_scale, self.route_eps, self.scores)
+            picked, weights, counts = routed[:3]
         with jax.named_scope("moe.experts"):
             y, compact = held_experts_ffn(
                 flat, picked, weights, params["experts_" + first],
                 params["experts_w_down"], self.n_experts,
-                self.experts_held[0], self.activation)
+                self.experts_held[0], self.activation, self.compact)
         if self.n_shared:
             with jax.named_scope("moe.shared"):
                 y = y + act(flat @ params["shared_" + first]) @ params[
@@ -197,4 +230,8 @@ class SparseMoE(KerasLayer):
             state = {"select_bias": bias - jnp.mean(bias),
                      "expert_tokens": counts,
                      "compact": compact.astype(jnp.float32)}
+            if self.balance_coef:
+                with jax.named_scope("moe.balance"):
+                    state.update(balance_f=counts / flat.shape[0],
+                                 balance_p=routed[3])
         return y.reshape(shape), state

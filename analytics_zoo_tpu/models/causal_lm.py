@@ -18,7 +18,7 @@ the slice; ids are drawn from it): see ``SparseMoE``.
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +33,7 @@ from analytics_zoo_tpu.keras.layers.decoder import (
 )
 from analytics_zoo_tpu.keras.layers.embeddings import Embedding
 from analytics_zoo_tpu.keras.layers.moe import DECODER_INIT, SparseMoE
+from analytics_zoo_tpu.ops.rotary import Rotary, Yarn
 
 
 def _experts(cfg: Dict, held_key: str) -> Dict:
@@ -77,7 +78,8 @@ def _lfm2_moe(cfg: Dict) -> Dict:
         **_listed_layers(cfg),
         head_dim=cfg["hidden_size"] // cfg["num_attention_heads"],
         n_shared=0, rope_theta=cfg["rope_parameters"]["rope_theta"],
-        rope_full_layers=True, gated_attention=False,
+        full_rotary=cfg["rope_parameters"]["rope_theta"],
+        gated_attention=False,
         conv_kernel=cfg["conv_L_cache"], norms="pre",
         epsilon=cfg["norm_eps"], embed_scale=1.0,
         route_norm=cfg["norm_topk_prob"],
@@ -173,8 +175,8 @@ def _nemotron_h(cfg: Dict) -> Dict:
     return dict(
         layer_types=[_NEMOTRON_H_LETTERS[c] for c in pattern],
         one_part=True, n_kv_head=cfg["num_key_value_heads"],
-        head_dim=cfg["head_dim"], rope_full_layers=False,
-        gated_attention=False, qk_norm=False, norms="pre",
+        head_dim=cfg["head_dim"], gated_attention=False, qk_norm=False,
+        norms="pre",
         epsilon=cfg["layer_norm_epsilon"], embed_scale=1.0,
         mamba_heads=cfg["mamba_num_heads"],
         mamba_head_dim=cfg["mamba_head_dim"], ssm_groups=cfg["n_groups"],
@@ -193,17 +195,96 @@ def _nemotron_h(cfg: Dict) -> Dict:
         tie_embeddings=cfg.get("tie_word_embeddings", False))
 
 
+# what `_mellum` does not build, by key: the only value it takes
+_MELLUM_ONLY = {"use_sliding_window": True, "max_window_layers": 0,
+                "attention_bias": False, "hidden_act": "silu"}
+# a `rope_parameters` section's keys that `_mellum_rotary` reads
+_ROPE_KEYS = {"rope_type", "rope_theta", "factor",
+              "original_max_position_embeddings", "beta_fast", "beta_slow",
+              "attention_factor"}
+
+
+def _mellum_rotary(kind: str, section: Dict) -> Rotary:
+    """The rotary spec of one layer kind's ``rope_parameters`` section:
+    ``default`` (a base alone) or ``yarn``; anything else is refused."""
+    rope_type = section.get("rope_type", "default")
+    unknown = sorted(set(section) - _ROPE_KEYS)
+    if rope_type not in ("default", "yarn") or unknown:
+        raise NotImplementedError(
+            f"mellum {kind} rotary {rope_type!r} with {unknown}: only "
+            f"'default' and 'yarn' over {sorted(_ROPE_KEYS)} are built")
+    if rope_type == "default":
+        return Rotary(float(section["rope_theta"]))
+    return Rotary(float(section["rope_theta"]), Yarn(
+        float(section["factor"]),
+        int(section["original_max_position_embeddings"]),
+        float(section.get("beta_fast", 32.0)),
+        float(section.get("beta_slow", 1.0)),
+        section.get("attention_factor")))
+
+
+def _mellum(cfg: Dict) -> Dict:
+    """Mellum (JetBrains; the Qwen3-MoE layout): grouped-query attention
+    whose ``layer_types`` windows (``sliding_window``) and full layers each
+    take the rotary of their own ``rope_parameters`` section (YaRN on the
+    full ones), q / k head norms, no gate, the two pre-norms alone; the
+    leading ``dense`` entries of ``mlp_layer_types`` are dense layers, the
+    ``sparse`` ones softmax top-k expert layers with no shared expert and no
+    selection bias (``bias_rate`` 0), balanced by the loss's term of
+    ``router_aux_loss_coef`` (0.001 where the file has none, the training
+    framework's choice); ``num_experts`` counts the experts held here. With
+    nothing steering the load, the held rows always go through in chunks
+    (``SparseMoE.compact``).
+    Raises for what is not built: no window, windows on some layers only,
+    biases, another activation, a dense layer after an expert one, a rotary
+    type other than ``default`` and ``yarn``."""
+    for key, only in _MELLUM_ONLY.items():
+        if cfg.get(key, only) != only:
+            raise NotImplementedError(
+                f"mellum with {key}={cfg[key]!r}: only {only!r} is built")
+    mlp = cfg["mlp_layer_types"]
+    dense = next((i for i, kind in enumerate(mlp) if kind != "dense"),
+                 len(mlp))
+    if set(mlp) - {"dense", "sparse"} or "dense" in mlp[dense:]:
+        raise NotImplementedError(
+            f"mellum mlp_layer_types {mlp}: only leading 'dense' layers "
+            f"before 'sparse' ones are built")
+    if len(mlp) != len(cfg["layer_types"]):
+        raise ValueError(f"{len(mlp)} mlp_layer_types for "
+                         f"{len(cfg['layer_types'])} layer_types")
+    rope = {kind: _mellum_rotary(kind, section)
+            for kind, section in cfg["rope_parameters"].items()}
+    missing = sorted(set(cfg["layer_types"]) - set(rope))
+    if missing:
+        raise ValueError(f"mellum layers {missing} have no rope_parameters")
+    return dict(
+        layer_types=cfg["layer_types"], num_dense_layers=dense,
+        n_kv_head=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        **_experts(cfg, "num_experts"), n_shared=0,
+        sliding_window=cfg["sliding_window"],
+        rope_theta=rope.get("sliding_attention"),
+        full_rotary=rope.get("full_attention"), gated_attention=False,
+        qk_norm=True, norms="pre", epsilon=cfg["rms_norm_eps"],
+        embed_scale=1.0, route_norm=cfg["norm_topk_prob"], route_scale=1.0,
+        route_eps=0.0, scores="softmax", bias_rate=0.0,
+        balance_coef=cfg.get("router_aux_loss_coef", 0.001),
+        tie_embeddings=cfg.get("tie_word_embeddings", False))
+
+
 # a published config's `model_type` -> the constructor's arguments that its
 # own key names give
 _FAMILIES = {"afmoe": _afmoe, "lfm2_moe": _lfm2_moe,
-             "deepseek_v3": _deepseek_v3, "nemotron_h": _nemotron_h}
+             "deepseek_v3": _deepseek_v3, "nemotron_h": _nemotron_h,
+             "mellum": _mellum}
 
 
 class CausalLM(KerasNet):
     """See the module docstring. ``layer_types``: a layer's token mixer, one
-    of ``"sliding_attention"`` (rotary positions, window ``sliding_window``),
-    ``"full_attention"`` (causal; rotary positions where ``rope_full_layers``,
-    else no positional encoding), ``"latent_attention"`` (a
+    of ``"sliding_attention"`` (rotary positions by ``rope_theta``, a base or
+    an ``ops.rotary.Rotary`` spec, window ``sliding_window``),
+    ``"full_attention"`` (causal; rotary positions by ``full_rotary``, a
+    base or a spec, where given, else no positional encoding),
+    ``"latent_attention"`` (a
     ``LatentAttention`` of ``qk_nope_dim``, ``qk_rope_dim``, ``v_dim`` and
     ``kv_rank``, rotary over neighbouring pairs;
     ``n_kv_head`` and ``head_dim`` say nothing of it), ``"conv"`` (a
@@ -214,18 +295,23 @@ class CausalLM(KerasNet):
     ``num_dense_layers`` layers get a dense ``SwiGLU`` of ``dense_width``,
     the others a ``SparseMoE`` of ``expert_act`` experts (``"swiglu"`` or
     ``"relu2"``) beside a shared one ``shared_width`` wide (``expert_width *
-    n_shared`` where not given). ``one_part``: each layer is one part alone,
+    n_shared`` where not given), routed by ``scores`` (``"sigmoid"`` with a
+    selection bias moved by ``bias_rate``, or ``"softmax"``); with
+    ``balance_coef`` > 0 the training loss gains the expert layers' balance
+    term (``auxiliary_loss``). ``one_part``: each layer is one part alone,
     the kind's mixer, or, of kind ``"experts"``, the ``SparseMoE``.
     ``gated_attention``: the attention's sigmoid output gate; ``qk_norm``:
     its per-head RMS norm of q and k. ``norms``: a
     block's norm layout (``DecoderBlock``). ``embed_scale``: the embedding's
     multiplier (sqrt(hidden) with muP). ``tie_embeddings``: the head is the
     embedding's transpose, one leaf of the parameters whose gradient is the
-    sum of both uses. State: each expert layer's selection bias and step
-    counts, and ``tokens``, the tokens of the last training step;
-    ``train_stats`` hands the counts to ``Estimator.train``, which brings
-    them out with the loss and gives them back to ``record_train_stats`` at
-    the drain."""
+    sum of both uses. State: each expert layer's selection bias, step
+    counts and balance statistics, ``tokens``, the tokens of the last
+    training step, and, where some layer has a window, ``window_pairs``, the
+    (query, key) pairs inside the windows of that step summed over the
+    window layers; ``train_stats`` hands them to ``Estimator.train``, which
+    brings them out with the loss and gives them back to
+    ``record_train_stats`` at the drain."""
 
     MIXERS = ("sliding_attention", "full_attention", "latent_attention",
               "conv", "mamba")
@@ -236,11 +322,13 @@ class CausalLM(KerasNet):
                  head_dim: int, dense_width: int, num_dense_layers: int = 0,
                  n_experts: int = 0, experts_held: Optional[Tuple[int, int]] = None,
                  expert_width: int = 0, top_k: int = 1, n_shared: int = 1,
-                 sliding_window: int = 2048, rope_theta: float = 10000.0,
+                 sliding_window: int = 2048,
+                 rope_theta: Union[float, Rotary] = 10000.0,
                  epsilon: float = 1e-5, embed_scale: Optional[float] = None,
                  route_norm: bool = True, route_scale: float = 1.0,
                  bias_rate: float = 0.001, route_eps: float = 1e-20,
-                 rope_full_layers: bool = False, gated_attention: bool = True,
+                 full_rotary: Union[None, float, Rotary] = None,
+                 gated_attention: bool = True,
                  conv_kernel: int = 3, norms: str = "sandwich",
                  tie_embeddings: bool = False, qk_nope_dim: int = 0,
                  qk_rope_dim: int = 0, v_dim: int = 0, kv_rank: int = 0,
@@ -249,7 +337,8 @@ class CausalLM(KerasNet):
                  ssm_groups: int = 1, ssm_state: int = 0, ssm_chunk: int = 128,
                  time_step: Tuple[float, float, float] = (0.001, 0.1, 1e-4),
                  ssm_out_scale: float = 1.0, expert_act: str = "swiglu",
-                 shared_width: Optional[int] = None,
+                 shared_width: Optional[int] = None, scores: str = "sigmoid",
+                 balance_coef: float = 0.0,
                  seq_len: Optional[int] = None,
                  dtype: Optional[str] = "bfloat16", remat: bool = True,
                  name: Optional[str] = None):
@@ -287,8 +376,7 @@ class CausalLM(KerasNet):
                 mixer = GroupedQueryAttention(
                     n_head, n_kv_head, head_dim,
                     window=sliding_window if sliding else None,
-                    rope_theta=(rope_theta if sliding or rope_full_layers
-                                else None),
+                    rope_theta=rope_theta if sliding else full_rotary,
                     qk_norm=qk_norm, gated=gated_attention, epsilon=epsilon,
                     name=f"{self.name}_l{i}_attn")
             if one_part and mixer is not None:
@@ -298,8 +386,8 @@ class CausalLM(KerasNet):
             else:
                 mlp = SparseMoE(n_experts, expert_width, top_k, experts_held,
                                 n_shared, route_norm, route_scale, bias_rate,
-                                route_eps, expert_act, shared_width,
-                                name=f"{self.name}_l{i}_moe")
+                                route_eps, expert_act, shared_width, scores,
+                                balance_coef, name=f"{self.name}_l{i}_moe")
             block = DecoderBlock(mixer, mlp, epsilon, dtype, remat, norms,
                                  name=f"{self.name}_l{i}")
             block.ensure_built((None, seq_len, hidden_size))
@@ -308,6 +396,10 @@ class CausalLM(KerasNet):
         self.latent_layers = sum(kind == "latent_attention"
                                  for kind in layer_types)
         self.ssm_layers = sum(kind == "mamba" for kind in layer_types)
+        self.windows = [b.attn.window for b in self.blocks
+                        if b.attn is not None
+                        and getattr(b.attn, "window", None) is not None]
+        self.balance_coef = float(balance_coef)
         self.final_norm = RMSNorm(epsilon, name=self.name + "_final_norm")
         self.final_norm.ensure_built((None, seq_len, hidden_size))
         self.head = None
@@ -347,8 +439,13 @@ class CausalLM(KerasNet):
 
     def init(self, rng):
         params, state = super().init(rng)
-        state[self.name] = {"tokens": jnp.zeros((), jnp.float32)}
+        state[self.name] = {key: jnp.zeros((), jnp.float32)
+                            for key in self._step_counts()}
         return params, state
+
+    def _step_counts(self):
+        """What the model's own state counts of a training step."""
+        return ("tokens", "window_pairs") if self.windows else ("tokens",)
 
     def apply(self, params, state, x, training=False, rng=None):
         """x: token ids (rows, tokens) -> (logits (rows, tokens, vocabulary)
@@ -373,8 +470,15 @@ class CausalLM(KerasNet):
             logits = h @ (kernel if self.dtype is None
                           else kernel.astype(self.dtype))
         if training:
+            rows, seq = ids.shape
+            # keys a query sees in a window of w, summed over a row: the
+            # first w queries see 1 .. w, the others w each
+            pairs = sum(w * (w + 1) / 2 + (seq - w) * w if w < seq
+                        else seq * (seq + 1) / 2 for w in self.windows)
+            counted = {"tokens": ids.size, "window_pairs": rows * pairs}
             new_state[self.name] = {
-                "tokens": jnp.asarray(ids.size, jnp.float32)}
+                key: jnp.asarray(counted[key], jnp.float32)
+                for key in self._step_counts()}
         return logits, new_state
 
     def get_output_shape(self):
@@ -383,18 +487,39 @@ class CausalLM(KerasNet):
     def get_input_shape(self):
         return (None, self.seq_len)
 
+    # -- the loss's own term ----------------------------------------------
+
+    def auxiliary_loss(self, model_state):
+        """The term a training loss adds beside the criterion, from the state
+        the step's ``apply`` returned: ``balance_coef * E * sum_e f_e p_e``,
+        ``f_e`` the tokens that picked expert e over the tokens and ``p_e``
+        its mean probability, each pooled over the expert layers (as
+        ``load_balancing_loss_func`` of the transformers library pools a
+        step's router logits), E the router's width. A constant 0 without
+        ``balance_coef``."""
+        if not self.balance_coef:
+            return 0.0
+        moe = [model_state[b.name] for b in self.blocks if b.has_state]
+        with jax.named_scope("moe.balance"):
+            f = jnp.mean(jnp.stack([m["balance_f"] for m in moe]), axis=0)
+            p = jnp.mean(jnp.stack([m["balance_p"] for m in moe]), axis=0)
+            return self.balance_coef * f.shape[0] * jnp.sum(f * p)
+
     # -- step statistics -------------------------------------------------
 
     def train_stats(self, model_state) -> Dict:
-        """What a train step returns beside its loss: the step's tokens and,
-        a row an expert layer, the tokens routed to each expert and whether
-        the held ones went through in the compacted pass."""
-        stats = {"tokens": model_state[self.name]["tokens"]}
+        """What a train step returns beside its loss: the step's tokens, its
+        (query, key) pairs inside windows, its balance term and, a row an
+        expert layer, the tokens routed to each expert and whether the held
+        ones went through in the compacted pass."""
+        stats = dict(model_state[self.name])
         moe = [model_state[b.name] for b in self.blocks if b.has_state]
         if moe:
             stats["expert_tokens"] = jnp.stack(
                 [m["expert_tokens"] for m in moe])
             stats["moe_compact"] = jnp.stack([m["compact"] for m in moe])
+        if self.balance_coef:
+            stats["balance"] = self.auxiliary_loss(model_state)
         return stats
 
     def record_train_stats(self, stats: Dict) -> None:
@@ -413,6 +538,10 @@ class CausalLM(KerasNet):
             float(stats["tokens"]) * self.latent_layers)
         # and through every Mamba-2 layer
         obs["ssm_token_layers"].inc(float(stats["tokens"]) * self.ssm_layers)
+        if "window_pairs" in stats:
+            obs["window_pairs"].inc(float(stats["window_pairs"]))
+        if "balance" in stats:
+            obs["balance"].observe(float(stats["balance"]))
         counts = stats.get("expert_tokens")
         if counts is None:
             return

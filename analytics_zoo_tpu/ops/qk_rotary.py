@@ -1,20 +1,24 @@
 """The per-head RMS norm and rotary embedding of queries or keys as one op.
 
 ``norm_rotary(x, gain, eps, theta)`` is ``rotary_embedding(rms_norm(x, gain,
-eps), theta)`` over a head's width (``keras/layers/decoder.py``), taken from
-the input projection's layout ``(batch, seq, heads, head_dim)`` to the
-attention kernels' ``(batch, heads, seq, head_dim)``. On the chip, at head
-widths 64 and 128 and a sequence that the token tile divides, it is a pair
-of Pallas kernels with their own VJP, ``zoo_qk_rotary_fwd`` and
+eps), theta)`` over a head's width (``keras/layers/decoder.py``; ``theta`` a
+base or an ``ops.rotary.Rotary`` spec, YaRN's scaled frequencies among them),
+taken from the input projection's layout ``(batch, seq, heads, head_dim)``
+to the attention kernels' ``(batch, heads, seq, head_dim)``. On the chip, at
+head widths 64 and 128 and a sequence that the token tile divides, it is a
+pair of Pallas kernels with their own VJP, ``zoo_qk_rotary_fwd`` and
 ``zoo_qk_rotary_bwd``: each reads and writes every number once, in x's
 dtype, with float32 inside. Anywhere else it is the two functions as XLA
-differentiates them; ``zoo_qk_rotary_built_total{path}`` counts which.
+differentiates them; ``zoo_qk_rotary_built_total{path}`` counts which, with
+``_scaled`` after the path where the spec scales its frequencies.
 
 The numerics are the two functions': norm statistics and the rotation in
-float32 over the same angle bits (``pos * inv``, as ``rotary_embedding``
-computes them), the result rounded once to x's dtype. The angles' cos and
-signed sin are a table made once a call outside the kernels and read a
-token tile at a time, resident while the heads of that tile go past.
+float32 over the same angle bits (``ops.rotary.angles``, as
+``rotary_embedding`` computes them), the result rounded once to x's dtype.
+The angles' cos and signed sin, times the spec's magnitude, are a table made
+once a call outside the kernels and read a token tile at a time, resident
+while the heads of that tile go past: a scaled spec is other data in the
+same kernels.
 
 A kernel row is one 128-lane tile of the projection's output: one head at
 width 128, two neighbouring heads at width 64, so no row is half empty.
@@ -36,6 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from analytics_zoo_tpu.ops.flash_attention import _interpret
+from analytics_zoo_tpu.ops.rotary import Rotary, angles, as_rotary
 
 LANES = 128
 # The token tile: the kernels take a sequence that it divides.
@@ -50,17 +55,14 @@ def _on_chip() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _tables(s: int, head_dim: int, theta: Optional[float]):
+def _tables(s: int, head_dim: int, spec: Optional[Rotary]):
     """(cos, signed sin), each ``(s, LANES)`` float32: a lane's angle is its
-    head's pair's, ``pos * inv`` as ``rotary_embedding`` makes it; the sign
-    is the rotate-half's, minus on a head's first half. None without
-    rotary."""
-    if theta is None:
+    head's pair's, as ``rotary_embedding`` makes it (``ops.rotary.angles``,
+    times the spec's magnitude); the sign is the rotate-half's, minus on a
+    head's first half. None without rotary."""
+    if spec is None:
         return None
-    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                           / head_dim))
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    cos, sin = angles(spec, s, head_dim)
     reps = (1, LANES // head_dim)
     return (jnp.tile(jnp.concatenate([cos, cos], axis=-1), reps),
             jnp.tile(jnp.concatenate([-sin, sin], axis=-1), reps))
@@ -186,12 +188,12 @@ def _gain_row(gain, head_dim: int):
     return jnp.tile(gain.astype(jnp.float32), LANES // head_dim)[None]
 
 
-def _forward(x, gain, eps, head_dim, theta):
+def _forward(x, gain, eps, head_dim, spec):
     b, s, width = x.shape
-    rotary = theta is not None
+    rotary = spec is not None
     grid, tiles, x_spec, heads_spec, row_spec, table_specs = _specs(
         x, head_dim, rotary)
-    tables = _tables(s, head_dim, theta) or ()
+    tables = _tables(s, head_dim, spec) or ()
     return pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps, head_dim=head_dim,
                           tiles=tiles, rotary=rotary),
@@ -204,13 +206,13 @@ def _forward(x, gain, eps, head_dim, theta):
     )(x, _gain_row(gain, head_dim), *tables)
 
 
-def _backward(x, gain, dy, eps, head_dim, theta):
+def _backward(x, gain, dy, eps, head_dim, spec):
     b, s, width = x.shape
-    rotary = theta is not None
+    rotary = spec is not None
     grid, tiles, x_spec, heads_spec, row_spec, table_specs = _specs(
         x, head_dim, rotary)
     n_tok = grid[1]
-    tables = _tables(s, head_dim, theta) or ()
+    tables = _tables(s, head_dim, spec) or ()
     dx, dg = pl.pallas_call(
         functools.partial(_bwd_kernel, eps=eps, head_dim=head_dim,
                           tiles=tiles, rotary=rotary),
@@ -227,17 +229,17 @@ def _backward(x, gain, dy, eps, head_dim, theta):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def _fused(x, gain, eps, head_dim, theta):
-    return _forward(x, gain, eps, head_dim, theta)
+def _fused(x, gain, eps, head_dim, spec):
+    return _forward(x, gain, eps, head_dim, spec)
 
 
-def _fused_fwd(x, gain, eps, head_dim, theta):
-    return _forward(x, gain, eps, head_dim, theta), (x, gain)
+def _fused_fwd(x, gain, eps, head_dim, spec):
+    return _forward(x, gain, eps, head_dim, spec), (x, gain)
 
 
-def _fused_bwd(eps, head_dim, theta, res, dy):
+def _fused_bwd(eps, head_dim, spec, res, dy):
     x, gain = res
-    return _backward(x, gain, dy, eps, head_dim, theta)
+    return _backward(x, gain, dy, eps, head_dim, spec)
 
 
 _fused.defvjp(_fused_fwd, _fused_bwd)
@@ -255,7 +257,7 @@ def _refusal(x) -> Optional[str]:
     return None
 
 
-def norm_rotary_kernel(x, gain, eps: float, theta: Optional[float] = None):
+def norm_rotary_kernel(x, gain, eps: float, theta=None):
     """The Pallas path of :func:`norm_rotary`, in interpret mode off the
     chip. Raises NotImplementedError for a head width other than 64 or 128,
     an odd number of heads at 64 and a sequence the token tile
@@ -265,7 +267,7 @@ def norm_rotary_kernel(x, gain, eps: float, theta: Optional[float] = None):
         raise NotImplementedError(why)
     b, s, n, d = x.shape
     return _fused(x.reshape(b, s, n * d), gain, float(eps), d,
-                  None if theta is None else float(theta))
+                  as_rotary(theta))
 
 
 def _composed(x, gain, eps, theta):
@@ -276,20 +278,25 @@ def _composed(x, gain, eps, theta):
     return y if theta is None else rotary_embedding(y, theta)
 
 
-def norm_rotary(x, gain, eps: float, theta: Optional[float] = None):
+def norm_rotary(x, gain, eps: float, theta=None):
     """``rotary_embedding(rms_norm(h, gain, eps), theta)`` of the heads
     ``h = x.transpose(0, 2, 1, 3)``: x ``(batch, seq, heads, head_dim)`` as
     the input projection gives it, the result ``(batch, heads, seq,
     head_dim)`` as the attention kernels take it; no rotary where ``theta``
-    is None. On the chip at head widths 64 (an even number of heads) and
-    128 over a sequence that ``TOKENS`` divides, the fused kernels
-    (:func:`norm_rotary_kernel`);
+    is None, else a base or an ``ops.rotary.Rotary`` spec. On the chip at
+    head widths 64 (an even number of heads) and 128 over a sequence that
+    ``TOKENS`` divides, the fused kernels (:func:`norm_rotary_kernel`);
     elsewhere the two functions as they are. Each call counts one build in
-    ``zoo_qk_rotary_built_total`` under the path it took."""
+    ``zoo_qk_rotary_built_total`` under the path it took, ``kernel`` or
+    ``xla``, with ``_scaled`` after it where the spec's frequencies are
+    scaled (YaRN)."""
     from analytics_zoo_tpu.common.observability import qk_rotary_built
 
+    spec = as_rotary(theta)
     kernel = _on_chip() and _refusal(x) is None
-    qk_rotary_built().labels(path="kernel" if kernel else "xla").inc()
+    path = ("kernel" if kernel else "xla") + (
+        "_scaled" if spec is not None and spec.scaled else "")
+    qk_rotary_built().labels(path=path).inc()
     if kernel:
         return norm_rotary_kernel(x, gain, eps, theta)
     return _composed(x, gain, eps, theta)

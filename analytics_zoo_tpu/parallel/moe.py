@@ -20,8 +20,8 @@ Everything is dense fixed-shape einsums — no dynamic gather/sort — so one
 jitted program covers any routing pattern.
 
 Below that Switch layer sits the other family (``route_topk`` /
-``held_experts_ffn``): sigmoid top-k routing that drops nothing, computed
-over the experts one chip of an expert-parallel group holds.
+``held_experts_ffn``): sigmoid or softmax top-k routing that drops nothing,
+computed over the experts one chip of an expert-parallel group holds.
 """
 
 from __future__ import annotations
@@ -140,23 +140,41 @@ _CHUNK_TOKENS = 4096
 
 
 def route_topk(x, router, select_bias, k: int, normalize: bool = True,
-               scale: float = 1.0, eps: float = 1e-20):
-    """Sigmoid top-k routing in float32. ``x`` (T, d), ``router`` (d, E),
-    ``select_bias`` (E,): added to the scores for the pick only, outside the
-    gradient. ``normalize``: a token's weights over their sum plus ``eps``
-    (the published model files differ in it: 1e-20, 1e-6). Returns (picked
-    (T, k) int32, weights (T, k) float32, counts (E,) float32: the tokens
-    routed to each expert)."""
-    scores = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, picked = jax.lax.top_k(scores + jax.lax.stop_gradient(select_bias), k)
-    w = jnp.take_along_axis(scores, picked, axis=-1)
+               scale: float = 1.0, eps: float = 1e-20,
+               scores: str = "sigmoid"):
+    """Top-k routing in float32 over all E experts. ``x`` (T, d), ``router``
+    (d, E), the scores ``x router`` at ``Precision.HIGHEST``.
+
+    ``scores="sigmoid"``: a score's sigmoid a weight; ``select_bias`` (E,) is
+    added for the pick only, outside the gradient. ``"softmax"``: the softmax
+    over all E is the weights, the k most probable are picked and
+    ``select_bias`` is left out. ``normalize``: a token's k weights over
+    their sum plus ``eps`` (the published model files differ in it: 1e-20,
+    1e-6, none); then times ``scale``.
+
+    Returns (picked (T, k) int32, weights (T, k) float32, counts (E,)
+    float32: the tokens routed to each expert) and, for softmax scores, the
+    mean probability of each expert over the tokens (E,), which a balance
+    term of the loss takes with the counts."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if scores == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        w, picked = jax.lax.top_k(probs, k)
+    elif scores == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+        _, picked = jax.lax.top_k(probs + jax.lax.stop_gradient(select_bias),
+                                  k)
+        w = jnp.take_along_axis(probs, picked, axis=-1)
+    else:
+        raise ValueError(f"unknown router scores {scores!r}; known: "
+                         f"['sigmoid', 'softmax']")
     if normalize:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
-    counts = jnp.sum(jax.nn.one_hot(picked, scores.shape[-1],
+    counts = jnp.sum(jax.nn.one_hot(picked, probs.shape[-1],
                                     dtype=jnp.float32), axis=(0, 1))
-    return picked.astype(jnp.int32), w * scale, counts
+    routed = (picked.astype(jnp.int32), w * scale, counts)
+    return routed + (jnp.mean(probs, axis=0),) if scores == "softmax" else routed
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -439,7 +457,8 @@ _held_ffn.defvjp(_held_ffn_fwd, _held_ffn_bwd)
 
 
 def held_experts_ffn(x, picked, weights, w_gate_up, w_down, n_experts: int,
-                     offset: int = 0, activation: str = "swiglu"):
+                     offset: int = 0, activation: str = "swiglu",
+                     compact: bool = True):
     """The held experts' part of a top-k expert layer, nothing dropped:
     ``sum over the picks of a token that fall on a held expert of weight *
     W_down_e act(W_in_e x)``. ``x`` (T, d); ``picked`` / ``weights`` (T, k)
@@ -459,11 +478,14 @@ def held_experts_ffn(x, picked, weights, w_gate_up, w_down, n_experts: int,
     T*k assignments through in chunks of ``_CHUNK_TOKENS`` tokens instead,
     each rematerialised in the backward pass; so does every call, with no
     choice compiled in, where the experts held are half of all or more and
-    the buffer would hold every assignment anyway."""
+    the buffer would hold every assignment anyway, or where ``compact`` is
+    False: a layer whose held load crosses the buffer's edge back and forth
+    pays the difference between the two paths at each crossing, and the
+    chunks' cost follows the load with no edge."""
     t, k = picked.shape
     count = w_gate_up.shape[0]
     rows = _held_capacity(t, k, count, n_experts)
-    if rows >= t * k:
+    if not compact or rows >= t * k:
         return (_held_chunks(x, picked, weights, w_gate_up, w_down, n_experts,
                              offset, activation), jnp.zeros((), jnp.bool_))
     local = picked.reshape(-1) - offset

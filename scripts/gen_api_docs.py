@@ -334,10 +334,12 @@ PAGES = {
     "ops": (
         "Ops — attention, flash kernels, q/k norm and rotary, SSD scan, bbox",
         "The hot-op layer: dispatchered attention, the Pallas flash "
-        "kernels, the fused q/k head norm and rotary, the chunked "
-        "state-space scan, padded NMS (SURVEY §2.3).",
+        "kernels, the fused q/k head norm and rotary with the rotary spec "
+        "they read (YaRN among them), the chunked state-space scan, padded "
+        "NMS (SURVEY §2.3).",
         ["analytics_zoo_tpu.ops.attention",
          "analytics_zoo_tpu.ops.flash_attention",
+         "analytics_zoo_tpu.ops.rotary",
          "analytics_zoo_tpu.ops.qk_rotary",
          "analytics_zoo_tpu.ops.ssd",
          "analytics_zoo_tpu.ops.bbox"]),

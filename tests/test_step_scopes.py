@@ -23,14 +23,17 @@ ACCEPTED = ("attn.window", "attn.full", "attn.latent", "conv.short",
             "moe.route", "moe.experts", "moe.shared", "lm.loss", "optimizer")
 SSM = ("ssm.proj_in", "ssm.scan", "ssm.proj_out")
 NEW = ("attn.proj_in", "attn.qk_rotary", "attn.proj_out", "block.norm",
-       "block.cast", "mlp.dense", "lm.embed", "lm.head") + SSM
+       "block.cast", "mlp.dense", "lm.embed", "lm.head") + SSM + (
+           "moe.balance",)
 SCOPES = ACCEPTED + NEW
 FAMILIES = {"afmoe": AFMOE, "lfm2_moe": LFM2_MOE, "deepseek_v3": DEEPSEEK_V3,
             "nemotron_h": NEMOTRON_H}
 # `benchmark.scope_shares.program_text` of each SwiGLU family's compiled step
-# as it stood before `held_experts_ffn` took the expert's activation as data
+# as it stood before `held_experts_ffn` took the expert's activation as data;
+# `afmoe`'s since its step also returns the (query, key) pairs inside its
+# windows (`CausalLM.train_stats`), one scalar more and nothing else
 SWIGLU_STEPS = {
-    "afmoe": "55b955b12c99bc7b864dce76e62eb7f6edd4d954472fc8ff7d343b0ffeeee751",
+    "afmoe": "f84a5cddb2d89119f1ec84a875cf1744653c56fbb50ffcb2a63373a479e8205d",
     "deepseek_v3":
         "eb87b296fd436b0711a161896589c0433c411efcb8aa49522ad7c6823ab2c2d1",
     "lfm2_moe":
@@ -132,14 +135,16 @@ def _named(full: str):
 
 
 def test_no_scope_name_holds_another_and_the_benchmark_names_the_nine():
-    from benchmark import fit_kanana2, fit_lfm2, fit_nemotronh, trace_lm
+    from benchmark import (fit_kanana2, fit_lfm2, fit_mellum2, fit_nemotronh,
+                           trace_lm)
 
-    assert len(set(SCOPES)) == 20
+    assert len(set(SCOPES)) == 21
     for a in SCOPES:
         assert [b for b in SCOPES if a in b] == [a], a
     assert set(trace_lm.SCOPES + fit_lfm2.SCOPES + fit_kanana2.SCOPES) == set(
         ACCEPTED)
     assert fit_nemotronh.SCOPES == SSM
+    assert fit_mellum2.SCOPES == ("moe.balance",)
 
 
 def test_every_product_look_up_and_kernel_is_under_one_scope(step):
